@@ -3,10 +3,12 @@
 from __future__ import annotations
 
 import argparse
+import os
 from typing import Optional, Tuple
 
 from .. import events, log
-from ..conf import Config, ConfigWatcher, parse as parse_conf
+from ..conf import (COMPILE_CACHE_DIR, Config, ConfigWatcher,
+                    parse as parse_conf)
 from ..core import Keyspace
 
 
@@ -42,22 +44,18 @@ def setup_common(args) -> Tuple[Config, Keyspace, Optional[ConfigWatcher]]:
     return cfg, Keyspace(cfg.prefix), watcher
 
 
-def enable_compile_cache(path: str):
+def enable_compile_cache():
     """Persistent XLA compilation cache (conf.compile_cache): restarted
     processes — including a cold failover standby on the same host —
     reload compiled planner programs from disk instead of recompiling.
-    Must run before the first jit dispatch; safe to call on any jax
-    version (older ones without the knobs just skip it)."""
-    import os as _os
-    try:
-        import jax
-        d = _os.path.expanduser(path)
-        _os.makedirs(d, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", d)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          0.3)
-    except Exception as e:  # noqa: BLE001 — a cache is an optimization
-        log.warnf("compile cache unavailable (%s): %s", path, e)
+    Must run before the first jit dispatch.  Where
+    JAX_COMPILATION_CACHE_DIR is set JAX reads the directory from it
+    and none is set here."""
+    import jax
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        os.makedirs(COMPILE_CACHE_DIR, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", COMPILE_CACHE_DIR)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.3)
 
 
 def server_tls(tls, native: bool, daemon: str):

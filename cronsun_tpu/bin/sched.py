@@ -69,6 +69,18 @@ def install_worker_signal_watchdog():
                       name="sig-watchdog").start()
 
 
+def describe_device(planner) -> dict:
+    """Where this process plans, as JAX reports it, and the kernel
+    variant the planner resolves for its first window — choose_impl
+    answers "jnp" for any backend that is not a TPU without a word, so
+    a scheduler that missed its chip says so here."""
+    import jax
+    devs = jax.devices()
+    return {"platform": devs[0].platform,
+            "device_kind": devs[0].device_kind,
+            "count": len(devs), "impl": planner.first_window_impl()}
+
+
 def main(argv=None) -> int:
     ap = base_parser(__doc__)
     ap.add_argument("--node-id", default="scheduler-1")
@@ -167,7 +179,7 @@ def main(argv=None) -> int:
     # must never pay a jax import for a cache they'd never use
     if cfg.compile_cache:
         from .common import enable_compile_cache
-        enable_compile_cache(cfg.compile_cache)
+        enable_compile_cache()
     if args.profile_port:
         import jax
         jax.profiler.start_server(args.profile_port)
@@ -288,6 +300,7 @@ def main(argv=None) -> int:
     else:
         log.infof("cronsun-sched %s up (store %s, tz %s)",
                   args.node_id, args.store, cfg.timezone)
+    log.infof("device %s", json.dumps(describe_device(sched.planner)))
     print(f"READY {args.node_id}", flush=True)
     if sync_proxy is not None:
         # stop order matters: join the service loop FIRST so no plan

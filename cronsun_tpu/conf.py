@@ -22,6 +22,9 @@ from typing import Callable, List, Optional
 from .tlsutil import Tls
 
 EXTEND_KEY = "@extend:"
+COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".jax_cache")
 
 
 @dataclasses.dataclass
@@ -122,14 +125,20 @@ class Config:
     slo_eval_s: int = 15        # web-tier SLO engine evaluation cadence
                                 # (burn-rate windows are 5m/30m/1h/6h;
                                 # the scrape ring keeps ~6h of samples)
-    compile_cache: str = "~/.cache/cronsun-tpu/xla"
+    compile_cache: str = COMPILE_CACHE_DIR
                                 # persistent XLA compilation cache: a
                                 # restarted scheduler (or a cold failover
                                 # standby on the same host) reloads its
                                 # compiled planner programs from disk
-                                # instead of recompiling (~27 s of a cold
-                                # boot measured on CPU; 20-40 s per
-                                # program on TPU).  "" disables.
+                                # instead of recompiling.  "" disables;
+                                # anything else enables.  The directory
+                                # is placed from outside: where
+                                # JAX_COMPILATION_CACHE_DIR is set JAX
+                                # reads it and the program sets none,
+                                # otherwise it is this one fixed path
+                                # inside the checkout (the path is part
+                                # of the cache key — a directory that
+                                # moves never hits).
     security: Security = dataclasses.field(default_factory=Security)
     mail: Mail = dataclasses.field(default_factory=Mail)
     web: Web = dataclasses.field(default_factory=Web)

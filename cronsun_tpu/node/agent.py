@@ -115,6 +115,7 @@ class NodeAgent:
         self.on_fatal = on_fatal
 
         self._lease: Optional[int] = None
+        self._lease_store = None           # lazy clone, see _lease_conn
         self._proc_lease: Optional[int] = None
         self._procs: Dict[str, str] = {}   # live proc keys -> value
         self._procs_mu = threading.Lock()  # guards _procs + _proc_lease
@@ -235,6 +236,19 @@ class NodeAgent:
         # cost each agent a gigabyte)
         self._job_cache: Dict[tuple, Job] = {}
         self._job_cache_cap = 65536
+        # "group/job_id" of Common jobs whose broadcasts this node has
+        # already judged "not mine".  Every agent sees every Common
+        # fire of the fleet; without this, each fire of a job that
+        # never runs here costs a store fetch and a parse again as soon
+        # as the bounded cache above has turned over — at 1M jobs x 10k
+        # nodes that is ~10k fetches/s per agent, which one interpreter
+        # does not keep up with.  One short string per job: 41 MB
+        # measured for that fleet's 450k Common jobs (85 MB as a tuple
+        # of two), and reset whole at the cap like the cache above.
+        # Invalidated like the cache too: by the job watch per key,
+        # wholesale by any group change or watch resync.
+        self._not_here: set = set()
+        self._not_here_cap = 1 << 20
         # operator metrics (rendered fleet-wide at /v1/metrics); counters
         # are bumped from concurrent pool workers -> lock the increments
         self.stats = {"orders_consumed_total": 0, "execs_total": 0,
@@ -344,13 +358,39 @@ class NodeAgent:
         for k, v in self._procs.items():
             self.store.put(k, v, lease=self._proc_lease)
 
-    def keepalive_once(self) -> bool:
-        ok = self._lease is not None and self.store.keepalive(self._lease)
+    def _lease_conn(self):
+        """Connection for the node-lease keepalive: a dedicated clone
+        when the store supports it.  On the MAIN connection a keepalive
+        waits, server-side, behind this agent's own bulk RPCs (job
+        prefetches, bundle claims — the server applies a connection's
+        requests in arrival order) and its reply behind every queued
+        watch push; at 1M jobs x 10k nodes that outlasted node_ttl, the
+        node read as down and the exclusive fires pinned to it were not
+        placed (PERF.md, PR 22)."""
+        if self._lease_store is None:
+            self._lease_store = (self.store.clone()
+                                 if hasattr(self.store, "clone")
+                                 else self.store)
+        return self._lease_store
+
+    def _keepalive_node(self) -> bool:
+        """Refresh the node lease; register again after a lapse, as the
+        reference does."""
+        ok = self._lease is not None and \
+            self._lease_conn().keepalive(self._lease)
         if not ok:
-            self.register()     # reference re-registers after a lapse
-        else:
-            self._ensure_proc_lease()
+            self.register()
+        return ok
+
+    def _housekeep(self):
+        """The rest of a keepalive round — proc lease, metrics snapshot
+        — on the main connection, where it may wait."""
+        self._ensure_proc_lease()
         self.metrics.maybe_publish()
+
+    def keepalive_once(self) -> bool:
+        ok = self._keepalive_node()
+        self._housekeep()
         return ok
 
     def _bump(self, counter: str, n: int = 1):
@@ -415,6 +455,7 @@ class NodeAgent:
 
     def _poll_groups(self):
         for ev in self._w_groups.drain():
+            self._not_here.clear()      # membership decides eligibility
             if ev.type == DELETE:
                 self.groups.pop(ev.kv.key[len(self.ks.group):], None)
             else:
@@ -463,6 +504,7 @@ class NodeAgent:
             rest = ev.kv.key[len(self.ks.cmd):]
             if "/" not in rest:
                 continue
+            self._not_here.discard(rest)
             key = tuple(rest.split("/", 1))
             if ev.type == DELETE:
                 self._job_cache.pop(key, None)
@@ -500,8 +542,19 @@ class NodeAgent:
         # (job.go:194-233).
         ttl = max(5.0, min(self.lock_ttl, 2.0 * job.avg_time + 5.0))
         lease = self.store.grant(ttl)
-        if not self.store.put_if_absent(
-                self.ks.alone_lock_key(job.id), self.id, lease=lease):
+        try:
+            won = self.store.put_if_absent(
+                self.ks.alone_lock_key(job.id), self.id, lease=lease)
+        except KeyError:
+            # the fresh lease expired before the put landed: a store
+            # round trip longer than the lock's ttl (seen at 1M jobs,
+            # PERF.md PR 22).  The lock is not ours, so this fire is
+            # skipped as behind a live previous run — raising instead
+            # lost every other member of the bundle with it
+            log.warnf("alone lock for %s: its %.0fs lease expired before "
+                      "the put landed; fire skipped", job.id, ttl)
+            return None
+        if not won:
             self.store.revoke(lease)
             return None
         stop = threading.Event()
@@ -1292,6 +1345,7 @@ class NodeAgent:
         self.groups.clear()
         self._load_groups()
         self._job_cache.clear()    # invalidations inside the gap are lost
+        self._not_here.clear()
         n = 0
         for kv in self.store.get_prefix(self.ks.dispatch + self.id + "/"):
             n += self._handle_dispatch_kv(kv.key, kv.value,
@@ -1668,9 +1722,12 @@ class NodeAgent:
         a measured top cost of the dispatch plane."""
         pairs = []
         for rest in keys:
-            parts = rest.split("/")
-            if len(parts) == 3:
-                pairs.append((parts[1], parts[2]))
+            gj = rest.partition("/")[2]
+            if gj in self._not_here:
+                continue
+            group, _, job_id = gj.partition("/")
+            if job_id and "/" not in job_id:
+                pairs.append((group, job_id))
         self._prefetch_pairs(pairs)
 
     def _prefetch_pairs(self, pairs):
@@ -1715,18 +1772,26 @@ class NodeAgent:
         return n
 
     def _handle_broadcast_kv(self, key: str) -> int:
-        rest = key[len(self.ks.dispatch_all):]
-        parts = rest.split("/")
-        if len(parts) != 3:
+        ep, _, gj = key[len(self.ks.dispatch_all):].partition("/")
+        if gj in self._not_here:
             return 0
-        epoch_s, group, job_id = int(parts[0]), parts[1], parts[2]
+        group, _, job_id = gj.partition("/")
+        if not job_id or "/" in job_id:
+            return 0
+        epoch_s = int(ep)
         # Common runs have no store fence; this in-memory (job, second)
         # dedup keeps the resync re-list (and any stream re-delivery)
         # from double-running a broadcast this agent already took
         if (job_id, epoch_s) in self._bseen:
             return 0
         job = self._get_job(group, job_id)
-        if job is None or job.pause or not self.is_run_on(job):
+        if job is None or job.pause:
+            return 0
+        if not self.is_run_on(job):
+            if len(self._not_here) >= self._not_here_cap:
+                self._not_here.clear()
+            self._not_here.add(gj)
+            self._job_cache.pop((group, job_id), None)
             return 0
         self._bseen[(job_id, epoch_s)] = self.clock()
         if len(self._bseen) > 8192:     # prune half-hour-old entries
@@ -1885,9 +1950,22 @@ class NodeAgent:
             # but losing the identity to ANOTHER live agent is fatal: keep
             # running and this process ghost-executes orders meant for the
             # replacement
+            #
+            # The node lease has this thread (and _lease_conn) to
+            # itself: the round's other store work runs on
+            # housekeep_loop, so a slow main connection delays that
+            # work, never the refresh the fleet judges liveness by.
+            last_ok = time.monotonic()
             while not self._stop.wait(max(1.0, self.ttl / 3)):
                 try:
-                    self.keepalive_once()
+                    if self._keepalive_node():
+                        last_ok = time.monotonic()
+                    else:
+                        now = time.monotonic()
+                        log.warnf("node lease lapsed (ttl %ss, last "
+                                  "refreshed %.1fs ago); registered again",
+                                  self.ttl, now - last_ok)
+                        last_ok = now
                 except DuplicateNode as e:
                     log.errorf("node identity lost to a live replacement; "
                                "shutting down: %s", e)
@@ -1898,6 +1976,13 @@ class NodeAgent:
                 except Exception as e:  # noqa: BLE001
                     log.warnf("keepalive failed (retrying): %s", e)
 
+        def housekeep_loop():
+            while not self._stop.wait(max(1.0, self.ttl / 3)):
+                try:
+                    self._housekeep()
+                except Exception as e:  # noqa: BLE001
+                    log.warnf("housekeeping failed (retrying): %s", e)
+
         def poll_loop():
             while not self._stop.is_set():
                 try:
@@ -1907,7 +1992,7 @@ class NodeAgent:
                     time.sleep(0.5)
                 time.sleep(0.05)
 
-        for fn in (keepalive_loop, poll_loop):
+        for fn in (keepalive_loop, housekeep_loop, poll_loop):
             t = threading.Thread(target=fn, daemon=True,
                                  name=f"agent-{fn.__name__}")
             t.start()
@@ -1941,6 +2026,10 @@ class NodeAgent:
         self._flush_acks()
         self._flush_records(final=True)
         self.unregister()
+        if self._lease_store is not None \
+                and self._lease_store is not self.store:
+            self._lease_store.close()
+        self._lease_store = None
 
 
 def _local_id() -> str:

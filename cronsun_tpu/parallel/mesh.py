@@ -68,19 +68,10 @@ NODE_BLOCK_PSUM_MIN_N = 65536
 
 
 def _shard_map(body, mesh, in_specs, out_specs):
-    """shard_map across jax versions: >= 0.6 exports it at top level with
-    ``check_vma``; older releases (0.4.x, the CPU tier-1 environment)
-    keep it under jax.experimental with ``check_rep``.  One shim so the
-    mesh planners — and therefore the whole tier-1 mesh test set — run
-    on both."""
-    try:
-        from jax import shard_map as sm
-        return sm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=False)
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as sm
-        return sm(body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_rep=False)
+    """``jax.shard_map`` without the varying-manual-axes check: the plan
+    bodies mix replicated and sharded operands freely."""
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _reconcile_sharded(cand, choice, cost, load, rem_cap, is_final, axis,
@@ -462,8 +453,10 @@ class _ShardedPlannerBase:
 
         from ..ops.schedule_table import build_table
         self.table = build_table([], capacity=self.J, sharding=self._shard)
-        self.elig = jax.device_put(
-            np.zeros((self.J, self.N // 32), np.uint32), self._shard2)
+        # allocated on the devices, sharded at creation: at 1M x 100k
+        # nodes a host-built zero matrix is 13.4 GB to page in and ship
+        self.elig = jnp.zeros((self.J, self.N // 32), jnp.uint32,
+                              device=self._shard2)
         self.exclusive = jax.device_put(np.zeros(self.J, bool), self._shard)
         self.cost = jax.device_put(np.ones(self.J, np.float32), self._shard)
         self.load = jax.device_put(np.zeros(self.N, np.float32), self._repl)
@@ -590,6 +583,11 @@ class _ShardedPlannerBase:
         # device; choose_impl holds the shared measured heuristic
         from ..ops.assign import choose_impl
         return choose_impl(self.N // getattr(self, "Dn", 1), k_local)
+
+    def first_window_impl(self) -> str:
+        """The kernel variant an unpinned plan resolves to."""
+        return self._resolve_impl(
+            max(256, _next_pow2(self.max_fire_bucket) // self.Dj))
 
     def _resolve_demand_format(self, k_local: int) -> str:
         """Static per-plan pick of the demand wire format (the
@@ -978,13 +976,18 @@ class Sharded2DTickPlanner(_ShardedPlannerBase):
     bitmask width needs to fit one device.  Same contract as
     ShardedTickPlanner.
 
-    impl="jnp" (default) breaks exact-score ties by lowest global node
-    id — placements invariant to the column split; impl="pallas" runs the
+    impl="jnp"/"mixed" break exact-score ties by lowest global node id —
+    placements invariant to the column split; impl="pallas" runs the
     HBM-efficient bitpacked block kernel — deterministic per mesh shape
-    (see _sharded2d_plan_body)."""
+    (see _tick2d_local).  The default "auto" resolves per plan from the
+    per-device tile (ops.assign.choose_impl): at 1M x 102,400 nodes on
+    a 2x2 mesh the jnp bid's [32768, 51200] f32 tile makes the window
+    program need 11.9 GB of temporaries beside 3.4 GB of arguments —
+    15.3 of a v5e chip's 15.75 GB — where the pallas bid needs 4.0 GB
+    (tests/test_chip_compile.py compiles both)."""
 
     def __init__(self, mesh: Mesh, job_capacity: int, node_capacity: int,
-                 rounds: int = 3, impl: str = "jnp",
+                 rounds: int = 3, impl: str = "auto",
                  max_fire_bucket: int = 65536, tz=None,
                  shard_bids: bool = True, demand_format: str = "auto",
                  node_block_psum=None):

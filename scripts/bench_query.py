@@ -180,6 +180,8 @@ def run_query_bench(logd_shards=1, readers=4, seconds=4.0, on_log=print,
             stdout=subprocess.PIPE, stderr=subprocess.DEVNULL, text=True,
             cwd=os.path.dirname(os.path.abspath(__file__)))
 
+        ingest_up = threading.Event()
+
         def writer_counts():
             # "W <wrote> <errors>" lines, one per beat
             for line in wproc.stdout:
@@ -187,6 +189,7 @@ def run_query_bench(logd_shards=1, readers=4, seconds=4.0, on_log=print,
                 if len(parts) == 3 and parts[0] == "W":
                     wrote[0] = int(parts[1])
                     werrs[0] = int(parts[2])
+                    ingest_up.set()
 
         lat_keys = SHAPES + ("history_hot", "history_cold")
         lats = {s: [] for s in lat_keys}
@@ -275,8 +278,15 @@ def run_query_bench(logd_shards=1, readers=4, seconds=4.0, on_log=print,
             rts.append(threading.Thread(target=web_reader,
                                         args=(web_counts, stop),
                                         daemon=True))
-        t0 = time.time()
         wt.start()
+        # the window opens at the writer's first beat: its interpreter
+        # start-up (seconds on a loaded host) is not ingest the readers
+        # ran against, and a short run would otherwise report 0 writes
+        if not ingest_up.wait(timeout=60):
+            raise RuntimeError("query bench writer produced no beat in "
+                               "60 s")
+        w0 = wrote[0]
+        t0 = time.time()
         for t in rts:
             t.start()
         time.sleep(seconds)
@@ -319,7 +329,8 @@ def run_query_bench(logd_shards=1, readers=4, seconds=4.0, on_log=print,
             "query_plane_write_rate_target": write_rate,
             "query_plane_cold_fraction": cold_fraction,
             "query_plane_aged_records": aged,
-            "query_plane_write_records_per_s": round(wrote[0] / elapsed, 1),
+            "query_plane_write_records_per_s": round(
+                (wrote[0] - w0) / elapsed, 1),
             "query_plane_write_errors": werrs[0],
             "query_plane_read_errors": rerrs[0],
         }

@@ -8,6 +8,8 @@ reference only ever fires late, never early — cron.go:212-215).
 import json
 import time
 
+import pytest
+
 from cronsun_tpu.core import Job, JobRule, Keyspace, KIND_COMMON
 from cronsun_tpu.logsink import JobLogStore
 from cronsun_tpu.node.agent import NodeAgent
@@ -77,6 +79,69 @@ def test_stop_abandons_pending_future_orders():
     store.close()
 
 
+def test_broadcast_not_mine_is_remembered_until_the_job_or_a_group_changes():
+    """Every agent sees every Common fire of the fleet.  A job judged
+    "not mine" is not fetched again on its next fire — and IS judged
+    again once its document changes, a group changes, or the watches
+    resync, so an edit that makes this node eligible takes effect at
+    the next fire."""
+    from cronsun_tpu.core import Group
+    store, sink = MemStore(), JobLogStore()
+    agent = NodeAgent(store, sink, node_id="n0")
+    agent.register()
+    job = Job(name="elsewhere", command="echo hi", kind=KIND_COMMON,
+              rules=[JobRule(timer="* * * * * *", nids=["n9"],
+                             gids=["g"])])
+    job.check()
+    key, pair = KS.job_key(job.group, job.id), (job.group, job.id)
+    gj = f"{job.group}/{job.id}"
+    store.put(key, job.to_json())
+    fetches = []
+    real_get, real_many = store.get, store.get_many
+    store.get = lambda k: (fetches.append(k), real_get(k))[1]
+    store.get_many = lambda ks_: (fetches.extend(ks_), real_many(ks_))[1]
+    epoch = [int(time.time()) - 100]
+
+    def fire():
+        epoch[0] += 1
+        store.put(KS.dispatch_all_key(epoch[0], job.group, job.id), "")
+        n = agent.poll()
+        agent.join_running()
+        return n
+
+    assert fire() == 0 and gj in agent._not_here
+    assert pair not in agent._job_cache
+    del fetches[:]
+    assert fire() == 0 and key not in fetches      # no second fetch
+    # the job's document changes: judged afresh, and now it is mine
+    job.rules[0].nids = ["n0"]
+    store.put(key, job.to_json())
+    assert fire() == 1 and gj not in agent._not_here
+    # back to "elsewhere" ... until a group that the rule names gains
+    # this node
+    job.rules[0].nids = ["n9"]
+    store.put(key, job.to_json())
+    assert fire() == 0 and gj in agent._not_here
+    store.put(KS.group_key("g"),
+              Group(id="g", name="g", node_ids=["n0"]).to_json())
+    assert fire() == 1
+    # bounded: at the cap the set starts over, as the job cache does
+    agent._not_here_cap = 1
+    agent._not_here.add("g/other")
+    job.rules[0].gids = []
+    store.put(key, job.to_json())
+    assert fire() == 0 and agent._not_here == {gj}
+    # a watch resync forgets every verdict (and judges the re-listed
+    # broadcasts afresh)
+    agent._not_here_cap = 1 << 20
+    agent._not_here.add("g/stale")
+    agent.resync_watches()
+    assert agent._not_here == {gj}
+    _, total = sink.query_logs(job_ids=[job.id])
+    assert total == 2
+    store.close()
+
+
 def test_proc_keys_survive_lease_reregister():
     store, sink = MemStore(), JobLogStore()
     agent = NodeAgent(store, sink, node_id="n0")
@@ -122,6 +187,40 @@ def test_proc_lease_lapse_repaired_by_keepalive():
     assert store.get_prefix(KS.proc), "proc key not re-attached after repair"
     agent.join_running()
     store.close()
+
+
+def test_node_lease_survives_a_stalled_main_connection():
+    """The node lease is refreshed on its own connection and thread: a
+    main connection that is seconds behind (the agent's own bulk RPCs
+    and queued watch pushes, at 1M jobs x 10k nodes) delays the round's
+    housekeeping, never the refresh the fleet judges liveness by."""
+    from cronsun_tpu.store.remote import RemoteStore, StoreServer
+    srv = StoreServer().start()
+    main = RemoteStore(srv.host, srv.port)
+    agent = NodeAgent(main, JobLogStore(), node_id="n0", ttl=1.0)
+    stalled = []
+
+    def stall(_lease):               # the proc-lease leg of a round
+        stalled.append(time.monotonic())
+        time.sleep(4.5)              # > node lease (ttl + 2 = 3 s)
+        return True
+    try:
+        agent.start()
+        assert agent._lease_conn() is not main
+        registered = main.get(KS.node_key("n0")).mod_rev
+        main.keepalive = stall
+        deadline = time.monotonic() + 6.0
+        while time.monotonic() < deadline:
+            kv = srv.store.get(KS.node_key("n0"))
+            assert kv is not None and kv.mod_rev == registered, \
+                "node lease lapsed behind the stalled main connection"
+            time.sleep(0.2)
+        assert stalled, "the stall was never reached"
+    finally:
+        del main.keepalive
+        agent.stop()
+        main.close()
+        srv.stop()
 
 
 def test_duplicate_node_guard():
@@ -702,11 +801,16 @@ def test_bundle_tolerates_legacy_keys_side_by_side():
     store.close()
 
 
-def test_bundle_alone_skip_does_not_consume_fence():
+@pytest.mark.parametrize("why", ["previous run live",
+                                 "lock lease expired before its put"])
+def test_bundle_alone_skip_does_not_consume_fence(why):
     """A KindAlone member whose previous run still holds the lifetime
     lock is skipped WITHOUT consuming its (job, second) fence — the
     lock-first ordering survives coalescing — while the rest of the
-    bundle runs and the reservation is still released."""
+    bundle runs and the reservation is still released.  Likewise a
+    member whose fresh lock lease (5 s for a fast job) ran out before
+    its put landed, behind a store round trip longer than that: the
+    store refuses the put, and the bundle's other members still run."""
     store, sink = MemStore(), JobLogStore()
     agent = NodeAgent(store, sink, node_id="n0")
     agent.register()
@@ -714,7 +818,16 @@ def test_bundle_alone_skip_does_not_consume_fence():
     alone = Job(id="alz", name="alz", group="g", command="echo a", kind=1,
                 rules=[JobRule(id="r", timer="* * * * * *", nids=["n0"])])
     store.put(KS.job_key("g", "alz"), alone.to_json())
-    store.put(KS.alone_lock_key("alz"), "other")   # previous run live
+    if why == "previous run live":
+        store.put(KS.alone_lock_key("alz"), "other")
+    else:
+        real = store.put_if_absent
+
+        def late(key, value, lease=0):
+            if key == KS.alone_lock_key("alz"):
+                store.revoke(lease)             # its ttl ran out in flight
+            return real(key, value, lease=lease)
+        store.put_if_absent = late
     epoch = int(time.time()) - 1
     key = KS.dispatch_bundle_key("n0", epoch)
     store.put(key, _bundle(jobs + [("g", "alz")], epoch))

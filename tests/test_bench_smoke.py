@@ -17,9 +17,8 @@ import sys
 
 import pytest
 
-sys.path.insert(0, os.path.join(
-    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-    "scripts"))
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+sys.path.insert(0, os.path.join(REPO, "scripts"))
 
 
 @pytest.mark.slow
@@ -321,6 +320,20 @@ def test_bench_sched_trace_overhead_gate():
         f"{res['trace_overhead_ratio']})")
 
 
+def test_bench_refuses_a_backend_that_is_not_the_chip():
+    """bench.py records chip numbers: on any other backend it exits
+    non-zero before any leg, prints no headline, and never imports JAX
+    in the parent (a probe child names the backend)."""
+    import subprocess
+    out = subprocess.run(
+        [sys.executable, os.path.join(REPO, "bench.py"), "--quick"],
+        cwd=REPO, env=dict(os.environ, JAX_PLATFORMS="cpu"),
+        capture_output=True, text=True, timeout=300)
+    assert out.returncode == 2, out.stderr[-2000:]
+    assert out.stdout == ""
+    assert "refusing" in out.stderr and "'cpu'" in out.stderr
+
+
 def test_bench_query_smoke():
     """Tier-1 smoke for the read-plane bench: a short run against one
     py-logd shard with concurrent readers and a full-drain writer must
@@ -332,8 +345,11 @@ def test_bench_query_smoke():
         import bench_query
         # >= 3 readers: shapes are reader-dedicated round-robin, so
         # fewer readers would leave a shape undriven
+        # the window opens at the writer's first beat (not at its
+        # spawn), and is long enough for each reader's own connect +
+        # first query on a host shared with five other xdist workers
         res = bench_query.run_query_bench(
-            logd_shards=1, readers=3, seconds=1.5, seed_records=1000,
+            logd_shards=1, readers=3, seconds=3.0, seed_records=1000,
             on_log=lambda *a: print(*a, file=sys.stderr))
     finally:
         os.environ.pop("BENCH_LOGD", None)

@@ -35,6 +35,38 @@ def test_extend_and_substitution(tmp_path):
     assert cfg.log_db == str(tmp_path / "x.db")  # @pwd@ expanded
 
 
+@pytest.mark.parametrize("env_dir", [None, "somewhere-else"])
+def test_compile_cache_is_placed_from_outside(tmp_path, env_dir):
+    """JAX_COMPILATION_CACHE_DIR set: the program sets NO directory (JAX
+    reads the variable).  Unset: the one fixed path inside the checkout,
+    which is also conf.compile_cache's default.  A child process, so the
+    persistent cache stays off in this one."""
+    import os
+    import subprocess
+    import sys
+    from cronsun_tpu.conf import COMPILE_CACHE_DIR
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert COMPILE_CACHE_DIR == os.path.join(repo, ".jax_cache")
+    assert parse(None).compile_cache == COMPILE_CACHE_DIR
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    want = COMPILE_CACHE_DIR
+    if env_dir:
+        want = env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / env_dir)
+    out = subprocess.run(
+        [sys.executable, "-c",
+         "import jax\n"
+         "from cronsun_tpu.bin.common import enable_compile_cache\n"
+         "enable_compile_cache()\n"
+         "print(jax.config.jax_compilation_cache_dir)"],
+        cwd=repo, env=env, capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
+    assert out.stdout.strip().splitlines()[-1] == want
+    # with the variable set nothing is created at the in-code path's
+    # expense: the directory named from outside is JAX's to make
+    assert not env_dir or not os.path.exists(want)
+
+
 def test_nested_sections(tmp_path):
     p = tmp_path / "c.json"
     p.write_text(json.dumps({

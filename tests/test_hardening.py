@@ -184,3 +184,30 @@ def test_hostsync_unlogged_mutator_raises():
         proxy.set_table
     with pytest.raises(RuntimeError, match="op-log"):
         proxy.decay_load
+
+
+def test_sched_names_its_device_and_first_window_impl():
+    """bin.sched's line before READY: the device as JAX reports it and
+    the kernel variant the planner resolves for its first window — on
+    this CPU backend choose_impl answers "jnp", which is exactly what
+    chip_smoke.py must be able to see and refuse."""
+    import jax
+    from cronsun_tpu.bin.sched import describe_device
+    from cronsun_tpu.ops.planner import TickPlanner
+    from cronsun_tpu.parallel.mesh import ShardedTickPlanner, make_mesh
+    want = {"platform": "cpu", "device_kind": jax.devices()[0].device_kind,
+            "count": len(jax.devices()), "impl": "jnp"}
+    assert describe_device(TickPlanner(512, 64)) == want
+    assert describe_device(ShardedTickPlanner(
+        make_mesh(2), job_capacity=1024, node_capacity=64)) == want
+    orig = jax.default_backend
+    try:
+        jax.default_backend = lambda: "tpu"
+        # a fresh planner's first window runs at the 65,536 bucket
+        assert describe_device(TickPlanner(1 << 17, 10240))["impl"] \
+            == "pallas"
+        assert describe_device(TickPlanner(512, 64, max_fire_bucket=2048)
+                               )["impl"] == "mixed"
+    finally:
+        jax.default_backend = orig
+

@@ -287,6 +287,66 @@ def test_serial_step_resolves_pipelined_replan_futures():
 # CI smoke: a small pipelined bench config must show real overlap
 # ---------------------------------------------------------------------------
 
+def test_stop_during_a_long_step_abdicates_first_and_tears_down_last():
+    """SIGTERM while a step is in flight: leadership is released at
+    once (a successor takes over while the step drains), and the planes
+    under the step are torn down only after it has run to its end — a
+    step thread still inside the runtime at teardown is what used to
+    abort the process."""
+    import threading
+    store = MemStore()
+    sched = SchedulerService(store, job_capacity=64, node_capacity=8,
+                             window_s=1, node_id="stop-sched")
+    assert sched.try_lead()
+    leader_key = sched._leader_key
+    assert store.get(leader_key) is not None
+    order, in_step, release = [], threading.Event(), threading.Event()
+
+    def long_step():
+        in_step.set()
+        release.wait(10)
+        order.append("step ends")
+    sched.step = long_step
+    real_stop = sched.publisher.stop
+    sched.publisher.stop = lambda: (order.append("teardown"), real_stop())
+    sched.start()
+    assert in_step.wait(5)
+    stopper = threading.Thread(target=sched.stop)
+    stopper.start()
+    deadline = time.monotonic() + 5
+    while store.get(leader_key) is not None and time.monotonic() < deadline:
+        time.sleep(0.02)
+    assert store.get(leader_key) is None, \
+        "leadership held while the in-flight step drains"
+    assert stopper.is_alive() and order == [], \
+        "teardown began under the running step"
+    release.set()
+    stopper.join(10)
+    assert not stopper.is_alive()
+    assert order == ["step ends", "teardown"]
+    assert sched._thread is None and not sched.is_leader
+    store.close()
+
+
+def test_bench_seed_takes_a_seed_and_a_command():
+    """chip_smoke.py's use of the one seeder: the generator follows
+    ``seed`` and the command is the caller's."""
+    from cronsun_tpu.store import MemStore
+    from scripts.bench_sched import seed
+
+    def docs(**kw):
+        store = MemStore()
+        seed(store, KS, 200, 16, on_log=lambda *a: None, **kw)
+        assert len(store.get_prefix(KS.node)) == 16
+        return {kv.key: kv.value for kv in store.get_prefix(KS.cmd)}
+    cmd = "printenv CRONSUN_SCHEDULED_TS"
+    a, b, c = docs(seed=3, command=cmd), docs(seed=3, command=cmd), \
+        docs(seed=4, command=cmd)
+    assert all(f'"command":"{cmd}"' in v for v in a.values())
+    assert a == b and a != c and len(a) == 200
+    assert all('"command":"true"' in v for v in docs().values())
+
+
 def test_pipeline_smoke_bench_cpu():
     """Tier-1 regression tripwire for the pipeline itself: a small-scale
     pipelined bench config (networked py store, bench seed mix, paced
