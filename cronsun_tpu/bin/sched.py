@@ -10,6 +10,7 @@ import os
 import sys
 
 from .. import events, log
+from ..metrics import PhaseClock
 from ..sched import SchedulerService
 from .common import base_parser, connect_store, setup_common
 
@@ -82,12 +83,19 @@ def describe_device(planner) -> dict:
 
 
 def main(argv=None) -> int:
+    # the cold-load phases (cold_*_s gauges) count from where the OS
+    # started the process, not from here: the interpreter, the imports
+    # above and a launcher's own work come first
+    cold = PhaseClock.from_process_start()
     ap = base_parser(__doc__)
     ap.add_argument("--node-id", default="scheduler-1")
     ap.add_argument("--profile-port", type=int, default=0, metavar="PORT",
-                    help="start a jax.profiler server (TensorBoard-"
-                         "connectable) so tick/assign spans can be captured "
-                         "live; 0 disables")
+                    help="start a jax.profiler server (TensorBoard / "
+                         "xprof connect to it) that captures the "
+                         "scheduler's cronsun.step.* / build.* / "
+                         "publish.* / plan.* spans on one timeline with "
+                         "the device's operations (docs/OPERATIONS.md); "
+                         "0 disables")
     ap.add_argument("--mesh", type=int, default=0, metavar="D",
                     help="shard the planner over a D-device jobs mesh "
                          "(0 = single chip)")
@@ -180,8 +188,12 @@ def main(argv=None) -> int:
     if cfg.compile_cache:
         from .common import enable_compile_cache
         enable_compile_cache()
+    import jax
+    # the backend starts HERE, where it is stamped, and not inside the
+    # planner's first array
+    jax.devices()
+    cold.mark("startup")
     if args.profile_port:
-        import jax
         jax.profiler.start_server(args.profile_port)
         log.infof("jax profiler server on :%d", args.profile_port)
 
@@ -211,6 +223,7 @@ def main(argv=None) -> int:
                   "%s demand)", args.mesh,
                   "bucket-sharded" if shard_bids else "replicated",
                   args.mesh_demand_format)
+    cold.mark("planner")
     if args.mesh_hosts > 1 and args.mesh_proc_id > 0:
         # mesh worker: no store, no leadership — replay the leader's
         # broadcast deltas and join its collective plans until told to
@@ -276,7 +289,7 @@ def main(argv=None) -> int:
         delta_max_chain=cfg.checkpoint_rebase_chain,
         delta_max_bytes=cfg.checkpoint_rebase_bytes,
         trace_shift=cfg.trace_sample_shift,
-        partitions=args.partitions, partition=args.partition)
+        partitions=args.partitions, partition=args.partition, cold=cold)
     sched.start()
     health = None
     if args.health_port:
@@ -302,6 +315,7 @@ def main(argv=None) -> int:
                   args.node_id, args.store, cfg.timezone)
     log.infof("device %s", json.dumps(describe_device(sched.planner)))
     print(f"READY {args.node_id}", flush=True)
+    cold.mark("ready")      # cold_total_s ends here
     if sync_proxy is not None:
         # stop order matters: join the service loop FIRST so no plan
         # broadcast can interleave with the workers' release

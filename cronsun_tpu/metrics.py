@@ -12,6 +12,7 @@ failure-must-not-stall-the-caller rule.
 from __future__ import annotations
 
 import json
+import os
 import threading
 import time
 from typing import Callable, Dict, Optional
@@ -94,6 +95,124 @@ class LatencyRing:
         if not vals:
             return 0.0
         return vals[min(len(vals) - 1, int(p * len(vals)))]
+
+    def sum(self) -> float:
+        return float(sum(self._v))
+
+
+class _Span:
+    """One timed block of a :class:`Spans` holder; ``ms`` is set when
+    the block ends."""
+
+    __slots__ = ("_holder", "_key", "_into", "_note", "_t0", "ms")
+
+    def __init__(self, holder, key, into, note, since):
+        self._holder, self._key, self._into = holder, key, into
+        self._note, self._t0, self.ms = note, since, 0.0
+
+    def __enter__(self):
+        if self._note is not None:
+            self._note.__enter__()
+        if self._t0 is None:
+            self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        self.ms = (time.perf_counter() - self._t0) * 1e3
+        if self._note is not None:
+            self._note.__exit__(*exc)
+        if self._into is not None:
+            self._into[self._key] = self._into.get(self._key, 0.0) + self.ms
+        else:
+            self._holder.ring(self._key).add(self.ms)
+        return False
+
+
+class Spans:
+    """The one timing primitive of a component's phases.  ``with
+    spans.span(name, **ids):`` adds the block's duration (ms) to the
+    :class:`LatencyRing` of that name and, when the holder was built
+    with an annotation factory, shows the block on the profiler's
+    timeline as ``cronsun.<layer>.<name>`` with ``ids`` as its event
+    stats — the same clock the device trace is on.  The scheduler
+    passes ``jax.profiler.TraceAnnotation``; this module never imports
+    JAX (agents, store, logd and web import it), and a holder without
+    a factory only times.
+
+    Spans sit at step / window / batch granularity — never per fire,
+    per order or per key — and are LEAVES: at most one open per thread
+    (a trace reduction joins every overlapping name into the label of
+    an idle gap, so an enclosing span would sit on every label).
+
+    ``ring`` names the ring where it differs from the span's name;
+    ``into`` collects the duration in a dict instead (summed per name:
+    the step commits its spans at its end, a standby's are dropped);
+    ``since`` is a ``time.perf_counter()`` reading to measure from
+    where the work began on another thread."""
+
+    def __init__(self, layer: str, annotate: Optional[Callable] = None,
+                 rings: Optional[Dict[str, LatencyRing]] = None):
+        self.layer = layer
+        self.rings: Dict[str, LatencyRing] = {} if rings is None else rings
+        self._annotate = annotate
+
+    def ring(self, name: str) -> LatencyRing:
+        ring = self.rings.get(name)
+        if ring is None:
+            ring = self.rings[name] = LatencyRing()
+        return ring
+
+    def span(self, name: str, ring: Optional[str] = None,
+             into: Optional[dict] = None, since: Optional[float] = None,
+             **ids) -> _Span:
+        note = (self._annotate(f"cronsun.{self.layer}.{name}", **ids)
+                if self._annotate is not None else None)
+        return _Span(self, ring or name, into, note, since)
+
+    def commit(self, spans: Dict[str, float]) -> None:
+        for name, ms in spans.items():
+            self.ring(name).add(ms)
+
+
+def process_age_s() -> Optional[float]:
+    """Seconds since the OS started this process (``starttime`` of
+    ``/proc/self/stat`` against ``/proc/uptime``; 10 ms ticks), or None
+    where there is no ``/proc``."""
+    try:
+        with open("/proc/self/stat") as f:
+            after_comm = f.read().rsplit(")", 1)[1].split()
+        with open("/proc/uptime") as f:
+            uptime = float(f.read().split()[0])
+        # field 22 of the line; the split starts at field 3 (state)
+        started = int(after_comm[19]) / os.sysconf("SC_CLK_TCK")
+        return max(0.0, uptime - started)
+    except (OSError, ValueError, IndexError):
+        return None
+
+
+class PhaseClock:
+    """Consecutive phases of one start-up on one clock: ``mark(name)``
+    gives the time since the previous mark (or the start) to ``name``,
+    so the phases tile the stretch from the start to the last mark and
+    nothing in it goes unnamed.  ``from_process_start`` starts the
+    clock where the OS started the process — before the interpreter,
+    the imports and any launcher."""
+
+    def __init__(self, age_s: float = 0.0):
+        self._last = self.t0 = time.monotonic() - age_s
+        self.seconds: Dict[str, float] = {}
+
+    @classmethod
+    def from_process_start(cls) -> "PhaseClock":
+        return cls(process_age_s() or 0.0)
+
+    def mark(self, name: str) -> None:
+        now = time.monotonic()
+        self.seconds[name] = self.seconds.get(name, 0.0) + now - self._last
+        self._last = now
+
+    def total(self) -> float:
+        return self._last - self.t0
 
 
 def parse_exposition(text: str):

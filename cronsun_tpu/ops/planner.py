@@ -379,6 +379,12 @@ class TickPlanner:
             raise ValueError(f"table capacity {table.capacity} != {self.J}")
         self.table = table
 
+    def block_until_ready(self) -> None:
+        """Wait until the device holds every update scattered so far
+        (the setters below dispatch asynchronously)."""
+        jax.block_until_ready((self.table, self.elig, self.exclusive,
+                               self.cost, self.load, self.rem_cap))
+
     def update_table_rows(self, rows: np.ndarray, vals) -> None:
         """Scatter schedule-row updates — the planner-agnostic mutator
         the scheduler (and the mesh-sync replay) drive; subclasses
@@ -605,7 +611,10 @@ class TickPlanner:
             f["sec"], f["min"], f["hour"], f["dom"], f["month"], f["dow"],
             np.arange(window_s, dtype=np.int64) + (epoch_s - FRAMEWORK_EPOCH),
         ], axis=1).astype(np.int32)                     # [W, 7]
-        with jax.profiler.TraceAnnotation("cronsun.plan.dispatch"):
+        # w: the first second the window covers — the id the window's
+        # spans share from here to its HWM (sched/service.py)
+        with jax.profiler.TraceAnnotation("cronsun.plan.dispatch",
+                                          w=epoch_s):
             # + 0.0 / | 0: the jit donates its load/rem_cap/last_fire
             # args, and the dispatch may run on the scheduler's dispatch
             # thread while the step thread scatters capacity/load
@@ -645,7 +654,7 @@ class TickPlanner:
         fires follow with assigned = -1 (fan-out is the dispatcher's job).
         """
         epoch_s, kx, kc, outs32, outs16, outs_t = handle
-        with jax.profiler.TraceAnnotation("cronsun.plan.gather"):
+        with jax.profiler.TraceAnnotation("cronsun.plan.gather", w=epoch_s):
             # one fetch per window: the gather is the pipeline's only
             # host<->device synchronization point
             o, oa, ot = jax.device_get((outs32, outs16, outs_t))

@@ -46,13 +46,18 @@ from typing import Callable, List, Optional, Sequence, Tuple
 
 from .. import log
 from ..core.backoff import PUBLISH, PUBLISH_ATTEMPTS
+from ..metrics import Spans
 
 
 class OrderPublisher:
     def __init__(self, lanes: Sequence, advance_hwm: Callable[[int], None],
                  chunk: int = 20_000, max_backlog: int = 2,
-                 shard_of: Optional[Callable[[str], int]] = None):
+                 shard_of: Optional[Callable[[str], int]] = None,
+                 spans: Optional[Spans] = None):
         self._lane_conns = list(lanes)
+        # span "window" (w = the window's first second): dequeued ->
+        # the last second's orders in the store and its mark noted
+        self.spans = spans if spans is not None else Spans("publish")
         self._pools = [ThreadPoolExecutor(1, thread_name_prefix=f"pub{i}")
                        for i in range(len(self._lane_conns))]
         self._advance_hwm = advance_hwm
@@ -160,7 +165,7 @@ class OrderPublisher:
     # -- producer side -----------------------------------------------------
 
     def submit(self, seconds: List[Tuple[int, list]], lease: int,
-               hwm: int, covers_from=None) -> float:
+               hwm: int, covers_from=None) -> None:
         """Queue one window: ``seconds`` = [(epoch, [(key, val), ...])],
         oldest first; ``hwm`` is the mark to advance to once the whole
         window has landed.  ``covers_from`` is the CONTIGUOUS start of
@@ -169,14 +174,12 @@ class OrderPublisher:
         outstanding publish hole is the scheduler's rewound re-plan and
         clears the hole; anything else queued behind a hole is
         abandoned (and extends the hole to its own oldest second) so
-        the monotone HWM can never pass unpublished fires.  Returns
-        seconds spent blocked on backpressure."""
-        t0 = time.perf_counter()
+        the monotone HWM can never pass unpublished fires.  Blocks on
+        backpressure (the caller's span times it)."""
         self._sem.acquire()
         with self._mu:
             self._inflight += 1
         self._q.put((seconds, lease, hwm, covers_from))
-        return time.perf_counter() - t0
 
     def clear_failed_epoch_below(self, epoch: int) -> bool:
         """Clear an outstanding publish hole strictly OLDER than
@@ -349,13 +352,29 @@ class OrderPublisher:
             self._inflight -= 1
             self._idle.notify_all()
 
-    def _publish_window(self, seconds, lease, hwm, staged, t0):
+    def _publish_window(self, seconds, lease, hwm, staged, covers_from,
+                        since=None):
         """Publish (or, in shard-lane mode, barrier) one window:
         per-second completion strictly oldest-first, the mark moving
         ONLY once a second's orders are in the store — a crash between
         seconds re-plans the unpublished tail (a rare double fire
         beats silently missing one; fences/broadcast-dedup absorb the
-        dup)."""
+        dup).  ``since``: when the window was staged, on another
+        thread (shard-lane mode)."""
+        ids = {} if covers_from is None else {"w": covers_from}
+        span = self.spans.span("window", since=since, **ids)
+        try:
+            with span:
+                self._publish_seconds(seconds, lease, hwm, staged)
+        finally:
+            self.last_window_ms = span.ms
+            self.stats["publish_windows"] += 1
+            self._sem.release()
+            with self._idle:
+                self._inflight -= 1
+                self._idle.notify_all()
+
+    def _publish_seconds(self, seconds, lease, hwm, staged):
         n = len(self._pools)
         try:
             for si, (epoch, orders) in enumerate(seconds):
@@ -401,13 +420,6 @@ class OrderPublisher:
             log.errorf("window publish failed: %s", e)
             if seconds:
                 self._mark_failed(seconds[0][0])
-        finally:
-            self.last_window_ms = (time.perf_counter() - t0) * 1e3
-            self.stats["publish_windows"] += 1
-            self._sem.release()
-            with self._idle:
-                self._inflight -= 1
-                self._idle.notify_all()
 
     def _run(self):
         while True:
@@ -417,13 +429,12 @@ class OrderPublisher:
                     self._bq.put(None)
                 return
             seconds, lease, hwm, covers_from = item
-            t0 = time.perf_counter()
             if self._bq is None:
                 if self._check_hole(covers_from):
                     self._abandon(seconds)
                     continue
-                self._publish_window(seconds, lease, hwm, staged=None,
-                                     t0=t0)
+                self._publish_window(seconds, lease, hwm, None,
+                                     covers_from)
             else:
                 if self._peek_hole_stale(covers_from):
                     # stale window behind an uncleared hole: abandon at
@@ -440,6 +451,7 @@ class OrderPublisher:
                 # shard's legs (the pre-decoupling structural term:
                 # the LAST second of every window paid ~2·window_s·
                 # delay behind one slow shard)
+                t0 = time.perf_counter()
                 staged = self._stage_sharded(seconds, lease)
                 self._bq.put((seconds, staged, hwm, covers_from, t0))
 
@@ -469,8 +481,8 @@ class OrderPublisher:
                             pass           # already counted failures
                 self._abandon(seconds)
                 continue
-            self._publish_window(seconds, lease=0, hwm=hwm,
-                                 staged=staged, t0=t0)
+            self._publish_window(seconds, 0, hwm, staged, covers_from,
+                                 since=t0)
 
 
 class WindowBuilder:
@@ -494,8 +506,10 @@ class WindowBuilder:
     racing it."""
 
     def __init__(self, build_fn: Callable[[object], None],
-                 max_depth: int = 2):
+                 max_depth: int = 2, spans: Optional[Spans] = None):
         self._build_fn = build_fn
+        # the submitting thread's holder: span "stall"
+        self._spans = spans if spans is not None else Spans("step")
         self.max_depth = max_depth
         self._sem = threading.Semaphore(max_depth)
         self._q: "queue.Queue" = queue.Queue()
@@ -512,22 +526,20 @@ class WindowBuilder:
         """Windows queued or being built in this stage right now."""
         return self._inflight
 
-    def submit(self, item) -> float:
-        """Queue one window for build+publish; returns seconds spent
-        blocked on this stage's depth cap (0.0 when the pipeline kept
-        up)."""
-        stall = 0.0
-        if not self._sem.acquire(blocking=False):
-            t0 = time.perf_counter()
-            self._sem.acquire()
-            stall = time.perf_counter() - t0
-            with self._mu:
-                self.stats["stalls_total"] += 1
-                self.stats["stall_ms_total"] += stall * 1e3
+    def submit(self, item, into: Optional[dict] = None, **ids) -> None:
+        """Queue one window for build+publish, blocking on this stage's
+        depth cap.  The hand-off is the caller's "stall" span (``into``,
+        ``ids``: see :meth:`Spans.span`); a blocked one is counted."""
+        with self._spans.span("stall", into=into, **ids) as span:
+            blocked = not self._sem.acquire(blocking=False)
+            if blocked:
+                self._sem.acquire()
         with self._mu:
+            if blocked:
+                self.stats["stalls_total"] += 1
+                self.stats["stall_ms_total"] += span.ms
             self._inflight += 1
         self._q.put(item)
-        return stall
 
     def flush(self, timeout: float = 120.0) -> bool:
         """Block until every submitted window has been built and handed

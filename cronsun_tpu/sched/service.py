@@ -29,15 +29,20 @@ import os
 import re
 import threading
 import time
+import weakref
 from operator import itemgetter
 from typing import Callable, Dict, List, NamedTuple, Optional, Set, Tuple
 
 import numpy as np
 
+from jax import monitoring as _jax_monitoring
+from jax.profiler import TraceAnnotation
+
 from .. import log
 from ..core import Group, Job, Keyspace, TenantQuota
 from ..core.models import KIND_ALONE
 from ..cron.parser import ParseError, parse
+from ..metrics import LatencyRing, MetricsPublisher, PhaseClock, Spans
 from ..ops.deps import NEVER as DEP_NEVER, POLICY_BY_NAME
 from ..ops.eligibility import EligibilityBuilder, NodeUniverse
 from ..ops.planner import TickPlanner
@@ -48,6 +53,31 @@ from ..store.memstore import CompactedError, DELETE, MemStore, PUT, \
 
 # ids that serialize into a JSON string verbatim (no escapes needed)
 _WIRE_SAFE = re.compile(r"^[A-Za-z0-9_.:-]*$").match
+
+# JAX's own duration events: the first wraps every executable the
+# process obtains (compiled, or loaded from the persistent cache), the
+# second is recorded only for a load
+_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+_CACHE_LOAD_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
+# ONE listener a process (JAX keeps listeners for the process's life and
+# tests build many services); it hands each event to the live services
+_compile_sinks: "weakref.WeakSet[SchedulerService]" = weakref.WeakSet()
+_compile_listening = False
+
+
+def _on_compile_event(event: str, secs: float, **_kw):
+    if event == _COMPILE_EVENT or event == _CACHE_LOAD_EVENT:
+        for svc in list(_compile_sinks):
+            svc._count_compile(event, secs)
+
+
+def _listen_for_compiles(svc: "SchedulerService"):
+    global _compile_listening
+    _compile_sinks.add(svc)
+    if not _compile_listening:
+        _compile_listening = True
+        _jax_monitoring.register_event_duration_secs_listener(
+            _on_compile_event)
 
 
 class _BuildItem(NamedTuple):
@@ -131,6 +161,7 @@ class SchedulerService:
                  partitions: int = 1,
                  partition: int = 0,
                  acct_exchange_s: float = 2.0,
+                 cold: Optional[PhaseClock] = None,
                  clock: Callable[[], float] = time.time):
         self.store = store
         self.ks = ks or Keyspace()
@@ -140,6 +171,10 @@ class SchedulerService:
         self.dispatch_ttl = dispatch_ttl
         self.default_node_cap = default_node_cap
         self.node_id = node_id
+        # cold-load phases, one clock from the OS's process start
+        # (bin/sched hands it in, past the JAX import and the backend's
+        # start) to READY; a service built in-process starts its own
+        self.cold = cold if cold is not None else PhaseClock()
 
         # ---- partitioned scheduler plane --------------------------------
         # P independent leaders, each owning the job-space slice whose
@@ -191,6 +226,7 @@ class SchedulerService:
         self._acct_next = 0.0
         self._w_acct = None
 
+        self.cold.mark("lists")     # connect + the partition-map pin
         planner_kw = {} if tz is None else {"tz": tz}
         self.planner = planner or TickPlanner(
             job_capacity=job_capacity, node_capacity=node_capacity,
@@ -198,6 +234,7 @@ class SchedulerService:
         self.universe = NodeUniverse(self.planner.N)
         self.builder = EligibilityBuilder(self.universe, self.planner.J)
         self.rows = _Rows(self.planner.J)
+        self.cold.mark("planner")
         self.jobs: Dict[Tuple[str, str], Job] = {}
         self.groups: Dict[str, Group] = {}
         self.node_caps: Dict[str, int] = {}
@@ -515,9 +552,29 @@ class SchedulerService:
             else:
                 lanes = [store]
                 self._owned_lanes = []
+        # the one timing primitive (metrics.Spans): a phase is a sample
+        # in the ring of its name — what the step_span_* gauges read —
+        # AND a cronsun.<layer>.<name> event on the profiler's timeline,
+        # the clock the device trace shares.  Leaves only, one open per
+        # thread, at step / window granularity.  ``_timers`` is the
+        # ring-only holder for the two stretches the planner annotates
+        # itself (cronsun.plan.dispatch / .gather).
+        self._tick_ms = LatencyRing()
+        self._step_ms = LatencyRing()        # full step() cycle latencies
+        self._step_cpu_ms = LatencyRing()    # ... and their thread CPU
+        self._step_spans: Dict[str, float] = {}   # last step's phase ms
+        # per-span latency distributions (p50/p99 per phase, including
+        # the builder-side gather/build/submit stages)
+        self._span_hist: Dict[str, LatencyRing] = {}
+        self._spans = Spans("step", TraceAnnotation, rings=self._span_hist)
+        self._build_spans = Spans("build", TraceAnnotation,
+                                  rings=self._span_hist)
+        self._timers = Spans("plan", rings=self._span_hist)
+        self._warm_spans = Spans("warm", TraceAnnotation)
         from .publisher import OrderPublisher, WindowBuilder
-        self.publisher = OrderPublisher(lanes, self._advance_hwm,
-                                        shard_of=shard_of)
+        self.publisher = OrderPublisher(
+            lanes, self._advance_hwm, shard_of=shard_of,
+            spans=Spans("publish", TraceAnnotation))
         # in-process stores (tests, demo) publish synchronously: their
         # put_many is microseconds and callers assert store contents
         # right after step(); the networked path keeps the overlap
@@ -536,7 +593,8 @@ class SchedulerService:
         # baseline / rollback switch).
         self.pipelined = (hasattr(self.planner, "plan_window_async")
                           if pipelined is None else pipelined)
-        self._builder = WindowBuilder(self._build_window)
+        self._builder = WindowBuilder(self._build_window,
+                                      spans=self._spans)
         # builder -> step hand-backs (thread-safe via GIL deque ops):
         # completed-window accounting (mirror adds, fire counts, stage
         # spans) and overflow-replan requests (the DEVICE dispatch must
@@ -552,12 +610,19 @@ class SchedulerService:
         from concurrent.futures import ThreadPoolExecutor
         self._dispatch_pool = ThreadPoolExecutor(
             1, thread_name_prefix="plan-dispatch")
-        self._dispatch_ms: "collections.deque" = collections.deque()
-        # pipeline overlap accounting: step-thread wall vs builder busy
+        # pipeline overlap accounting: step-thread wall vs the work off
+        # it — one writer each (step, dispatch thread, build worker)
         self._pl_step_ms = 0.0
-        self._pl_offstep_ms = 0.0
+        self._pl_dispatch_ms = 0.0
+        self._pl_build_ms = 0.0
         self._warm_thread: Optional[threading.Thread] = None
         self._warmed = False
+        self._warm_s = 0.0
+        # fresh leadership -> the first window's HWM acknowledged:
+        # the step that wins the lease notes (when, the mark to wait
+        # for); the publisher's hwm thread closes it (_advance_hwm)
+        self._first_pub_wait: Optional[Tuple[float, int]] = None
+        self._first_publish_s = 0.0
 
         self._leader_lease: Optional[int] = None
         # lease watchdog: wall time of the last keepalive CONFIRM,
@@ -577,23 +642,31 @@ class SchedulerService:
                       "skipped_seconds": 0,
                       "watch_losses": 0, "dispatches_total": 0,
                       "steps_total": 0, "lease_resigns_total": 0,
-                      "acct_exchanges_total": 0}
+                      "acct_exchanges_total": 0,
+                      # executables obtained (compiled, or loaded from
+                      # the persistent cache: cache_loads_total of
+                      # them) and the seconds that took; _leading: while
+                      # this scheduler led, on any thread but the warm
+                      # thread — a stall of the served path
+                      "compiles_total": 0, "compile_s_total": 0.0,
+                      "cache_loads_total": 0, "compiles_leading_total": 0,
+                      # fires the order build drops without a trace
+                      # downstream: an Alone fire whose lock the mirror
+                      # showed live at build time; an exclusive fire
+                      # placed on a node that left the fleet since
+                      "alone_left_out_total": 0,
+                      "fires_node_gone_total": 0}
+        self._compile_mu = threading.Lock()
+        _listen_for_compiles(self)
         # herd gauges, tracked where orders are built: the most
         # EXCLUSIVE (per-node) keys any one second published — bounded
         # by active nodes under coalescing, it was one per fire before —
         # and the most exclusive fires those keys carried
         self.max_second_node_keys = 0
         self.max_second_excl_fires = 0
-        # operator metrics: recent device-plan latencies (ring) published
-        # via the shared leased-snapshot protocol (a dead scheduler's
-        # snapshot expires instead of going stale)
-        from ..metrics import LatencyRing, MetricsPublisher
-        self._tick_ms = LatencyRing()
-        self._step_ms = LatencyRing()        # full step() cycle latencies
-        self._step_spans: Dict[str, float] = {}   # last step's phase ms
-        # per-span latency distributions (p50/p99 per phase, including
-        # the builder-side gather/build/submit stages)
-        self._span_hist: Dict[str, LatencyRing] = {}
+        # operator metrics, published via the shared leased-snapshot
+        # protocol (a dead scheduler's snapshot expires instead of
+        # going stale)
         self.metrics = MetricsPublisher(
             store, self.ks, "sched", self.node_id, self.metrics_snapshot,
             interval_s=5.0, clock=clock)
@@ -622,9 +695,12 @@ class SchedulerService:
         restored = False
         if checkpoint_dir:
             restored = self._checkpoint_restore()
+            if restored:
+                # checkpoint_restore_ms holds it; lists/jobs read 0
+                self.cold.mark("restore")
         if not restored:
             self._open_watches()
-            self._load_initial()
+            self._load_initial(cold=self.cold)
         # start recording the delta stream only once the slate is known
         # (a restore's chain fold must not re-enter the buffer); the
         # watch tail replayed after a warm restore drains through
@@ -769,9 +845,11 @@ class SchedulerService:
 
     # ---- bootstrap (reference loadJobs, node/node.go:121-141) ------------
 
-    def _load_initial(self, groups=None, nodes=None, jobs=None):
+    def _load_initial(self, groups=None, nodes=None, jobs=None,
+                      cold: Optional[PhaseClock] = None):
         """Apply the store's current contents; prefetched KV lists avoid
-        re-listing when the caller (resync) already has them.
+        re-listing when the caller (resync) already has them.  ``cold``
+        (the constructor's cold load only) takes the phase marks.
 
         Bulk-load fast path: @every phase anchors are prefetched in ONE
         prefix listing and missing ones written back in ONE put_many —
@@ -841,6 +919,8 @@ class SchedulerService:
             kv.key: kv.value
             for kv in _list_prefix(self.store, self.ks.phase)}
         self._phase_puts = []
+        if cold is not None:
+            cold.mark("lists")      # watches + every listing but cmd
         try:
             for kv in (jobs if jobs is not None
                        else _list_prefix(self.store, self.ks.cmd)):
@@ -850,8 +930,18 @@ class SchedulerService:
                 self.store.put_many(self._phase_puts[i:i + 50_000])
             self._phase_prefetch = None
             self._phase_puts = None
+        if cold is not None:
+            cold.mark("jobs")
         self._mirror_antientropy()
         self._flush_device()
+        if cold is not None:
+            # the scatters are asynchronous dispatches: wait until the
+            # device holds the table, or the wait shows up unnamed in
+            # the first plan
+            sync = getattr(self.planner, "block_until_ready", None)
+            if sync is not None:
+                sync()
+            cold.mark("device")
 
     # ---- leadership ------------------------------------------------------
 
@@ -3023,9 +3113,12 @@ class SchedulerService:
 
         def run():
             try:
-                now = int(self.clock())
-                self.planner.warm_window(now + 1, max(1, self.window_s))
-                k = self.planner.warm_escalation(now + 1)
+                with self._warm_spans.span("compile") as sp:
+                    now = int(self.clock())
+                    self.planner.warm_window(now + 1,
+                                             max(1, self.window_s))
+                    k = self.planner.warm_escalation(now + 1)
+                self._warm_s = sp.ms / 1e3
                 log.infof("plan executables warmed (window + "
                           "escalation bucket %d)", k)
             except Exception as e:  # noqa: BLE001 — degraded, not down
@@ -3036,6 +3129,19 @@ class SchedulerService:
         self._warm_thread = threading.Thread(
             target=run, daemon=True, name="sched-plan-warm")
         self._warm_thread.start()
+
+    def _count_compile(self, event: str, secs: float):
+        """One of JAX's compile events (any thread; see
+        _on_compile_event)."""
+        with self._compile_mu:
+            if event == _CACHE_LOAD_EVENT:
+                self.stats["cache_loads_total"] += 1
+                return
+            self.stats["compiles_total"] += 1
+            self.stats["compile_s_total"] += secs
+            if self.is_leader and \
+                    threading.current_thread() is not self._warm_thread:
+                self.stats["compiles_leading_total"] += 1
 
     # ---- capacity reconciliation ----------------------------------------
 
@@ -3121,45 +3227,97 @@ class SchedulerService:
         """
         now = int(now if now is not None else self.clock())
         t_step = time.perf_counter()
-        spans = {}
-
-        def span(name, since):
-            t = time.perf_counter()
-            spans[name] = (t - since) * 1e3
-            return t
+        cpu0 = time.thread_time()
+        # this step's leaf spans, ms by name; they tile the step (what
+        # "total" holds beyond their sum is loop glue, well under a
+        # millisecond) and reach the rings only when the step led
+        spans: Dict[str, float] = {}
+        sp, n = self._spans, self.stats["steps_total"]
         # WARM STANDBY: watches drain and mirrors/device state stay
         # current whether or not we lead — a standby that only started
         # syncing after winning the lease would pay the full cold load
         # (minutes at 1M jobs) as dispatch outage; a warm one takes over
         # within one step (VERDICT r3 #3)
-        self.drain_watches()
-        t = span("drain", t_step)
-        # build-stage hand-backs: completed-window accounting (mirror
-        # adds + fire counts) and overflow-replan dispatch requests (the
-        # device dispatch stays on this thread)
-        n_done = self._drain_build_acct()
-        self._drain_replan_reqs()
-        self._drain_tenant_q()
-        self._maybe_antientropy_bg()
-        self._maybe_checkpoint()
-        led_before = self.is_leader
-        if not self.try_lead():
-            self._next_epoch = None
-            self._pending_plan = None
-            self._builder.flush()
-            n_done += self._drain_build_acct()
+        with sp.span("drain", into=spans, n=n):
+            self.drain_watches()
+        with sp.span("lead", into=spans, n=n):
+            # build-stage hand-backs: completed-window accounting
+            # (mirror adds + fire counts) and overflow-replan dispatch
+            # requests (the device dispatch stays on this thread)
+            n_done = self._drain_build_acct()
             self._drain_replan_reqs()
-            self._drain_replans()
-            self._flush_device()
-            self._start_warm()   # standby warms in the background
+            self._drain_tenant_q()
+            self._maybe_antientropy_bg()
+            self._maybe_checkpoint()
+            led_before = self.is_leader
+            leading = self.try_lead()
+            t_lead = time.monotonic()
+            if not leading:
+                self._next_epoch = None
+                self._pending_plan = None
+                self._builder.flush()
+                n_done += self._drain_build_acct()
+                self._drain_replan_reqs()
+                self._drain_replans()
+        if not leading:
+            with sp.span("flush", into=spans, n=n):
+                self._flush_device()
+                self._start_warm()   # standby warms in the background
             # standbys still publish (throttled): "is my failover target
             # alive" is an operator question too
+            self._publish_snapshots(spans, n)
+            return 0
+        with sp.span("lead", into=spans, n=n):
+            self._lead_housekeeping(led_before)
+        with sp.span("reconcile", into=spans, n=n):
+            self.reconcile_capacity()
+            if self.partitions > 1:
+                # leaders announce their per-node demand so every OTHER
+                # partition's next reconcile subtracts it (O(active
+                # nodes) JSON once per exchange period, not per step)
+                self._publish_acct()
+        with sp.span("flush", into=spans, n=n):
+            self._flush_device()
+        with sp.span("cursor", into=spans, n=n):
+            start = self._plan_cursor(now)
+        window = max(1, self.window_s)
+        if not led_before:
+            self._first_pub_wait = (t_lead, start + window)
+        if self.pipelined:
+            n_dispatch = n_done + self._step_pipelined(start, window,
+                                                       spans, n)
+        else:
+            n_dispatch = n_done + self._step_serial(start, window, spans,
+                                                    n)
+        # full-cycle latency distribution: everything a real tick pays
+        # on the STEP thread (watch drain + reconcile + device flush +
+        # plan dispatch + build or hand-off + stall/backpressure), and
+        # the thread's CPU inside it (wall against CPU: does the step
+        # compute, or wait on the store, the device or the GIL)
+        spans["total"] = (time.perf_counter() - t_step) * 1e3
+        self._step_cpu_ms.add((time.thread_time() - cpu0) * 1e3)
+        self._step_spans = spans
+        self._step_ms.add(spans["total"])
+        self._pl_step_ms += spans["total"]
+        sp.commit(spans)
+        self.stats["steps_total"] += 1
+        self._drain_tenant_q()
+        self._publish_snapshots(None, n)
+        return n_dispatch
+
+    def _publish_snapshots(self, into: Optional[dict], n: int):
+        """The throttled leased-snapshot publishes (outside "total":
+        the snapshot a step publishes holds that step)."""
+        with self._spans.span("snapshot", into=into, n=n):
             self.metrics.maybe_publish()
             if self._mesh_metrics is not None:
                 self._mesh_metrics.maybe_publish()
             if self._tenants:
                 self._tenant_metrics.maybe_publish()
-            return 0
+
+    def _lead_housekeeping(self, led_before: bool):
+        """What a leading step does once the lease is confirmed and
+        before it plans (the second half of the "lead" span)."""
         if self.stats["steps_total"]:
             # escalation sizes warm while leading — but only after the
             # first window is out the door: on a small host the warm
@@ -3186,15 +3344,11 @@ class SchedulerService:
             # re-derives the in-flight deferred fires from a bounded
             # lookback once the cursor is known (below)
             self._smear_recovered = False
-        self.reconcile_capacity()
-        if self.partitions > 1:
-            # leaders announce their per-node demand so every OTHER
-            # partition's next reconcile subtracts it (O(active nodes)
-            # JSON once per exchange period, not per step)
-            self._publish_acct()
-        t = span("reconcile", t)
-        self._flush_device()
-        t = span("flush", t)
+
+    def _plan_cursor(self, now: int) -> int:
+        """The first second this step plans (the "cursor" span): the
+        carried cursor, or on fresh leadership the persisted HWM; a
+        publish hole rewinds it, max_catchup_s clamps it."""
         start = self._next_epoch
         fresh_cursor = start is None
         had_hwm = False
@@ -3255,53 +3409,61 @@ class SchedulerService:
                 # (no HWM) has no in-flight spill — and must not invent
                 # fires for seconds older than its own birth.
                 self._smear_recover(start)
-        window = max(1, self.window_s)
-        if self.pipelined:
-            n_dispatch = n_done + self._step_pipelined(start, window,
-                                                       spans)
-        else:
-            n_dispatch = n_done + self._step_serial(start, window, spans,
-                                                    span)
-        # full-cycle latency distribution: everything a real tick pays
-        # on the STEP thread (watch drain + reconcile + device flush +
-        # plan dispatch + build or hand-off + stall/backpressure)
-        spans["total"] = (time.perf_counter() - t_step) * 1e3
-        self._step_spans = spans
-        self._step_ms.add(spans["total"])
-        self._pl_step_ms += spans["total"]
-        for k, v in spans.items():
-            self._span_ring(k).add(v)
-        self.stats["steps_total"] += 1
-        self._drain_tenant_q()
-        self.metrics.maybe_publish()
-        if self._mesh_metrics is not None:
-            self._mesh_metrics.maybe_publish()
-        if self._tenants:
-            self._tenant_metrics.maybe_publish()
-        return n_dispatch
+        return start
 
     def _step_serial(self, start: int, window: int, spans: dict,
-                     span) -> int:
+                     n: int) -> int:
         """The serial plan->build->submit body (mesh planners, and the
         ``pipelined=False`` baseline/rollback switch)."""
-        t_plan = time.perf_counter()
-        if self._pending_plan is not None and self._pending_plan[0] == start:
-            plans = self.planner.gather_window(
-                self._resolve_handle(self._pending_plan[1]))
-        else:
-            plans = self.planner.plan_window(start, window)
-        self._pending_plan = None
-        self._tick_ms.add((time.perf_counter() - t_plan) * 1e3)
-        t = span("plan", t_plan)
+        sp = self._spans
+        with sp.span("plan", into=spans, n=n) as plan_span:
+            if self._pending_plan is not None and \
+                    self._pending_plan[0] == start:
+                plans = self.planner.gather_window(
+                    self._resolve_handle(self._pending_plan[1]))
+            else:
+                plans = self.planner.plan_window(start, window)
+            self._pending_plan = None
+        self._tick_ms.add(plan_span.ms)
         self._next_epoch = start + window
         # prefetch: next window's plan on device while THIS window's
         # orders are built and shipped (duck-typed: the mesh planners'
         # collective plan is a synchronized call and stays one)
         if hasattr(self.planner, "plan_window_async"):
-            self._pending_plan = (
-                self._next_epoch,
-                self.planner.plan_window_async(self._next_epoch, window))
-        lease = self.store.grant(self.dispatch_ttl)
+            with sp.span("dispatch", into=spans, n=n):
+                self._pending_plan = (
+                    self._next_epoch,
+                    self.planner.plan_window_async(self._next_epoch,
+                                                   window))
+        with sp.span("grant", into=spans, n=n):
+            lease = self.store.grant(self.dispatch_ttl)
+        with sp.span("build", into=spans, n=n):
+            seconds, excl_acct, n_dispatch = self._build_serial(
+                start, plans)
+        # hand the window to the async publisher: oldest second first,
+        # HWM advanced after each second lands (the publisher owns the
+        # write-then-mark ordering: a crash in between re-plans the
+        # unpublished tail — a rare double fire beats silently missing
+        # it; the mark itself is a monotone CAS so a deposed leader
+        # can't regress the new one's progress).  The span is the
+        # step's wait on the publisher — backpressure, and the whole
+        # publish where it is synchronous (in-process stores); the wire
+        # time is publish_window_ms in the metrics snapshot
+        with sp.span("publish", into=spans, n=n):
+            self.publisher.submit(seconds, lease, self._next_epoch,
+                                  covers_from=start)
+            if self.sync_publish:
+                self.publisher.flush()
+            # mirror own publishes locally (the orders watch is
+            # delete-only: our puts are not echoed back at us)
+            for key, node, jobs in excl_acct:
+                self._acct_add_order(key, node, jobs)
+        self.stats["dispatches_total"] += n_dispatch
+        return n_dispatch
+
+    def _build_serial(self, start: int, plans: list):
+        """The serial path's order build (its "build" span): (seconds,
+        exclusive accounting, fires built)."""
         seconds: List[Tuple[int, list]] = []
         excl_acct: List[Tuple[str, str, list]] = []
         wpend: Dict[int, int] = {}    # this window's admitted-excl
@@ -3354,68 +3516,51 @@ class SchedulerService:
             n_dispatch += self._build_plan_orders(plan, seconds,
                                                   excl_acct,
                                                   pending_excl=wpend)
-        t = span("build", t)
-        # hand the window to the async publisher: oldest second first,
-        # HWM advanced after each second lands (the publisher owns the
-        # write-then-mark ordering: a crash in between re-plans the
-        # unpublished tail — a rare double fire beats silently missing
-        # it; the mark itself is a monotone CAS so a deposed leader
-        # can't regress the new one's progress)
-        wait_s = self.publisher.submit(seconds, lease, self._next_epoch,
-                                       covers_from=start)
-        if self.sync_publish:
-            self.publisher.flush()
-        # mirror own publishes locally (the orders watch is delete-only:
-        # our puts are not echoed back at us)
-        for key, node, jobs in excl_acct:
-            self._acct_add_order(key, node, jobs)
-        spans["publish"] = wait_s * 1e3   # backpressure only; the wire
-                                          # time is publish_window_ms in
-                                          # the metrics snapshot
-        self.stats["dispatches_total"] += n_dispatch
-        return n_dispatch
+        return seconds, excl_acct, n_dispatch
 
     def _step_pipelined(self, start: int, window: int,
-                        spans: dict) -> int:
+                        spans: dict, n: int) -> int:
         """The pipelined body: dispatch this window's plan (usually
         already in flight from the previous step — the double buffer),
         dispatch the NEXT window's plan, and hand the current handle to
         the build worker.  The gather, the order build and the publisher
         submit all run OFF this thread; the only blocking here is the
         builder's depth cap (``stall`` span) when the plane is behind."""
-        t0 = time.perf_counter()
-        if self._pending_plan is not None and \
-                self._pending_plan[0] == start:
-            handle = self._pending_plan[1]
-        else:
-            # cold start / hole rewind / clamp moved the cursor: the
-            # prefetched plan covers the wrong seconds — drop it and
-            # dispatch the right one (the wasted device work is the
-            # rewind's price, not the steady state's)
-            handle = self._dispatch_plan(start, window)
-        self._pending_plan = None
-        self._next_epoch = start + window
-        self._pending_plan = (
-            self._next_epoch,
-            self._dispatch_plan(self._next_epoch, window))
-        spans["dispatch"] = (time.perf_counter() - t0) * 1e3
-        lease = self.store.grant(self.dispatch_ttl)
+        sp = self._spans
+        with sp.span("dispatch", into=spans, n=n):
+            if self._pending_plan is not None and \
+                    self._pending_plan[0] == start:
+                handle = self._pending_plan[1]
+            else:
+                # cold start / hole rewind / clamp moved the cursor: the
+                # prefetched plan covers the wrong seconds — drop it and
+                # dispatch the right one (the wasted device work is the
+                # rewind's price, not the steady state's)
+                handle = self._dispatch_plan(start, window)
+            self._pending_plan = None
+            self._next_epoch = start + window
+            self._pending_plan = (
+                self._next_epoch,
+                self._dispatch_plan(self._next_epoch, window))
+        with sp.span("grant", into=spans, n=n):
+            lease = self.store.grant(self.dispatch_ttl)
         # matured replan handles ride in FRONT of the window (oldest
         # epochs first), exactly as on the serial path
         replans, self._pending_replans = self._pending_replans, []
-        stall_s = self._builder.submit(_BuildItem(
+        self._builder.submit(_BuildItem(
             replans=replans, handle=handle, lease=lease,
-            hwm=self._next_epoch, covers_from=start))
-        spans["stall"] = stall_s * 1e3
+            hwm=self._next_epoch, covers_from=start), into=spans, n=n)
         n_dispatch = 0
         if self.sync_publish:
             # in-process stores: callers assert store contents right
             # after step() — run the pipeline to completion (the same
-            # code path, without the overlap)
-            self._builder.flush()
-            self.publisher.flush()
-            n_dispatch = self._drain_build_acct()
-            self._drain_replan_reqs()
+            # code path, without the overlap); the step waits on its
+            # own pipeline, so the wait is the rest of its "stall"
+            with sp.span("stall", into=spans, n=n):
+                self._builder.flush()
+                self.publisher.flush()
+                n_dispatch = self._drain_build_acct()
+                self._drain_replan_reqs()
         return n_dispatch
 
     # ---- pipeline plan-dispatch stage ------------------------------------
@@ -3430,13 +3575,15 @@ class SchedulerService:
         requested it: the same one-window staleness the prefetched
         ``_pending_plan`` already had."""
         def run():
-            t0 = time.perf_counter()
-            try:
-                return self.planner.plan_window_async(epoch_s, window_s,
-                                                      sla_bucket=sla)
-            finally:
-                self._dispatch_ms.append(
-                    (time.perf_counter() - t0) * 1e3)
+            # ring "plan", the name the serial path reports the plan
+            # call under; the planner annotates cronsun.plan.dispatch.
+            # Off the step thread: counted as overlapped (the CPU
+            # backend executes much of the plan INLINE at dispatch)
+            with self._timers.span("plan") as sp:
+                handle = self.planner.plan_window_async(
+                    epoch_s, window_s, sla_bucket=sla)
+            self._pl_dispatch_ms += sp.ms
+            return handle
         return self._dispatch_pool.submit(run)
 
     @staticmethod
@@ -3460,51 +3607,54 @@ class SchedulerService:
         thread, as do overflow-replan requests (device dispatches stay
         single-threaded)."""
         t0 = time.perf_counter()
-        acct = {"fires": 0, "drops": 0, "excl": [], "gather_ms": 0.0,
-                "build_ms": 0.0, "submit_ms": 0.0, "busy_ms": 0.0}
+        acct = {"fires": 0, "drops": 0, "excl": []}
+        sp, w = self._build_spans, item.covers_from
         try:
-            t = time.perf_counter()
-            build_list: List[Tuple[object, bool]] = []
-            for _ep, handle, _fires in item.replans:
-                build_list.append(
-                    (self.planner.gather_window(
-                        self._resolve_handle(handle))[0], False))
-            build_list += [(p, True) for p in self.planner.gather_window(
-                self._resolve_handle(item.handle))]
-            acct["gather_ms"] = (time.perf_counter() - t) * 1e3
-            t = time.perf_counter()
-            seconds: List[Tuple[int, list]] = []
-            wpend: Dict[int, int] = {}
-            if self._smear_ring:
-                self._smear_begin(
-                    min([item.covers_from]
-                        + [p.epoch_s for p, _ in build_list]),
-                    seconds, acct["excl"])
-            for plan, may_replan in build_list:
-                if plan.overflow:
-                    if may_replan:
-                        # escalated replans are REQUESTED here and
-                        # dispatched by the step thread next cycle —
-                        # late, never lost, one step of extra latency
-                        # for the over-bucket tail
-                        self._replan_reqs.append(
-                            (plan.epoch_s, plan.total_fired,
-                             plan.overflow))
-                    else:
-                        acct["drops"] += plan.overflow
-                        log.errorf("%d fires over the escalated bucket "
-                                   "at t=%d — dropped", plan.overflow,
-                                   plan.epoch_s)
-                acct["fires"] += self._build_plan_orders(
-                    plan, seconds, acct["excl"], pending_excl=wpend)
-            acct["build_ms"] = (time.perf_counter() - t) * 1e3
-            t = time.perf_counter()
+            # ring only: the planner annotates the fetch itself
+            # (cronsun.plan.gather).  In pipelined mode tick_* tracks
+            # this RESIDUAL device wait (the dispatch is async) — the
+            # honest "how long did the build stage wait on the device"
+            with self._timers.span("gather") as gather:
+                build_list: List[Tuple[object, bool]] = []
+                for _ep, handle, _fires in item.replans:
+                    build_list.append(
+                        (self.planner.gather_window(
+                            self._resolve_handle(handle))[0], False))
+                build_list += [
+                    (p, True) for p in self.planner.gather_window(
+                        self._resolve_handle(item.handle))]
+            self._tick_ms.add(gather.ms)
+            with sp.span("orders", ring="build", w=w):
+                seconds: List[Tuple[int, list]] = []
+                wpend: Dict[int, int] = {}
+                if self._smear_ring:
+                    self._smear_begin(
+                        min([item.covers_from]
+                            + [p.epoch_s for p, _ in build_list]),
+                        seconds, acct["excl"])
+                for plan, may_replan in build_list:
+                    if plan.overflow:
+                        if may_replan:
+                            # escalated replans are REQUESTED here and
+                            # dispatched by the step thread next cycle —
+                            # late, never lost, one step of extra
+                            # latency for the over-bucket tail
+                            self._replan_reqs.append(
+                                (plan.epoch_s, plan.total_fired,
+                                 plan.overflow))
+                        else:
+                            acct["drops"] += plan.overflow
+                            log.errorf("%d fires over the escalated "
+                                       "bucket at t=%d — dropped",
+                                       plan.overflow, plan.epoch_s)
+                    acct["fires"] += self._build_plan_orders(
+                        plan, seconds, acct["excl"], pending_excl=wpend)
             # publisher backpressure lands HERE, which fills this
             # stage's depth cap, which stalls the step's next plan —
             # backpressure propagates without ever reordering seconds
-            self.publisher.submit(seconds, item.lease, item.hwm,
-                                  covers_from=item.covers_from)
-            acct["submit_ms"] = (time.perf_counter() - t) * 1e3
+            with sp.span("submit", w=w):
+                self.publisher.submit(seconds, item.lease, item.hwm,
+                                      covers_from=item.covers_from)
         except Exception as e:  # noqa: BLE001 — the window never
             # reached the publisher: record a hole at its oldest second
             # so the next step REWINDS and re-plans it (late, never
@@ -3515,7 +3665,7 @@ class SchedulerService:
             log.errorf("pipelined window build failed (hole at %d): %s",
                        hole, e)
         finally:
-            acct["busy_ms"] = (time.perf_counter() - t0) * 1e3
+            self._pl_build_ms += (time.perf_counter() - t0) * 1e3
             self._acct_q.append(acct)
 
     def _drain_build_acct(self) -> int:
@@ -3531,21 +3681,6 @@ class SchedulerService:
             self.stats["dispatches_total"] += a["fires"]
             if a["drops"]:
                 self.stats["overflow_drops"] += a["drops"]
-            self._pl_offstep_ms += a["busy_ms"]
-            # pipelined mode: tick_* tracks the RESIDUAL device wait the
-            # gather paid (the dispatch itself is async) — the honest
-            # "how long did the step stage actually wait on the device"
-            self._tick_ms.add(a["gather_ms"])
-            for k in ("gather_ms", "build_ms", "submit_ms"):
-                self._span_ring(k[:-3]).add(a[k])
-        # the dispatch thread's work (the CPU backend executes much of
-        # the plan INLINE at dispatch) is serial-path step time that now
-        # runs off the step thread: count it as overlapped, under the
-        # same "plan" span name the serial path reports it in
-        while self._dispatch_ms:
-            dt = self._dispatch_ms.popleft()
-            self._pl_offstep_ms += dt
-            self._span_ring("plan").add(dt)
         return n
 
     def _drain_replan_reqs(self):
@@ -3562,24 +3697,18 @@ class SchedulerService:
             self._pending_replans.append(
                 (ep, self._dispatch_plan(ep, 1, sla=want), overflow))
 
-    def _span_ring(self, name: str):
-        ring = self._span_hist.get(name)
-        if ring is None:
-            from ..metrics import LatencyRing
-            ring = self._span_hist[name] = LatencyRing()
-        return ring
-
     def reset_latency_stats(self):
         """Drop the accumulated latency distributions and overlap
         accounting (benches: exclude the compile-paying first step from
         the reported p50/p99 and from ``pipeline_overlap_ratio``)."""
         self._step_ms.clear()
+        self._step_cpu_ms.clear()
         self._tick_ms.clear()
         for ring in self._span_hist.values():
             ring.clear()
         self._pl_step_ms = 0.0
-        self._pl_offstep_ms = 0.0
-        self._dispatch_ms.clear()
+        self._pl_dispatch_ms = 0.0
+        self._pl_build_ms = 0.0
         self._builder.stats["stalls_total"] = 0
         self._builder.stats["stall_ms_total"] = 0.0
 
@@ -3850,11 +3979,16 @@ class SchedulerService:
                             continue   # job dropped since the source
                         if flags & 4 and self._alone_live and \
                                 self._rd_job[row][1] in self._alone_live:
-                            continue   # KindAlone lifetime lock is live
+                            # KindAlone lifetime lock is live
+                            self.stats["alone_left_out_total"] += 1
+                            continue
                         if flags & 2:
-                            if not (0 <= col < len(self._col_node)
-                                    and self._col_live[col]):
-                                continue   # placed node left the fleet
+                            if not 0 <= col < len(self._col_node):
+                                continue
+                            if not self._col_live[col]:
+                                # placed node left the fleet
+                                self.stats["fires_node_gone_total"] += 1
+                                continue
                             node = self._col_node[col]
                             key = (self.ks.dispatch + node + "/" + ep
                                    + self._rd_suffix[row])
@@ -4062,6 +4196,7 @@ class SchedulerService:
                             if rd_job[rows[i]][1] in alone_live]
                     if drop:
                         live[drop] = False
+                        self.stats["alone_left_out_total"] += len(drop)
             is_excl = (flags & 2) != 0
             ep = str(plan.epoch_s)
             # Common fan-out, in plan order: ONE broadcast order per
@@ -4084,8 +4219,13 @@ class SchedulerService:
             xi = np.flatnonzero(live & is_excl)
             if xi.size:
                 cols = np.asarray(plan.assigned)[xi]
-                ok = (cols >= 0) & (cols < len(self._col_node))
-                ok &= self._col_live[np.where(ok, cols, 0)]
+                placed = (cols >= 0) & (cols < len(self._col_node))
+                ok = placed & self._col_live[np.where(placed, cols, 0)]
+                # placed on a node that has left the fleet since the
+                # plan: dropped here, counted
+                gone = int(np.count_nonzero(placed & ~ok))
+                if gone:
+                    self.stats["fires_node_gone_total"] += gone
                 xi = xi[ok]
                 cols = cols[ok]
             if xi.size and self._tenants:
@@ -4203,10 +4343,14 @@ class SchedulerService:
                 continue
             exclusive, payload, group, job_id, kind, suffix, bentry = ent
             if kind == KIND_ALONE and job_id in alone_live:
-                continue   # previous run still holds the fleet lock
+                # previous run still holds the fleet lock
+                self.stats["alone_left_out_total"] += 1
+                continue
             if exclusive:
                 if 0 <= node_col < n_cols:
                     node = col_node[node_col]
+                    if not node:    # placed node left the fleet
+                        self.stats["fires_node_gone_total"] += 1
                     if node:
                         if mr_caps is not None:
                             tid = int(self._row_tenant[row])
@@ -4359,12 +4503,17 @@ class SchedulerService:
         # with (or idle beside) the step thread; the ratio is that
         # hidden time over what a fully serial step would have summed
         stall_ms = self._builder.stats["stall_ms_total"]
-        hidden_ms = max(0.0, self._pl_offstep_ms - stall_ms)
+        offstep_ms = self._pl_dispatch_ms + self._pl_build_ms
+        hidden_ms = max(0.0, offstep_ms - stall_ms)
         denom_ms = self._pl_step_ms + hidden_ms
         # partitioned plane: the partition index rides every sched
         # series as a partition= label on /v1/metrics (a stalled
         # partition must be visible, not averaged away); absent
         # entirely at P=1 so the unpartitioned snapshot is unchanged
+        busy_ms = self._spans.ring("total").sum()
+        wait_ms = self._spans.ring("wait").sum()
+        cold = self.cold.seconds
+        pub_ring = self.publisher.spans.ring("window")
         part = ({"partition": self.partition,
                  "partitions": self.partitions,
                  "acct_exchanges_total":
@@ -4380,6 +4529,13 @@ class SchedulerService:
             # the residual device wait the gather stage paid)
             "sched_step_p50_ms": round(self._step_ms.percentile(0.50), 3),
             "sched_step_p99_ms": round(self._step_ms.percentile(0.99), 3),
+            # the step thread's CPU inside those cycles, and the share
+            # of the loop's time the step takes (the rest is the wait
+            # on the clock): the distance from the knee
+            "sched_step_cpu_p50_ms":
+                round(self._step_cpu_ms.percentile(0.50), 3),
+            "step_duty_pct": round(100.0 * busy_ms / (busy_ms + wait_ms),
+                                   3) if busy_ms + wait_ms else 0.0,
             **{f"step_span_{k}_ms": round(v, 3)
                for k, v in self._step_spans.items()},
             # per-span latency DISTRIBUTIONS (last-step instantaneous
@@ -4397,7 +4553,7 @@ class SchedulerService:
             "pipeline_depth": self._builder.depth,
             "pipeline_stalls_total": self._builder.stats["stalls_total"],
             "pipeline_stall_ms_total": round(stall_ms, 3),
-            "pipeline_offstep_ms_total": round(self._pl_offstep_ms, 3),
+            "pipeline_offstep_ms_total": round(offstep_ms, 3),
             "pipeline_overlap_ratio":
                 round(hidden_ms / denom_ms, 4) if denom_ms else 0.0,
             "publish_inflight": self.publisher.inflight,
@@ -4410,6 +4566,20 @@ class SchedulerService:
             # lease watchdog health (per partition when partitioned —
             # the partition= label rides every series above)
             "lease_resigns_total": self.stats["lease_resigns_total"],
+            # what the program does silently (see self.stats)
+            **{k: (round(self.stats[k], 3) if k == "compile_s_total"
+                   else self.stats[k])
+               for k in ("compiles_total", "compile_s_total",
+                         "cache_loads_total", "compiles_leading_total",
+                         "alone_left_out_total", "fires_node_gone_total")},
+            # the cold load by phase, seconds from the OS's process
+            # start to READY (total); a checkpoint restore leaves
+            # lists/jobs at 0 and checkpoint_restore_ms holds the time
+            **{f"cold_{k}_s": round(cold.get(k, 0.0), 3)
+               for k in ("startup", "planner", "lists", "jobs", "device")},
+            "cold_total_s": round(self.cold.total(), 3),
+            "warm_s": round(self._warm_s, 3),
+            "first_publish_s": round(self._first_publish_s, 3),
             # per-shard publish decoupling: 1 when the publisher runs
             # one shard-routed lane per store shard
             "publish_shard_lanes":
@@ -4426,6 +4596,8 @@ class SchedulerService:
             # plane-side publish health: per-window wire time and the
             # published/dropped totals (the step only shows backpressure)
             "publish_window_ms": round(self.publisher.last_window_ms, 3),
+            "publish_window_p50_ms": round(pub_ring.percentile(0.50), 3),
+            "publish_window_p99_ms": round(pub_ring.percentile(0.99), 3),
             "published_total": self.publisher.stats["published_total"],
             "publish_failures": self.publisher.stats["publish_failures"],
             "publish_abandoned": self.publisher.stats["publish_abandoned"],
@@ -4520,12 +4692,19 @@ class SchedulerService:
             if kv is not None:
                 try:
                     if int(kv.value) >= value:
-                        return
+                        break
                 except ValueError:
                     pass
             if self.store.put_if_mod_rev(self._hwm_key, str(value),
                                          kv.mod_rev if kv else 0):
-                return
+                break
+        else:
+            return
+        wait = self._first_pub_wait
+        if wait is not None and value >= wait[1]:
+            # the first window of this leadership is acknowledged
+            self._first_publish_s = time.monotonic() - wait[0]
+            self._first_pub_wait = None
 
     def _row_cmd(self, row: int) -> Optional[Tuple[str, str, str]]:
         return self.rows.by_row.get(row)
@@ -4556,11 +4735,34 @@ class SchedulerService:
                 # plan ahead: sleep until the window is nearly consumed
                 nxt = (self._next_epoch or 0) - 1.5
                 delay = max(0.2, min(self.window_s, nxt - self.clock()))
-                if self._stop.wait(delay):
+                if self._wait(delay):
                     return
         self._thread = threading.Thread(target=run, daemon=True,
                                         name="scheduler-loop")
         self._thread.start()
+
+    # a profiler session sees an annotation only if it was open for
+    # neither the session's start nor its end: the wait on the clock
+    # is most of a window, so it goes out in slices this long
+    WAIT_SLICE_S = 0.25
+
+    def _wait(self, delay: float) -> bool:
+        """The loop's wait on the clock (span "wait"; one ring sample,
+        a leader's only — what is left of the loop's period after
+        "total"); True when the service is stopping."""
+        into: Dict[str, float] = {}
+        n = self.stats["steps_total"]
+        deadline = time.monotonic() + delay
+        stopping = False
+        while not stopping:
+            left = deadline - time.monotonic()
+            if left <= 0:
+                break
+            with self._spans.span("wait", into=into, n=n):
+                stopping = self._stop.wait(min(left, self.WAIT_SLICE_S))
+        if self.is_leader:
+            self._spans.commit(into)
+        return stopping
 
     def _abdicate(self):
         lease, self._leader_lease = self._leader_lease, None
