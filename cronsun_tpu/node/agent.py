@@ -257,7 +257,8 @@ class NodeAgent:
                       "rec_flush_total": 0, "rec_flush_records_total": 0,
                       "rec_dropped_total": 0, "dep_events_total": 0,
                       "dep_event_failures_total": 0,
-                      "trace_spans_total": 0, "trace_spans_dropped_total": 0}
+                      "trace_spans_total": 0, "trace_spans_dropped_total": 0,
+                      "execs_demoted_total": 0}
         # fire-lifecycle tracing: head-sampled (or failed, or per-job
         # trace:true) executions buffer a span here and ride the record
         # flush — zero extra RPCs on the hot path.  The verdict is the
@@ -278,7 +279,11 @@ class NodeAgent:
         # scheduled-second -> exec-start lag samples (the end-to-end
         # dispatch SLA), published as p50/p99 in the metrics snapshot
         self._lag_ring: list = []
-        from ..metrics import MetricsPublisher
+        from ..metrics import LatencyRing, MetricsPublisher
+        # wall time inside the executor's launch call, ms per execution
+        # (ExecResult.spawn_s): what a herd second's ramp is made of
+        # when the launch is slow; p50/p99 in the metrics snapshot
+        self._spawn_ring = LatencyRing(512)
         self.metrics = MetricsPublisher(
             store, self.ks, "node", self.id, self.metrics_snapshot,
             interval_s=10.0, clock=clock)
@@ -405,6 +410,11 @@ class NodeAgent:
             q = lambda p: lags[min(len(lags) - 1, int(p * len(lags)))]
             snap["exec_start_lag_p50_s"] = round(q(0.50), 3)
             snap["exec_start_lag_p99_s"] = round(q(0.99), 3)
+        if len(self._spawn_ring):
+            snap["exec_spawn_p50_ms"] = round(
+                self._spawn_ring.percentile(0.50), 3)
+            snap["exec_spawn_p99_ms"] = round(
+                self._spawn_ring.percentile(0.99), 3)
         snap["running"] = len(self.running)
         snap["procs_registered"] = len(self._procs)
         snap["rec_flush_max_batch"] = self._rec_flush_max_batch
@@ -967,9 +977,13 @@ class NodeAgent:
                 tr: Optional[tuple] = None):
         if res.skipped:
             return
-        self._bump("execs_total")
-        if not res.success:
-            self._bump("execs_failed_total")
+        with self._stats_mu:
+            self.stats["execs_total"] += 1
+            if not res.success:
+                self.stats["execs_failed_total"] += 1
+            if res.demoted:
+                self.stats["execs_demoted_total"] += 1
+            self._spawn_ring.add(res.spawn_s * 1e3)
         self._slo_observe(job, res)
         if self.dep_events and epoch_s:
             # the workflow DAG edge signal: last-write-wins per job, the

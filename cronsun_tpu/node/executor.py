@@ -6,8 +6,13 @@ job.go:134-187 retry + Parallels gate):
 - commands are tokenized with shell quoting (shlex) — a deliberate
   improvement over the reference's whitespace-only split (job.go:391-393),
   which cannot express arguments containing spaces;
-- ``user`` demotes the child via setuid/setgid before exec (reference
+- ``user`` demotes the child via setgid/setuid before exec (reference
   job.go:413-434) — requires running as root, otherwise recorded as failure;
+- no Python runs between fork and exec: the session, the group and the
+  demotion are ``Popen``'s own (``_posixsubprocess``, in C), so a job
+  without ``user`` takes CPython's ``vfork()`` path — a Python hook in
+  the child forces ``fork()`` of a process holding ~70 threads and an
+  interpreter rebuilt there, 0.3-3 s a launch in a herd second;
 - timeout kills the whole process group (reference uses CommandContext,
   job.go:437-443);
 - stdout+stderr are captured combined, truncated at ``max_output`` bytes;
@@ -42,6 +47,8 @@ class ExecResult:
     error: str = ""
     retries_used: int = 0
     skipped: bool = False        # concurrency gate refused the run
+    spawn_s: float = 0.0         # wall time inside the launch call
+    demoted: bool = False        # launch changed uid/gid (no vfork path)
 
     @property
     def seconds(self) -> float:
@@ -76,15 +83,6 @@ class _Gate:
                 self._counts[job_id] = cur - 1
 
 
-def _demote(user: str) -> Callable[[], None]:
-    info = pwd.getpwnam(user)
-
-    def fn():
-        os.setgid(info.pw_gid)
-        os.setuid(info.pw_uid)
-    return fn
-
-
 class Executor:
     def __init__(self, max_output: int = DEFAULT_MAX_OUTPUT,
                  clock: Callable[[], float] = time.time):
@@ -105,26 +103,29 @@ class Executor:
         if not argv:
             return ExecResult(False, "", begin, self.clock(),
                               error="empty command")
-        preexec = None
+        uid = gid = None
         if user:
             try:
-                demote = _demote(user)
+                info = pwd.getpwnam(user)
             except KeyError:
                 return ExecResult(False, "", begin, self.clock(),
                                   error=f"user {user!r} not found")
+            uid, gid = info.pw_uid, info.pw_gid
+        demoted = uid is not None
 
-            def preexec():  # noqa: F811
-                os.setsid()
-                demote()
-        else:
-            preexec = os.setsid
-
+        # setsid, then gid, then uid, all in C: the child leads its own
+        # session and group (pgid == pid), which the timeout kills whole
+        t0 = time.perf_counter()
         try:
             proc = subprocess.Popen(
                 argv, stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
-                env=env, preexec_fn=preexec, start_new_session=False)
-        except (OSError, PermissionError) as e:
-            return ExecResult(False, "", begin, self.clock(), error=str(e))
+                env=env, start_new_session=True, user=uid, group=gid)
+        except OSError as e:
+            # a missing binary, or a demotion the OS refuses (not root)
+            return ExecResult(False, "", begin, self.clock(), error=str(e),
+                              spawn_s=time.perf_counter() - t0,
+                              demoted=demoted)
+        spawn_s = time.perf_counter() - t0
 
         try:
             out, _ = proc.communicate(timeout=timeout or None)
@@ -136,14 +137,16 @@ class Executor:
             out, _ = proc.communicate()
             return ExecResult(
                 False, self._trunc(out), begin, self.clock(),
-                exit_code=-9, error=f"timeout after {timeout}s")
+                exit_code=-9, error=f"timeout after {timeout}s",
+                spawn_s=spawn_s, demoted=demoted)
         end = self.clock()
         return ExecResult(
             success=proc.returncode == 0,
             output=self._trunc(out),
             begin_ts=begin, end_ts=end, exit_code=proc.returncode,
             error="" if proc.returncode == 0
-            else f"exit status {proc.returncode}")
+            else f"exit status {proc.returncode}",
+            spawn_s=spawn_s, demoted=demoted)
 
     def _trunc(self, out: bytes) -> str:
         if out is None:

@@ -1014,3 +1014,52 @@ def test_bundle_failure_releases_alone_locks():
     assert total == 0
     agent.stop()
     store.close()
+
+
+def test_herd_second_records_every_execution_and_its_spawn_time():
+    """64 executions due in ONE second on one agent, through the real
+    Executor (a fork and an exec each): 40 exclusive fires of one
+    (node, second) bundle and 24 Common broadcasts — a herd second's
+    shape.  Every one is recorded once, each stamped with the second it
+    was scheduled for, and the snapshot carries what the launches cost.
+    No bound on the milliseconds: the CPU runners are shared."""
+    store, sink = MemStore(), JobLogStore()
+    agent = NodeAgent(store, sink, node_id="n0")
+    agent.register()
+    excl = _seed_excl(store, 40, prefix="hx")
+    for _, jid in excl:     # the cell's command: prints its own second
+        job = Job.from_json(store.get(KS.job_key("g", jid)).value)
+        job.group, job.id = "g", jid
+        job.command = "printenv CRONSUN_SCHEDULED_TS"
+        store.put(KS.job_key("g", jid), job.to_json())
+    common = []
+    for i in range(24):
+        job = Job(id=f"hc{i}", name=f"hc{i}", group="g",
+                  command="printenv CRONSUN_SCHEDULED_TS", kind=KIND_COMMON,
+                  rules=[JobRule(id="r", timer="* * * * * *", nids=["n0"])])
+        store.put(KS.job_key("g", job.id), job.to_json())
+        common.append(job.id)
+    epoch = int(time.time()) - 1
+    store.put(KS.dispatch_bundle_key("n0", epoch), _bundle(excl, epoch))
+    for jid in common:
+        store.put(KS.dispatch_all_key(epoch, "g", jid), "")
+    deadline = time.time() + 60
+    while time.time() < deadline:
+        agent.poll()
+        agent.join_running()
+        if sink.query_logs()[1] >= 64:
+            break
+        time.sleep(0.05)
+    recs, total = sink.query_logs(page_size=200)
+    assert total == 64
+    assert sorted(r.job_id for r in recs) == \
+        sorted([j for _, j in excl] + common), "one record a job"
+    assert all(r.success and r.output.strip() == str(epoch) for r in recs)
+    snap = agent.metrics_snapshot()
+    assert snap["execs_total"] == 64 and snap["execs_failed_total"] == 0
+    assert snap["exec_spawn_p50_ms"] > 0
+    assert snap["exec_spawn_p99_ms"] >= snap["exec_spawn_p50_ms"]
+    assert snap["execs_demoted_total"] == 0
+    assert len(agent._spawn_ring) == 64
+    agent.stop()
+    store.close()
