@@ -65,6 +65,13 @@ class OpStats:
                     for op, (c, t, m) in self._ns.items()}
 
 
+def percentile(ordered: list, p: float) -> float:
+    """The ``p`` quantile of an ascending list; 0.0 of an empty one."""
+    if not ordered:
+        return 0.0
+    return ordered[min(len(ordered) - 1, int(p * len(ordered)))]
+
+
 class LatencyRing:
     """Bounded ring of recent latency samples with percentile reads —
     the shared primitive behind every ``*_p50_ms``/``*_p99_ms`` gauge
@@ -90,11 +97,11 @@ class LatencyRing:
     def __len__(self) -> int:
         return len(self._v)
 
+    def values(self) -> list:
+        return list(self._v)
+
     def percentile(self, p: float) -> float:
-        vals = sorted(self._v)
-        if not vals:
-            return 0.0
-        return vals[min(len(vals) - 1, int(p * len(vals)))]
+        return percentile(sorted(self._v), p)
 
     def sum(self) -> float:
         return float(sum(self._v))
@@ -139,10 +146,16 @@ class Spans:
     JAX (agents, store, logd and web import it), and a holder without
     a factory only times.
 
-    Spans sit at step / window / batch granularity — never per fire,
-    per order or per key — and are LEAVES: at most one open per thread
-    (a trace reduction joins every overlapping name into the label of
-    an idle gap, so an enclosing span would sit on every label).
+    ``with`` blocks sit at step / window / batch granularity and are
+    LEAVES: at most one open per thread (a trace reduction joins every
+    overlapping name into the label of an idle gap, so an enclosing
+    span would sit on every label).  Per fire there is no block: the
+    agent stamps ``time.perf_counter()`` where an execution changes
+    hands, folds the differences into a dict carried on the task and
+    hands it to ``commit`` ONCE when the execution is over, under the
+    agent's own lock (``node/agent.py`` STAGES, ``_task_done``) — a
+    ring's append is safe from one producer, and there the producers
+    are 64 pool threads.  Nothing is timed per order or per key.
 
     ``ring`` names the ring where it differs from the span's name;
     ``into`` collects the duration in a dict instead (summed per name:

@@ -22,6 +22,7 @@ from __future__ import annotations
 
 import json
 import os
+import resource
 import socket
 import threading
 import time
@@ -34,18 +35,103 @@ from ..core.backoff import REC_FLUSH
 from ..core.errors import DuplicateNode
 from ..core.models import KIND_ALONE
 from ..logsink import JobLogStore, LogRecord
+from ..metrics import LatencyRing, MetricsPublisher, Spans, percentile
 from ..store.memstore import DELETE, MemStore, WatchLost
 from .executor import ExecResult, Executor
 
 VERSION = "v0.1.0-tpu"
 
+# One execution's life on the agent, in the order the work changes
+# hands; consecutive differences of one chain of time.perf_counter()
+# stamps, so the stages tile ``whole`` (handed to _stage_task ->
+# _update_avg_time returned) with nothing between them:
+#   stage      handed to _stage_task -> put on the pool's queue
+#   queue      on the queue -> a pool worker has it
+#   prelaunch  worker has it -> Executor.run_once's ``begin``: the wait
+#              for the second, lag ring, proc key, env, a lone
+#              exclusive order's claim round trip, the gate
+#   spawn      ``begin`` -> the launch call returned (argv split + Popen;
+#              ``exec_spawn_*`` is the Popen call alone)
+#   child      launch returned -> communicate() returned (a retried
+#              run: first launch -> last attempt reaped)
+#   cleanup    child reaped -> _record entered: result built, gate left,
+#              proc key deleted (a store round trip where the claim
+#              registered one), Alone lock revoked, order acked
+#   record     _record, whole (``dep_put``, the store round trip inside
+#              it, also on a ring of its own)
+#   avg_time   _update_avg_time
+STAGES = ("stage", "queue", "prelaunch", "spawn", "child", "cleanup",
+          "record", "avg_time")
+# rings beside the stages: ``enqueue_late`` is signed (ms past the
+# scheduled second when the task went on the queue); ``bundle_claim``
+# is once per bundle (the second arrived -> claim_bundle answered) and
+# ``bundle_prefetch`` the jobs' get_many + parse inside it
+SPAN_RINGS = STAGES + ("whole", "enqueue_late", "dep_put", "bundle_claim",
+                       "bundle_prefetch")
+# a scheduled second is a burst (a herd second) from this many tasks on
+# this agent; the steady seconds of the listed cell hold 4-5
+HERD_MIN = 16
+HERD_KEEP_S = 300.0     # the snapshot shows the largest burst this recent
+GIL_PROBE_NAP_S = 0.002
+GIL_PROBE_MAX_S = 10.0  # per arming: a herd second that holds a long
+                        # job must not keep the probe spinning for it
+
+
+def _children_cpu_s() -> float:
+    """CPU seconds of the children this process has reaped so far."""
+    ru = resource.getrusage(resource.RUSAGE_CHILDREN)
+    return ru.ru_utime + ru.ru_stime
+
+
+def _fold_stages(sp: dict, res: ExecResult, run0: tuple, t_rec: float,
+                 t_avg: float, dep_ms: Optional[float]):
+    """Leave one recorded execution's stages in its task's stamp dict:
+    ``sp["ms"]`` (ring name -> ms) for the one commit, ``sp["begin"]``
+    for its second's account."""
+    t_begin = res.t_begin or run0[0]
+    t_spawned = res.t_spawned or t_begin
+    marks = (sp["t0"], sp["enq"], sp["work"], t_begin, t_spawned,
+             res.t_end or t_spawned, t_rec, t_avg, time.perf_counter())
+    ms = {name: (b - a) * 1e3
+          for name, a, b in zip(STAGES, marks, marks[1:])}
+    ms["whole"] = (marks[-1] - marks[0]) * 1e3
+    ms["enqueue_late"] = sp["late_ms"]
+    if dep_ms is not None:
+        ms["dep_put"] = dep_ms
+    sp["ms"] = ms
+    sp["begin"] = (t_begin, res.begin_ts) + run0[1:]
+
+
+def _runq_wait_ms(tid: int) -> Optional[float]:
+    """ms a thread of this process has so far been runnable with no core
+    to run on (field 2 of its ``schedstat``); None where the kernel
+    keeps none, or the thread is gone."""
+    try:
+        with open(f"/proc/self/task/{tid}/schedstat") as f:
+            return int(f.read().split()[1]) / 1e6
+    except (OSError, ValueError, IndexError):
+        return None
+
+
+def _host_cpu_stall_ms() -> Optional[float]:
+    """ms so far in which SOME task of the host was runnable with no
+    core to run on (``/proc/pressure/cpu``); None without it."""
+    try:
+        with open("/proc/pressure/cpu") as f:
+            return int(f.readline().rsplit("total=", 1)[1]) / 1e3
+    except (OSError, ValueError, IndexError):
+        return None
+
 
 class _ExecTask:
-    __slots__ = ("fn", "finished")
+    __slots__ = ("fn", "finished", "sp")
 
     def __init__(self, fn):
         self.fn = fn
         self.finished = threading.Event()
+        # this task's stamps (time.perf_counter()) and, once it ran,
+        # its stages under "ms": folded into the rings by ONE commit
+        self.sp: dict = {}
 
     def done(self) -> bool:
         return self.finished.is_set()
@@ -68,6 +154,10 @@ class _ExecPool:
         import queue
         self._q: "queue.SimpleQueue" = queue.SimpleQueue()
         self._workers = workers
+        # workers busy now, and the most at once / the deepest queue
+        # since reset_max() (a burst opening resets them)
+        self._mu = threading.Lock()
+        self.busy = self.busy_max = self.queue_max = 0
         for i in range(workers):
             threading.Thread(target=self._worker, daemon=True,
                              name=f"{prefix}-{i}").start()
@@ -77,14 +167,102 @@ class _ExecPool:
             task = self._q.get()
             if task is None:
                 return
-            task.run()
+            task.sp["work"] = time.perf_counter()
+            with self._mu:
+                self.busy += 1
+                if self.busy > self.busy_max:
+                    self.busy_max = self.busy
+            try:
+                task.run()
+            finally:
+                with self._mu:
+                    self.busy -= 1
 
     def enqueue(self, task: _ExecTask):
+        task.sp["enq"] = time.perf_counter()
         self._q.put(task)
+        depth = self._q.qsize()
+        with self._mu:
+            if depth > self.queue_max:
+                self.queue_max = depth
+
+    def reset_max(self):
+        with self._mu:
+            self.busy_max, self.queue_max = self.busy, 0
 
     def shutdown(self):
         for _ in range(self._workers):
             self._q.put(None)      # idle workers exit; busy ones are daemons
+
+
+class _Second:
+    """What one scheduled second cost this agent: how many of its tasks
+    are still out, and — kept only if it turns out a burst — the sum of
+    every stage over its executions and the stamps of its first and last
+    ``begin``.  All under NodeAgent._stats_mu."""
+
+    __slots__ = ("pending", "enqueued", "n", "sums", "first", "last",
+                 "probe", "pool")
+
+    def __init__(self):
+        self.pending = self.enqueued = self.n = 0
+        self.sums: Dict[str, float] = {}
+        # (perf_counter at begin, begin_ts, process CPU s, children CPU s)
+        self.first = self.last = None
+        # a burst (armed): the pool it ran on and, from the moment it
+        # was armed, (the GIL probe's samples, how many there were,
+        # perf_counter, the probe thread's id and run-queue wait, the
+        # host's CPU stall) — see NodeAgent._arm_gil_probe
+        self.pool: Optional[_ExecPool] = None
+        self.probe: Optional[tuple] = None
+
+    def add(self, ms: dict, begin: Optional[tuple]):
+        # an execution's lag is enqueue_late + queue + prelaunch: the
+        # three sums ÷ n are the burst's mean lag, split
+        for name, v in ms.items():
+            self.sums[name] = self.sums.get(name, 0.0) + v
+        if begin is not None:
+            self.n += 1
+            if self.first is None or begin[0] < self.first[0]:
+                self.first = begin
+            if self.last is None or begin[0] > self.last[0]:
+                self.last = begin
+
+    def record(self, epoch_s: int) -> dict:
+        """The closed burst, flat: the ``herd_*`` snapshot fields and
+        the log line."""
+        first, last = self.first, self.last
+        drain = max(last[0] - first[0], 1e-9)
+        samples, i0, t_armed, tid, runq0, stall0 = self.probe
+        armed = samples[i0:]
+        over = sorted(o for t, o in armed if first[0] <= t <= last[0])
+        rec = {"sec": epoch_s, "n": self.n,
+               "lag_first_s": round(first[1] - epoch_s, 4),
+               "lag_last_s": round(last[1] - epoch_s, 4),
+               "drain_s": round(drain, 4),
+               "cpu_self_s": round(last[2] - first[2], 4),
+               "cpu_share": round((last[2] - first[2]) / drain, 3),
+               "children_cpu_s": round(last[3] - first[3], 4),
+               "children_share": round((last[3] - first[3]) / drain, 3),
+               "pool_busy_max": self.pool.busy_max,
+               "pool_queue_max": self.pool.queue_max,
+               "gil_probe_n": len(over),
+               "gil_probe_p50_ms": round(percentile(over, 0.50), 3),
+               "gil_probe_p99_ms": round(percentile(over, 0.99), 3),
+               "gil_probe_max_ms": round(percentile(over, 1.0), 3),
+               # armed -> closed: all the probe overshot by, and how much
+               # of that it waited for a core, not for the interpreter
+               "gil_probe_over_ms": round(sum(o for _t, o in armed), 3)}
+        runq1, stall1 = _runq_wait_ms(tid), _host_cpu_stall_ms()
+        if runq0 is not None and runq1 is not None:
+            rec["gil_probe_runq_ms"] = round(runq1 - runq0, 3)
+        if stall0 is not None and stall1 is not None:
+            rec["host_cpu_stall_share"] = round(
+                (stall1 - stall0) / 1e3
+                / max(time.perf_counter() - t_armed, 1e-9), 3)
+        for name, v in self.sums.items():
+            rec[f"sum_{name}_ms"] = round(v, 3)
+        return rec
 
 
 class NodeAgent:
@@ -258,7 +436,9 @@ class NodeAgent:
                       "rec_dropped_total": 0, "dep_events_total": 0,
                       "dep_event_failures_total": 0,
                       "trace_spans_total": 0, "trace_spans_dropped_total": 0,
-                      "execs_demoted_total": 0}
+                      "execs_demoted_total": 0,
+                      "avg_time_writebacks_total": 0,
+                      "stage_scan_enqueued_max": 0}
         # fire-lifecycle tracing: head-sampled (or failed, or per-job
         # trace:true) executions buffer a span here and ride the record
         # flush — zero extra RPCs on the hot path.  The verdict is the
@@ -279,11 +459,24 @@ class NodeAgent:
         # scheduled-second -> exec-start lag samples (the end-to-end
         # dispatch SLA), published as p50/p99 in the metrics snapshot
         self._lag_ring: list = []
-        from ..metrics import LatencyRing, MetricsPublisher
         # wall time inside the executor's launch call, ms per execution
         # (ExecResult.spawn_s): what a herd second's ramp is made of
         # when the launch is slow; p50/p99 in the metrics snapshot
         self._spawn_ring = LatencyRing(512)
+        # the stages of an execution's life (STAGES), ms, the agent's
+        # last 512: many pool threads produce, so the one commit an
+        # execution makes is under _stats_mu (_task_done)
+        self._spans = Spans("agent", rings={name: LatencyRing(512)
+                                            for name in SPAN_RINGS})
+        # scheduled second -> its account while tasks of it are out; the
+        # bursts closed lately, as (closed at, record); and the GIL
+        # probe, alive only while a burst is armed (_gil_probe_loop)
+        self._seconds: Dict[int, _Second] = {}
+        self._herds: list = []
+        self._probes_armed = 0
+        self._probe_thread: Optional[threading.Thread] = None
+        self._probe_until = 0.0
+        self._probe_samples: list = []
         self.metrics = MetricsPublisher(
             store, self.ks, "node", self.id, self.metrics_snapshot,
             interval_s=10.0, clock=clock)
@@ -406,6 +599,24 @@ class NodeAgent:
         with self._stats_mu:
             snap = dict(self.stats)
             lags = sorted(self._lag_ring)
+            spans = {name: ring.values()
+                     for name, ring in self._spans.rings.items()}
+            cut = self.clock() - HERD_KEEP_S
+            herds = [rec for at, rec in self._herds if at >= cut]
+        for name, vals in spans.items():
+            if vals:
+                vals.sort()
+                snap[f"exec_span_{name}_p50_ms"] = round(
+                    percentile(vals, 0.50), 3)
+                snap[f"exec_span_{name}_p99_ms"] = round(
+                    percentile(vals, 0.99), 3)
+        if herds:
+            for name, v in max(herds, key=lambda r: r["n"]).items():
+                snap[f"herd_{name}"] = v
+        pool = self._pool
+        if pool is not None:        # since the newest burst opened
+            snap["pool_busy_max"] = pool.busy_max
+            snap["pool_queue_max"] = pool.queue_max
         if lags:
             q = lambda p: lags[min(len(lags) - 1, int(p * len(lags)))]
             snap["exec_start_lag_p50_s"] = round(q(0.50), 3)
@@ -587,8 +798,11 @@ class NodeAgent:
     def _execute(self, job: Job, epoch_s: int, fenced: bool,
                  use_gate: bool = True, order_key: Optional[str] = None,
                  pre: Optional[tuple] = None,
-                 tr: Optional[tuple] = None):
-        """Run one fire.  ``pre`` = (proc_registered, alone) marks an
+                 tr: Optional[tuple] = None,
+                 sp: Optional[dict] = None):
+        """Run one fire.  ``sp`` is the task's stamp dict (_ExecTask.sp):
+        this path leaves the execution's stages in it (_fold_stages).
+        ``pre`` = (proc_registered, alone) marks an
         execution whose (job, second) fence — and KindAlone lifetime
         lock — were already settled by a bundle claim (_run_bundle): the
         fence/claim section is skipped, the rest (proc lifecycle,
@@ -687,6 +901,11 @@ class NodeAgent:
                 pdelay_token = self._schedule_proc_put(put_proc)
             else:
                 put_proc()
+            # where prelaunch ends if the executor stamps no ``begin`` of
+            # its own, and the CPU clocks a burst reads at its first and
+            # last ``begin`` (≈ 2 µs: two system calls)
+            run0 = (time.perf_counter(), time.process_time(),
+                    _children_cpu_s())
             try:
                 res = self.executor.run_job(
                     job_id=job.id, command=job.command, user=job.user,
@@ -730,8 +949,12 @@ class NodeAgent:
                     log.warnf("alone lock revoke failed (lease will "
                               "expire it): %s", e)
             consume_order()                # consume the order regardless
-        self._record(job, res, epoch_s, tr=tr)
+        t_rec = time.perf_counter()
+        dep_ms = self._record(job, res, epoch_s, tr=tr)
+        t_avg = time.perf_counter()
         self._update_avg_time(job, res)
+        if sp is not None and "work" in sp and not res.skipped:
+            _fold_stages(sp, res, run0, t_rec, t_avg, dep_ms)
 
     _FENCE_GRACE = 60.0
 
@@ -956,9 +1179,14 @@ class NodeAgent:
         # too — an instant job (dur < 0.1 s) must NOT pay a CAS per fire
         # forever (each CAS also churns the job watch fleet-wide: every
         # agent invalidates its cache and the scheduler re-applies the
-        # job), and the planner floors its cost at 1.0 regardless.
+        # job).  The planner has NO floor under what is written here: it
+        # takes avg_time where it is > 0 and 1.0 only where it is 0
+        # (sched/service.py _acct_add, _meta_updates), so a sub-second
+        # write-back makes this node's orders weigh less than a node's
+        # that wrote none (ROADMAP Queue 1 #4).
         if abs(dur - job.avg_time) <= 0.1 * max(1.0, job.avg_time):
             return
+        self._bump("avg_time_writebacks_total")
         key = self.ks.job_key(job.group, job.id)
         for _ in range(3):
             kv = self.store.get(key)
@@ -974,9 +1202,13 @@ class NodeAgent:
                 return
 
     def _record(self, job: Job, res: ExecResult, epoch_s: int = 0,
-                tr: Optional[tuple] = None):
+                tr: Optional[tuple] = None) -> Optional[float]:
+        """Count, signal and buffer one finished execution.  Returns the
+        ms its ``dep`` put took — the one synchronous store round trip
+        of this path — or None where it made none."""
         if res.skipped:
-            return
+            return None
+        dep_ms = None
         with self._stats_mu:
             self.stats["execs_total"] += 1
             if not res.success:
@@ -992,6 +1224,7 @@ class NodeAgent:
             # scheduler's fold is a monotone max on it).  Best-effort —
             # a store outage here must not fail the execution path; the
             # round re-announces on the job's next completion.
+            t_dep = time.perf_counter()
             try:
                 self.store.put(
                     self.ks.dep_key(job.group, job.id),
@@ -1001,6 +1234,7 @@ class NodeAgent:
                 self._bump("dep_event_failures_total")
                 log.warnf("dep completion event for %s/%s failed: %s",
                           job.group, job.id, e)
+            dep_ms = (time.perf_counter() - t_dep) * 1e3
         rec = LogRecord(
             job_id=job.id, job_group=job.group, name=job.name, node=self.id,
             user=job.user, command=job.command,
@@ -1049,6 +1283,7 @@ class NodeAgent:
                    "to": job.to}
             self.store.put(self.ks.noticer_key(self.id),
                            json.dumps(msg, separators=(",", ":")))
+        return dep_ms
 
     def _trace_span(self, job: Job, res: ExecResult, epoch_s: int,
                     tr: Optional[tuple]) -> Optional[dict]:
@@ -1433,11 +1668,13 @@ class NodeAgent:
 
         def run():
             try:
-                self._run_bundle(key, epoch_s, pairs, tb=tb, recv=recv)
+                self._run_bundle(key, epoch_s, pairs, tb=tb, recv=recv,
+                                 sp=task.sp)
             except Exception as e:  # noqa: BLE001 — log, don't die silent
                 log.errorf("bundle %s failed: %s", name, e)
             finally:
                 self.running.pop(name, None)
+                self._task_done(task, epoch_s)
 
         task = _ExecTask(run)
         self.running[name] = task
@@ -1446,7 +1683,8 @@ class NodeAgent:
 
     def _run_bundle(self, order_key: str, epoch_s: int, pairs: list,
                     tb: Optional[float] = None,
-                    recv: Optional[float] = None):
+                    recv: Optional[float] = None,
+                    sp: Optional[dict] = None):
         """Consume one coalesced order: resolve the bundle's jobs (one
         get_many), settle KindAlone lifetime locks per job (lock FIRST —
         a skip because the previous run is still live must not consume
@@ -1459,7 +1697,9 @@ class NodeAgent:
         failover) re-claims and loses."""
         if not self._wait_until(epoch_s):
             return
+        t_claim = time.perf_counter()
         self._prefetch_pairs(pairs)
+        t_fetched = time.perf_counter()
         runnable = []   # [job, alone, with_proc, proc_key, proc_val]
         items = []      # parallel (fence_key, nonce, proc_key, proc_val)
         try:
@@ -1488,6 +1728,10 @@ class NodeAgent:
                 self._ack(order_key)
                 return
             wins = self._claim_bundle(order_key, items)
+            if sp is not None:
+                sp["ms"] = {
+                    "bundle_claim": (time.perf_counter() - t_claim) * 1e3,
+                    "bundle_prefetch": (t_fetched - t_claim) * 1e3}
             if wins is None:
                 # store unreachable: do NOT run unfenced.  Stop the
                 # Alone keepalives so the locks expire server-side; the
@@ -1868,13 +2112,14 @@ class NodeAgent:
         def run():
             try:
                 self._execute(job, epoch_s, fenced, use_gate, order_key,
-                              pre=pre, tr=tr)
+                              pre=pre, tr=tr, sp=task.sp)
             except Exception as e:  # noqa: BLE001 — log, don't die silent
                 log.errorf("execution %s failed: %s", name, e)
             finally:
                 # self-prune: a long-running agent must not accumulate one
                 # finished task record per execution
                 self.running.pop(name, None)
+                self._task_done(task, epoch_s)
 
         task = _ExecTask(run)
         self.running[name] = task
@@ -1896,13 +2141,15 @@ class NodeAgent:
         # orders cost zero extra threads); the stage lock makes stop()
         # vs due-enqueue atomic, so a stopping agent can never enqueue
         # into (or resurrect) a shut-down pool.
+        task.sp["t0"] = time.perf_counter()
         with self._stage_mu:
             if self._stop.is_set():
                 self.running.pop(name, None)
                 task.finished.set()
                 return
-            if epoch_s - self.clock() <= 0.02:
-                self._ensure_pool().enqueue(task)
+            now = self.clock()
+            if epoch_s - now <= 0.02:
+                self._release(epoch_s, [task], now)
                 return
             self._staged[name] = (task, epoch_s)
             if self._stage_monitor is None or \
@@ -1923,12 +2170,105 @@ class NodeAgent:
                     self._stage_monitor = None
                     return
                 now = self.clock()
+                due: Dict[int, list] = {}
                 for name, (task, epoch_s) in list(self._staged.items()):
                     if epoch_s - now <= 0.02:
                         self._staged.pop(name)
-                        self._ensure_pool().enqueue(task)
+                        due.setdefault(epoch_s, []).append(task)
+                for epoch_s, tasks in due.items():
+                    self._release(epoch_s, tasks, now)
+            released = sum(map(len, due.values()))
+            if released > self.stats["stage_scan_enqueued_max"]:
+                with self._stats_mu:    # only this thread writes it
+                    self.stats["stage_scan_enqueued_max"] = released
             time.sleep(0.1)
 
+    def _release(self, epoch_s: int, tasks: list, now: float):
+        """Put due tasks of one scheduled second on the pool's queue
+        (caller holds _stage_mu).  The second's account is opened for
+        all of them BEFORE the first can run, so it cannot close between
+        two tasks of one scan; from HERD_MIN tasks the second is a burst
+        and the GIL probe is armed for it."""
+        pool = self._ensure_pool()
+        with self._stats_mu:
+            acct = self._seconds.get(epoch_s)
+            if acct is None:
+                acct = self._seconds[epoch_s] = _Second()
+            acct.pending += len(tasks)
+            acct.enqueued += len(tasks)
+            if acct.enqueued >= HERD_MIN and acct.probe is None:
+                pool.reset_max()
+                acct.pool = pool
+                self._arm_gil_probe(acct)
+        late_ms = (now - epoch_s) * 1e3
+        for task in tasks:
+            task.sp["late_ms"] = late_ms
+            pool.enqueue(task)
+
+    def _arm_gil_probe(self, acct: _Second):
+        """Caller holds _stats_mu."""
+        now = time.perf_counter()
+        self._probes_armed += 1
+        self._probe_until = now + GIL_PROBE_MAX_S
+        if self._probe_thread is None:
+            self._probe_samples = []
+            self._probe_thread = threading.Thread(
+                target=self._gil_probe_loop, args=(self._probe_samples,),
+                daemon=True, name=f"gilprobe-{self.id}")
+            self._probe_thread.start()
+        tid = self._probe_thread.native_id
+        acct.probe = (self._probe_samples, len(self._probe_samples), now,
+                      tid, _runq_wait_ms(tid), _host_cpu_stall_ms())
+
+    def _gil_probe_loop(self, samples: list):
+        """While a burst is open: nap GIL_PROBE_NAP_S and note how much
+        later than asked this thread ran again.  Coming back from the
+        nap needs the GIL, so the overshoot is what any thread that
+        wants the interpreter pays for it (plus, on a host out of cores,
+        the wait for one).  Appends are this thread's alone; a burst
+        reads the list when it closes."""
+        while True:
+            t = time.perf_counter()
+            time.sleep(GIL_PROBE_NAP_S)
+            now = time.perf_counter()
+            samples.append((now, (now - t - GIL_PROBE_NAP_S) * 1e3))
+            with self._stats_mu:
+                if not self._probes_armed or now > self._probe_until:
+                    # handle cleared under the lock, as _stage_loop's
+                    self._probe_thread = None
+                    return
+
+    def _task_done(self, task: _ExecTask, epoch_s: int):
+        """A pool task ended (recorded, skipped, lost its claim or
+        failed): the ONE commit of its stages into the rings, and its
+        second's account — closed, logged and kept for the snapshot when
+        this was the last task out of a burst."""
+        sp = task.sp
+        if "enq" not in sp:
+            return                      # run-now: never on the pool
+        ms = sp.get("ms")
+        with self._stats_mu:
+            if ms is not None:
+                self._spans.commit(ms)
+            acct = self._seconds[epoch_s]
+            if ms is not None:
+                acct.add(ms, sp.get("begin"))
+            acct.pending -= 1
+            if acct.pending:
+                return
+            del self._seconds[epoch_s]
+            if acct.probe is None:
+                return
+            self._probes_armed -= 1
+            if acct.n < HERD_MIN:
+                return
+            # under the lock, once a burst: an emptied _seconds means
+            # its record is kept
+            rec = acct.record(epoch_s)
+            now = self.clock()
+            self._herds = [(at, r) for at, r in self._herds
+                           if at >= now - HERD_KEEP_S] + [(now, rec)]
+        log.infof("herd %s", json.dumps(rec, separators=(",", ":")))
 
     def join_running(self, timeout: float = 10.0):
         deadline = time.monotonic() + timeout

@@ -49,6 +49,13 @@ class ExecResult:
     skipped: bool = False        # concurrency gate refused the run
     spawn_s: float = 0.0         # wall time inside the launch call
     demoted: bool = False        # launch changed uid/gid (no vfork path)
+    # time.perf_counter() where the run changed hands, for the agent's
+    # per-execution stages (0.0 = not stamped: an injected executor, a
+    # run that never launched): beside ``begin_ts``, the launch call
+    # returned, the child was reaped
+    t_begin: float = 0.0
+    t_spawned: float = 0.0
+    t_end: float = 0.0
 
     @property
     def seconds(self) -> float:
@@ -95,6 +102,7 @@ class Executor:
     def run_once(self, command: str, user: str = "", timeout: int = 0,
                  env: Optional[dict] = None) -> ExecResult:
         begin = self.clock()
+        t_begin = time.perf_counter()
         try:
             argv = shlex.split(command)
         except ValueError as e:
@@ -125,7 +133,8 @@ class Executor:
             return ExecResult(False, "", begin, self.clock(), error=str(e),
                               spawn_s=time.perf_counter() - t0,
                               demoted=demoted)
-        spawn_s = time.perf_counter() - t0
+        t_spawned = time.perf_counter()
+        spawn_s = t_spawned - t0
 
         try:
             out, _ = proc.communicate(timeout=timeout or None)
@@ -138,7 +147,9 @@ class Executor:
             return ExecResult(
                 False, self._trunc(out), begin, self.clock(),
                 exit_code=-9, error=f"timeout after {timeout}s",
-                spawn_s=spawn_s, demoted=demoted)
+                spawn_s=spawn_s, demoted=demoted, t_begin=t_begin,
+                t_spawned=t_spawned, t_end=time.perf_counter())
+        t_end = time.perf_counter()
         end = self.clock()
         return ExecResult(
             success=proc.returncode == 0,
@@ -146,7 +157,8 @@ class Executor:
             begin_ts=begin, end_ts=end, exit_code=proc.returncode,
             error="" if proc.returncode == 0
             else f"exit status {proc.returncode}",
-            spawn_s=spawn_s, demoted=demoted)
+            spawn_s=spawn_s, demoted=demoted, t_begin=t_begin,
+            t_spawned=t_spawned, t_end=t_end)
 
     def _trunc(self, out: bytes) -> str:
         if out is None:
@@ -176,6 +188,8 @@ class Executor:
                 nxt = self.run_once(command, user, timeout, env)
                 nxt.retries_used = attempts
                 nxt.begin_ts = result.begin_ts  # whole-run span
+                nxt.t_begin = result.t_begin
+                nxt.t_spawned = result.t_spawned or nxt.t_spawned
                 result = nxt
                 if result.success:
                     break
