@@ -199,56 +199,16 @@ def main():
             detail["dispatch_plane_error"] = proc.stderr[-500:]
     except Exception as e:  # noqa: BLE001 — the TPU bench must still land
         detail["dispatch_plane_error"] = str(e)
-    # the C++ agent through the same sweep (instant-exec mode): the
-    # only way to show plane headroom beyond Python's per-agent
-    # ceiling on this host (VERDICT r4 #7).  Own error scope: a
-    # native-sweep failure must not mislabel the (already merged)
-    # Python sweep as failed.
-    if not quick:
-        try:
-            proc = subprocess.run(
-                [sys.executable, os.path.join(here, "scripts",
-                                              "bench_dispatch.py"),
-                 "--rates", "5000,20000,40000,80000", "--seconds", "3",
-                 "--agent-sweep", "1,2"],
-                capture_output=True, text=True, timeout=1800, cwd=here,
-                env={**os.environ, "BENCH_AGENT": "native"})
-            if proc.returncode == 0:
-                nd = json.loads(proc.stdout)
-                detail["dispatch_plane_native_backend"] = \
-                    nd.get("dispatch_plane_backend")
-                detail["dispatch_plane_native_orders_per_sec"] = \
-                    nd.get("dispatch_plane_orders_per_sec")
-                detail["dispatch_plane_native_saturation_offered_per_sec"] = \
-                    nd.get("dispatch_plane_saturation_offered_per_sec")
-                detail["dispatch_plane_native_agent_curve"] = \
-                    nd.get("dispatch_plane_agent_curve")
-                for k in ("dispatch_plane_exec_lag_p50_s",
-                          "dispatch_plane_exec_lag_p99_s",
-                          "dispatch_plane_exec_lag_net_p50_s",
-                          "dispatch_plane_exec_lag_net_p99_s",
-                          "dispatch_plane_exec_lag_offset_s",
-                          "dispatch_plane_agent_records_per_flush",
-                          "dispatch_plane_logd_records_per_batch",
-                          "dispatch_plane_logd_op_stats",
-                          "dispatch_plane_records_dropped"):
-                    if k in nd:
-                        detail[k.replace("plane_", "plane_native_")] = nd[k]
-            else:
-                detail["dispatch_plane_native_error"] = proc.stderr[-500:]
-        except Exception as e:  # noqa: BLE001
-            detail["dispatch_plane_native_error"] = str(e)
     # the shard-count ladder: one past-saturation rate at a fixed
     # agent count across 1/2/4 store shards — the horizontal-scaling
     # claim (ORDER drain past the one-PROCESS store ceiling) measured
-    # in the same artifact.  Native agents drive (Python agents
-    # saturate on the interpreter first); the store side is
-    # BENCH_STORE=py, one bin.store process per shard: the GIL-bound
-    # backend is the one whose single-process ceiling sits below the
-    # fleet's drive capacity on one host, so its curve shows the
-    # partitioning win (the native server is internally striped and
-    # multithreaded — its shard win is per-machine).  Own error scope
-    # like the native sweep.
+    # in the same artifact.  The store side is BENCH_STORE=py, one
+    # bin.store process per shard: the GIL-bound backend is the one
+    # whose single-process ceiling sits below the fleet's drive
+    # capacity on one host, so its curve shows the partitioning win
+    # (the native server is internally striped and multithreaded — its
+    # shard win is per-machine).  Own error scope: a ladder failure
+    # must not mislabel the (already merged) sweep as failed.
     if not quick:
         log("dispatch plane: store shard ladder 1/2/4")
         try:
@@ -258,8 +218,7 @@ def main():
                  "--rates", "150000", "--seconds", "3", "--agents", "8",
                  "--shard-ladder", "1,2,4"],
                 capture_output=True, text=True, timeout=1800, cwd=here,
-                env={**os.environ, "BENCH_AGENT": "native",
-                     "BENCH_STORE": "py"})
+                env={**os.environ, "BENCH_STORE": "py"})
             if proc.returncode == 0:
                 detail.update(json.loads(proc.stdout))
             else:
@@ -271,9 +230,9 @@ def main():
     # fixed agent count across 1/2/4 logd shards — the record-drain
     # scaling curve the sharded result plane must deliver (PR 6's probe
     # measured the unsharded logd as the wall at ~33k records/s).
-    # Native agents drive; BENCH_LOGD=py (one bin.logd process per
-    # shard) is the backend whose single-process ceiling the sharding
-    # removes on one host — the store-ladder lesson applied to logd.
+    # BENCH_LOGD=py (one bin.logd process per shard) is the backend
+    # whose single-process ceiling the sharding removes on one host —
+    # the store-ladder lesson applied to logd.
     if not quick:
         log("result plane: logd shard ladder 1/2/4")
         try:
@@ -283,8 +242,7 @@ def main():
                  "--rates", "60000", "--seconds", "3", "--agents", "4",
                  "--logd-shards", "1,2,4"],
                 capture_output=True, text=True, timeout=1800, cwd=here,
-                env={**os.environ, "BENCH_AGENT": "native",
-                     "BENCH_LOGD": "py"})
+                env={**os.environ, "BENCH_LOGD": "py"})
             if proc.returncode == 0:
                 detail.update(json.loads(proc.stdout))
             else:

@@ -195,8 +195,7 @@ class Keyspace:
 
     def dispatch_key(self, node_id: str, epoch_s: int, group: str,
                      job_id: str) -> str:
-        """Legacy per-(node, second, job) exclusive order key — still
-        consumed by both agents for rollout tolerance; the scheduler
+        """Per-(node, second, job) exclusive order key — the scheduler
         publishes :meth:`dispatch_bundle_key` for in-window fires, but
         late smeared arrivals (spill-ring entries whose carrying window
         has moved on) are emitted on this per-job form.  ``epoch_s`` is
@@ -209,8 +208,7 @@ class Keyspace:
         plain, or the partitioned scheduler's ``<epoch>.<partition>``
         form.  Returns ``(epoch, partition-or-None)``, or None when
         the segment is neither — THE one home of the suffix grammar
-        (agents, fsck, mirrors and benches all parse through here;
-        native/agentd.cc mirrors it)."""
+        (agents, fsck, mirrors and benches all parse through here)."""
         ep, dot, part = segment.partition(".")
         if not ep.isdigit() or (dot and not part.isdigit()):
             return None

@@ -10,7 +10,7 @@ protocol, same WAL/SQLite sidecar, just a smaller record space) — and
 gives every component a drop-in client with the exact JobLogStore
 surface, mirroring ``store/sharded.py`` end to end.
 
-Routing — deterministic, shared with ``native/agentd.cc`` bit-for-bit:
+Routing — deterministic, so every process of the fleet agrees:
 
 - the token is the record's ``job_id``, hashed with the same 64-bit
   FNV-1a the store shards use (:func:`~cronsun_tpu.store.sharded.fnv1a`
@@ -84,8 +84,7 @@ LOG_HASH_SCHEME = "fnv1a-job-v1"
 
 def log_shard_index(job_id: str, nshards: int) -> int:
     """The routing hash: 64-bit FNV-1a of the raw ``job_id`` mod N —
-    deterministic across processes and languages (native/agentd.cc
-    carries the same constants)."""
+    deterministic across processes (Python's own ``hash`` is salted)."""
     if nshards <= 1:
         return 0
     return fnv1a(job_id) % nshards

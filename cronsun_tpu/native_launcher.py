@@ -10,6 +10,7 @@ per-server modules add only their flag sets.
 
 from __future__ import annotations
 
+import fcntl
 import os
 import pathlib
 import select
@@ -34,15 +35,21 @@ def find_binary(name: str, env_var: str, build: bool = True) -> Optional[str]:
     cand = NATIVE_DIR / name
     srcs = [NATIVE_DIR / f"{name.split('-', 1)[1]}.cc", NATIVE_DIR / "njson.h"]
     if srcs[0].exists() and build:
-        stale = (not cand.exists() or any(
-            s.exists() and cand.stat().st_mtime < s.stat().st_mtime
-            for s in srcs))
-        if stale:
-            try:
-                subprocess.run(["make", "-C", str(NATIVE_DIR), name],
-                               check=True, capture_output=True, timeout=120)
-            except (subprocess.SubprocessError, OSError) as e:
-                log.warnf("native build of %s failed: %s", name, e)
+        # one builder at a time across processes, and nobody judges the
+        # binary fresh while another is still linking it: every xdist
+        # worker and every `bin.store --native` comes through here
+        try:
+            with open(NATIVE_DIR / ".build.lock", "w") as lock:
+                fcntl.flock(lock, fcntl.LOCK_EX)
+                stale = (not cand.exists() or any(
+                    s.exists() and cand.stat().st_mtime < s.stat().st_mtime
+                    for s in srcs))
+                if stale:
+                    subprocess.run(["make", "-C", str(NATIVE_DIR), name],
+                                   check=True, capture_output=True,
+                                   timeout=120)
+        except (subprocess.SubprocessError, OSError) as e:
+            log.warnf("native build of %s failed: %s", name, e)
     if cand.exists() and os.access(cand, os.X_OK):
         return str(cand)
     return shutil.which(name)

@@ -15,8 +15,6 @@ Wire protocol (one JSON object per line, UTF-8):
                        {"i": <id>, "e": <msg>, "k": <kind>}  (error)
                        {"w": <wid>, "evs": [<event>...]}     (watch push,
                                                               batched)
-                       {"w": <wid>, "ev": <event>}           (legacy
-                                                              single push)
 
 KV wire form: [key, value, create_rev, mod_rev, lease]
 Event wire form: [type, kv, prev_kv-or-null]
@@ -27,8 +25,7 @@ Design notes:
 - Watch pushes are BATCHED: one pump thread per connection drains every
   ready watcher per wakeup and ships one {"w", "evs"} frame per watcher
   (one sendall for the whole wakeup) — a dispatch burst of K events
-  costs a handful of wire frames, not K serialized lines.  Clients
-  accept both the batched and the legacy single-event form.
+  costs a handful of wire frames, not K serialized lines.
 - Leases live server-side and expire by TTL whether or not the client is
   connected — exactly etcd's behaviour, and what node-death detection
   relies on (noticer.go:172-200).  A dropped connection closes its
@@ -555,8 +552,6 @@ class RemoteStore:
                     elif "evs" in msg:       # batched push (one frame,
                         for e in msg["evs"]:  # many events)
                             w._emit(_ev_unwire(e))
-                    else:                    # legacy single-event push
-                        w._emit(_ev_unwire(msg["ev"]))
                 continue
             rid = msg.get("i")
             ev = self._pending_ev.get(rid)

@@ -9,7 +9,7 @@ protocol, same WAL + snapshot checkpoint format, just a smaller
 keyspace), and gives every component a drop-in client with the exact
 MemStore/RemoteStore surface.
 
-Routing — deterministic, shared with ``native/agentd.cc`` bit-for-bit:
+Routing — deterministic, so every process of the fleet agrees:
 
 - :func:`shard_token` extracts a ROUTING TOKEN from the key so related
   keys co-locate by key design (the pjit partitioning move: shard by
@@ -91,7 +91,7 @@ _MASK64 = (1 << 64) - 1
 
 def fnv1a(s: str) -> int:
     """64-bit FNV-1a over UTF-8 bytes — deterministic across processes
-    and languages (native/agentd.cc carries the same constants)."""
+    (Python's own ``hash`` is salted per process)."""
     h = _FNV_OFFSET
     for b in s.encode("utf-8"):
         h = ((h ^ b) * _FNV_PRIME) & _MASK64
@@ -451,10 +451,10 @@ class ShardedStore:
     def grant(self, ttl: float) -> int:
         if self.nshards == 1:
             return self.shards[0].grant(ttl)
-        # sequential with rollback (the C++ mirror's shape): a later
-        # shard failing must not strand live TTL leases on the earlier
-        # ones — callers retry grants in a loop, and each stranded set
-        # would pin its keys for the full TTL.
+        # sequential with rollback: a later shard failing must not
+        # strand live TTL leases on the earlier ones — callers retry
+        # grants in a loop, and each stranded set would pin its keys
+        # for the full TTL.
         #
         # BROWNOUT tolerance: a shard whose breaker is OPEN gets the
         # server-impossible -1 sentinel as its leg instead of failing

@@ -3,11 +3,11 @@ waterfall assembly for the trace plane.
 
 Every fire owns a deterministic 64-bit trace id
 ``fnv1a64("<job_id>|<scheduled_second>")`` — no coordination, computed
-independently by the scheduler, both agents (agent.py and agentd.cc)
-and the web tier, the same hash-parity pattern the sharded store routes
-by.  A head-sampled subset (low trace-id bits, ``trace_sample_shift``;
-plus per-job ``trace: true`` and every failed execution) carries span
-timestamps through the lifecycle:
+independently by the scheduler, the agents, logd and the web tier, the
+same hash-parity pattern the sharded store routes by.  A head-sampled
+subset (low trace-id bits, ``trace_sample_shift``; plus per-job
+``trace: true`` and every failed execution) carries span timestamps
+through the lifecycle:
 
 - the scheduler stamps the order-build wall time into the coalesced
   (node, second) order value as a trailing ``{"tb": <ts>}`` element
@@ -40,7 +40,7 @@ _MASK64 = (1 << 64) - 1
 
 def fnv1a64(s: str) -> int:
     """64-bit FNV-1a over UTF-8 bytes — must stay bit-identical to
-    store.sharded.fnv1a and the C++ twins (pinned by test)."""
+    store.sharded.fnv1a and native/logd.cc's (pinned by test)."""
     h = _FNV_OFFSET
     for b in s.encode():
         h = ((h ^ b) * _FNV_PRIME) & _MASK64

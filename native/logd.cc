@@ -60,7 +60,7 @@ struct Rec {
 };
 
 // 64-bit FNV-1a — the trace plane's deterministic id hash (bit-twin of
-// cronsun_tpu/trace.py fnv1a64 and agentd.cc's fnv1a64)
+// cronsun_tpu/trace.py fnv1a64)
 static unsigned long long trace_fnv1a64(const std::string& s) {
   unsigned long long h = 0xcbf29ce484222325ull;
   for (unsigned char c : s) h = (h ^ c) * 0x100000001b3ull;

@@ -125,18 +125,6 @@ def _PyLogShardServer(extra=(), env=None):
     return _PyProcServer("cronsun_tpu.bin.logd", extra, env=env)
 
 
-def _native_agent_workers(n_agents: int) -> str:
-    """Worker threads per native bench agent.  The agentd default (64)
-    assumes a dedicated machine; a bench fleet of 8 on one host would
-    run 512 workers on ~24 cores and measure scheduler thrash, not the
-    plane (measured: 64 workers drained 48k orders/s where 8 drained
-    109k on a 24-core host).  Scale the pool to the fleet's share."""
-    if os.environ.get("BENCH_WORKERS"):
-        return os.environ["BENCH_WORKERS"]
-    cores = os.cpu_count() or 8
-    return str(max(4, min(64, (2 * cores) // max(1, n_agents))))
-
-
 def run_bench(rates, n_agents, seconds, on_log=print, shards=1,
               logd_shards=1):
     from cronsun_tpu.core import Keyspace
@@ -199,33 +187,11 @@ def run_bench(rates, n_agents, seconds, on_log=print, shards=1,
         agents = []
         node_ids = [f"bench-agent-{i}" for i in range(n_agents)]
         here = os.path.abspath(__file__)
-        agentd = os.path.join(os.path.dirname(os.path.dirname(here)),
-                              "native", "cronsun-agentd")
-        use_native_agents = (os.environ.get("BENCH_AGENT", "py") == "native"
-                             and os.path.exists(agentd))
         for nid in node_ids:
-            if use_native_agents:
-                # --instant-exec: the C++ agent skips the fork/exec and
-                # returns success instantly — symmetric with the Python
-                # workers' InstantExecutor, so the two curves compare the
-                # PLANE cost per agent, not fork throughput
-                # --workers: fleet-share sized (BENCH_WORKERS overrides) —
-                # see _native_agent_workers.  --ttl 3: metrics snapshots
-                # publish every ~1s (the keepalive beat), so the per-agent
-                # consumed counts the fairness signal reads are fresh at
-                # the end of a short sweep, not one stale beat behind.
-                p = subprocess.Popen(
-                    [agentd, "--store", store_addr,
-                     "--logsink", logd_addr,
-                     "--node-id", nid, "--proc-req", "5", "--instant-exec",
-                     "--workers", _native_agent_workers(n_agents),
-                     "--ttl", "3"],
-                    stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True)
-            else:
-                p = subprocess.Popen(
-                    [sys.executable, here, "--worker", store_addr,
-                     logd_addr, nid],
-                    stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True)
+            p = subprocess.Popen(
+                [sys.executable, here, "--worker", store_addr,
+                 logd_addr, nid],
+                stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True)
             agents.append(p)
         for p in agents:
             # log warnings may precede READY; read until it appears
@@ -242,8 +208,7 @@ def run_bench(rates, n_agents, seconds, on_log=print, shards=1,
                     pass
             threading.Thread(target=_drain, daemon=True).start()
 
-        results = {"dispatch_plane_backend": backend
-                   + ("+native-agents" if use_native_agents else ""),
+        results = {"dispatch_plane_backend": backend,
                    "dispatch_plane_agents": n_agents,
                    "dispatch_plane_store_shards": shards,
                    "dispatch_plane_logd_shards": logd_shards,
@@ -444,7 +409,7 @@ def run_bench(rates, n_agents, seconds, on_log=print, shards=1,
                 if "orders_consumed_total" in m:
                     consumed_per_agent.append(m["orders_consumed_total"])
                 # record-plane health: flush batching + outage drops, as
-                # published by both agents' record flushers
+                # published by the agents' record flushers
                 rec_flushes += m.get("rec_flush_total", 0)
                 rec_flush_records += m.get("rec_flush_records_total", 0)
                 rec_dropped += m.get("rec_dropped_total", 0)
@@ -630,13 +595,11 @@ def run_shard_ladder(counts, rate=40000, n_agents=2, seconds=3,
     Backend choice matters on ONE host: the ceiling sharding removes
     is the single-PROCESS one (one GIL/event plane/accept loop), so
     the demonstrative rungs run BENCH_STORE=py — each shard its own
-    bin.store process — where that ceiling is real and low (measured
-    39k -> 77k -> 127k orders/s at 1/2/4 shards, 8 native agents,
-    24 cores).  The native server is already striped and
-    multithreaded within one process, so a single-host native ladder
-    mostly measures what CPU headroom is left after ~130k/s, not the
-    partitioning win; its shard win is per-MACHINE, which one box
-    cannot show."""
+    bin.store process — where that ceiling is real and low.  The
+    native server is already striped and multithreaded within one
+    process, so a single-host native ladder mostly measures what CPU
+    headroom is left, not the partitioning win; its shard win is
+    per-MACHINE, which one box cannot show."""
     ladder = []
     base = None
     backend = None
@@ -673,7 +636,7 @@ def run_logd_ladder(counts, rate=60000, n_agents=4, seconds=3,
     logd shard counts (1/2/4 by default).  Everything but the logd
     shard count is held still — the store stays a single native server
     (its ~130k orders/s ceiling sits far above the record rates swept
-    here), agents are whatever BENCH_AGENT says — so the curve isolates
+    here) — so the curve isolates
     what partitioning the RECORD space buys: the sustained record
     drain (executions landed in the result store over time) must scale
     toward linear while zero records drop and per-agent fairness
@@ -723,9 +686,9 @@ def main():
         return worker_main(sys.argv[2], sys.argv[3], sys.argv[4])
     ap = argparse.ArgumentParser()
     # the default sweep deliberately runs PAST 40k offered/s: in bundle
-    # (coalesced) mode the per-agent drain ceiling was unmeasured once
-    # both agents shared the ~7.7k/s legacy figure — the top rates pin
-    # it (drain at/past saturation over agent count)
+    # (coalesced) mode the per-agent drain ceiling sits above the
+    # ~7.7k/s legacy figure — the top rates pin it (drain at/past
+    # saturation over agent count)
     ap.add_argument("--rates", default="1000,10000,40000,80000")
     ap.add_argument("--agents", type=int, default=0,
                     help="0 = auto: one per core beyond the shared "

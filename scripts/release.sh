@@ -10,7 +10,7 @@ mkdir -p "$OUT"
 # 1. native components (C++ coordination + result store servers)
 if [ -d native ]; then
     make -C native -j"$(nproc)"
-    cp native/cronsun-stored native/cronsun-logd native/cronsun-agentd "$OUT"/ 2>/dev/null || true
+    cp native/cronsun-stored native/cronsun-logd "$OUT"/ 2>/dev/null || true
 fi
 
 # 2. Python wheel (console scripts: cronsun-store/sched/node/web/demo)

@@ -437,46 +437,6 @@ def test_staged_order_honors_virtual_clock():
     store.close()
 
 
-def test_native_tokenizer_matches_shlex():
-    """The native agent's command tokenizer decides what executes — it
-    must agree with Python's shlex.split (what the Python executor uses)
-    on every input, including quotes, escapes and unicode.  Differential
-    fuzz through agentd --tokenize."""
-    import pathlib
-    import random
-    import shlex
-    import subprocess
-    import pytest
-    agentd = pathlib.Path(__file__).resolve().parents[1] / "native" / \
-        "cronsun-agentd"
-    if not agentd.exists():
-        pytest.skip("native agent binary unavailable")
-    rng = random.Random(7)
-    pieces = ['a', 'bc', '"', "'", '\\', ' ', '\t', '\r', 'ζ日', '$x',
-              '*', '"a b"', "'c d'", '\\ ', '\\"', 'e=f', '|', '-n']
-    cases = ["echo hi", '''printf '%s|' "a b" c'd' e\\ f''', "", "   ",
-             "'unterminated", '"open', "a\\", "echo a\rb"]
-    for _ in range(300):
-        cases.append("".join(rng.choice(pieces)
-                             for _ in range(rng.randrange(1, 10))))
-    # the --tokenize harness is line-framed: newlines can't appear inside
-    # a case and the binary strips trailing CR like a text protocol would
-    cases = [c.replace("\n", " ").rstrip("\r") for c in cases]
-    inp = "\n".join(cases) + "\n"
-    out = subprocess.run([str(agentd), "--tokenize"], input=inp,
-                         capture_output=True, text=True, timeout=30)
-    got = out.stdout.splitlines()
-    assert len(got) == len(cases)
-    for case, line in zip(cases, got):
-        try:
-            expect = shlex.split(case)
-        except ValueError:
-            expect = None
-        actual = json.loads(line)
-        assert actual == expect, \
-            f"tokenizer divergence on {case!r}: {actual} != {expect}"
-
-
 def test_cron_context_env():
     """Executed commands see the cron-context environment — most
     importantly CRONSUN_SCHEDULED_TS, the second the run was planned

@@ -22,43 +22,6 @@ sys.path.insert(0, os.path.join(REPO, "scripts"))
 
 
 @pytest.mark.slow
-def test_native_agent_record_drain_not_record_bound():
-    """Result-plane regression gate: BENCH_r05 measured the NATIVE
-    agent's instant-exec drain ceilinged near 0.7k execs/s by one
-    lock-step create_job_log RPC per execution.  With the background
-    record flusher the same sweep must drain >= 2x that per-record
-    baseline, ship the record wire in real batches, and drop nothing —
-    with exec-start lag bounded by the drained backlog, not by the
-    record path."""
-    if (os.cpu_count() or 1) < 6:
-        pytest.skip("needs >= 6 cores for a meaningful drain signal")
-    agentd = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "native", "cronsun-agentd")
-    if not os.path.exists(agentd):
-        pytest.skip("native agent binary unavailable")
-    os.environ["BENCH_AGENT"] = "native"
-    try:
-        import bench_dispatch
-        res = bench_dispatch.run_bench(
-            [8000], 1, 3, on_log=lambda *a: print(*a, file=sys.stderr))
-    finally:
-        os.environ.pop("BENCH_AGENT", None)
-    drain = res["dispatch_plane_drain_per_agent_per_sec"]
-    assert drain >= 1400, (
-        f"native agent drained {drain}/s — at/below 2x the 0.7k/s "
-        f"lock-step per-record baseline; the record flusher regressed")
-    assert res.get("dispatch_plane_records_dropped", 0) == 0
-    rpb = res.get("dispatch_plane_logd_records_per_batch")
-    assert rpb is None or rpb > 2, (
-        f"record wire not batched ({rpb} records/bulk-RPC)")
-    # the sweep offers 3s of orders then waits for the drain: exec lag
-    # p99 must stay within the drained-backlog bound, not minutes of
-    # record-path queueing (13.6 s p50 was the r05 symptom)
-    lag99 = res.get("dispatch_plane_exec_lag_p99_s")
-    assert lag99 is None or lag99 < 30, f"exec lag p99 {lag99}s"
-
-
-@pytest.mark.slow
 def test_warm_takeover_beats_cold_load_at_scale():
     """Checkpoint-plane gate at the CPU-host scale (50k jobs x 512
     nodes): a standby restoring a scheduler checkpoint must take over
@@ -144,121 +107,6 @@ def test_two_agents_scale_aggregate_drain():
     # ways a routing regression that serializes one shard (or one
     # agent) shows up without flattening the 2-over-1 curve
     assert res["quick_gate_failures"] == [], res["quick_gate_failures"]
-
-
-@pytest.mark.slow
-def test_shard_scaling():
-    """Horizontal-store gate: at a FIXED agent count past the one-shard
-    saturation point, 2 store shards must lift aggregate ORDER drain
-    >= 1.5x over 1 shard with per-agent fairness holding >= 0.8 —
-    partitioning the keyspace has to buy real concurrency (separate
-    event planes and accept loops), not re-serialize behind one hot
-    shard.  Native instant-exec agents put the store on the critical
-    path (Python agents saturate on their own interpreter first); the
-    STORE side runs BENCH_STORE=py — one bin.store process per shard —
-    because the single-PROCESS ceiling is the thing sharding removes,
-    and on one host only the GIL-bound backend has that ceiling below
-    the fleet's drive capacity (the native server is internally
-    striped/multithreaded, so its single-host shard curve measures
-    leftover CPU headroom, not the partitioning win).  The record
-    plane stays logd-gated either way — the ladder's order-drain
-    figure isolates the sharded boundary."""
-    if (os.cpu_count() or 1) < 12:
-        pytest.skip("needs >= 12 cores for a store-bound drain signal")
-    agentd = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "native", "cronsun-agentd")
-    if not os.path.exists(agentd):
-        pytest.skip("native agent binary unavailable")
-    os.environ["BENCH_AGENT"] = "native"
-    os.environ["BENCH_STORE"] = "py"
-    try:
-        import bench_dispatch
-        # a shared host's scheduler noise swings short benches; one
-        # retry keeps the gate sharp on regressions (a re-serialized
-        # shard split fails BOTH runs) without tripping on jitter
-        for attempt in (1, 2):
-            res = bench_dispatch.run_shard_ladder(
-                [1, 2], rate=150000, n_agents=8, seconds=3,
-                on_log=lambda *a: print(*a, file=sys.stderr))
-            ladder = res["dispatch_plane_shard_ladder"]
-            one, two = ladder[0], ladder[1]
-            fair = two["fairness_min_over_max"]
-            if (two["scaling_vs_1_shard"] >= 1.5
-                    and (fair is None or fair >= 0.8)) or attempt == 2:
-                break
-            print("shard ladder below gate "
-                  f"({two['scaling_vs_1_shard']}x, fairness {fair}); "
-                  "retrying once", file=sys.stderr)
-    finally:
-        os.environ.pop("BENCH_AGENT", None)
-        os.environ.pop("BENCH_STORE", None)
-    assert one["order_drain_per_sec"] > 0
-    assert two["scaling_vs_1_shard"] >= 1.5, (
-        f"2-shard order drain {two['order_drain_per_sec']}/s is only "
-        f"{two['scaling_vs_1_shard']}x the 1-shard "
-        f"{one['order_drain_per_sec']}/s — the shard split "
-        "re-serialized")
-    fair = two["fairness_min_over_max"]
-    assert fair is None or fair >= 0.8, (
-        f"2-shard fairness {fair} < 0.8 — one shard (or its agent) "
-        "is hogging the drain")
-
-
-@pytest.mark.slow
-def test_logd_shard_scaling():
-    """RESULT-plane gate, the store gate's twin: at a fixed agent count
-    and one offered rate past the single-logd ingest ceiling, 2 logd
-    shards must lift sustained RECORD drain >= 1.5x over 1 shard with
-    zero record drops and per-agent fairness >= 0.8.  Native
-    instant-exec agents drive (their flushers split each bulk flush per
-    shard); the logd side runs BENCH_LOGD=py — one bin.logd process per
-    shard — because the single-PROCESS SQLite ceiling is the thing the
-    sharding removes on one host (the C++ logd's shard win is
-    per-machine).  A broken job-routing hash fails this as one hot
-    shard and a flat curve."""
-    if (os.cpu_count() or 1) < 12:
-        pytest.skip("needs >= 12 cores for a logd-bound drain signal")
-    agentd = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "native", "cronsun-agentd")
-    if not os.path.exists(agentd):
-        pytest.skip("native agent binary unavailable")
-    os.environ["BENCH_AGENT"] = "native"
-    os.environ["BENCH_LOGD"] = "py"
-    try:
-        import bench_dispatch
-        # one retry for shared-host jitter, like the store gate: a real
-        # routing/serialization regression fails both runs
-        for attempt in (1, 2):
-            res = bench_dispatch.run_logd_ladder(
-                [1, 2], rate=60000, n_agents=4, seconds=3,
-                on_log=lambda *a: print(*a, file=sys.stderr))
-            ladder = res["result_plane_logd_ladder"]
-            one, two = ladder[0], ladder[1]
-            fair = two["fairness_min_over_max"]
-            if (two["scaling_vs_1_shard"] >= 1.5
-                    and (fair is None or fair >= 0.8)
-                    and not (one["records_dropped"]
-                             or two["records_dropped"])) or attempt == 2:
-                break
-            print("logd ladder below gate "
-                  f"({two['scaling_vs_1_shard']}x, fairness {fair}); "
-                  "retrying once", file=sys.stderr)
-    finally:
-        os.environ.pop("BENCH_AGENT", None)
-        os.environ.pop("BENCH_LOGD", None)
-    assert one["records_per_sec"] > 0
-    assert two["scaling_vs_1_shard"] >= 1.5, (
-        f"2-shard record drain {two['records_per_sec']}/s is only "
-        f"{two['scaling_vs_1_shard']}x the 1-shard "
-        f"{one['records_per_sec']}/s — the result-plane split "
-        "re-serialized")
-    assert not one["records_dropped"] and not two["records_dropped"], (
-        f"record drops under the ladder: {one['records_dropped']} / "
-        f"{two['records_dropped']}")
-    fair = two["fairness_min_over_max"]
-    assert fair is None or fair >= 0.8, (
-        f"2-shard fairness {fair} < 0.8 — one logd shard (or its "
-        "agent) is hogging the drain")
 
 
 def test_bench_sched_dag_smoke():

@@ -338,25 +338,12 @@ def test_secured_fleet_end_to_end(tmp_path):
                        "--logsink", logd_addr, "--conf", str(conf),
                        "--port", "0")
         procs += [sched_p, node_p, web_p]
-        # the native agent authenticates with the same shared secrets
-        import pathlib
-        agentd = pathlib.Path(REPO) / "native" / "cronsun-agentd"
-        nagent_p = None
-        if agentd.exists():
-            nagent_p = subprocess.Popen(
-                [str(agentd), "--store", store_addr, "--logsink", logd_addr,
-                 "--node-id", "sec-cxx", "--ttl", "5",
-                 "--store-token", "st-secret", "--log-token", "lg-secret"],
-                stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True)
-            procs.append(nagent_p)
         _await_ready(sched_p)
         _await_ready(node_p)
-        if nagent_p is not None:
-            _await_ready(nagent_p)
         web_addr = _await_ready(web_p)
 
         op, base = _login(web_addr)
-        nids = ["sec-node"] + (["sec-cxx"] if nagent_p else [])
+        nids = ["sec-node"]
         job = {"name": "sec", "command": "echo secured", "kind": 0,
                "rules": [{"timer": "* * * * * *", "nids": nids}]}
         _put_job(op, base, job)
@@ -467,133 +454,6 @@ def test_logd_crash_restart_fleet_heals(tmp_path):
         _teardown(procs)
 
 
-def test_native_agent_fleet(tmp_path):
-    """The ALL-native runtime: C++ store + C++ result store + two C++
-    agents (native/agentd.cc) under the Python/TPU scheduler and web.
-    A Common job reaches both agents, an Alone job executes exactly once
-    per planned second across them (store fences), run-now works, and a
-    SIGTERMed agent leaves a dead mirror."""
-    import pathlib
-    agentd = pathlib.Path(REPO) / "native" / "cronsun-agentd"
-    from cronsun_tpu.store.native import find_binary
-    if find_binary() is None or not agentd.exists():
-        pytest.skip("native binaries unavailable")
-    conf = tmp_path / "conf.json"
-    conf.write_text(json.dumps({
-        "log_db": str(tmp_path / "local-UNUSED.db"), "window_s": 2,
-        "node_ttl": 5, "proc_req": 0}))
-
-    procs = []
-    try:
-        store_p = _spawn("cronsun_tpu.bin.store", "--native", "--port", "0")
-        procs.append(store_p)
-        store_addr = _await_ready(store_p)
-        logd_p = _spawn("cronsun_tpu.bin.logd", "--native", "--port", "0",
-                        "--db", str(tmp_path / "logd.wal"))
-        procs.append(logd_p)
-        logd_addr = _await_ready(logd_p)
-
-        agents = []
-        for i in range(2):
-            p = subprocess.Popen(
-                [str(agentd), "--store", store_addr, "--logsink", logd_addr,
-                 "--node-id", f"cxx-{i}", "--ttl", "5", "--proc-req", "0.5"],
-                stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True)
-            procs.append(p)
-            agents.append(p)
-        for p in agents:
-            _await_ready(p)
-
-        sched_p = _spawn("cronsun_tpu.bin.sched", "--store", store_addr,
-                         "--conf", str(conf))
-        web_p = _spawn("cronsun_tpu.bin.web", "--store", store_addr,
-                       "--logsink", logd_addr, "--conf", str(conf),
-                       "--port", "0")
-        procs += [sched_p, web_p]
-        _await_ready(sched_p)
-        web_addr = _await_ready(web_p)
-
-        op, base = _login(web_addr)
-        _put_job(op, base, {
-            "name": "cxx-common", "command": "echo native-common",
-            "kind": 0,
-            "rules": [{"timer": "* * * * * *", "nids": ["cxx-0", "cxx-1"]}]})
-        _put_job(op, base, {
-            "name": "cxx-alone",
-            # echoes the cron-context env (native agentd must export the
-            # same CRONSUN_* vars as the Python agent) — the scheduled
-            # second makes cross-agent exactly-once directly assertable
-            "command": "sh -c 'echo $CRONSUN_SCHEDULED_TS $CRONSUN_NODE'",
-            "kind": 1,
-            "rules": [{"timer": "* * * * * *", "nids": ["cxx-0", "cxx-1"]}]})
-
-        from cronsun_tpu.logsink import RemoteJobLogStore
-        lh, _, lp = logd_addr.rpartition(":")
-        sink = RemoteJobLogStore(lh, int(lp))
-        deadline = time.time() + 60
-        while time.time() < deadline:
-            logs, total = sink.query_logs(page_size=200)
-            common_nodes = {l.node for l in logs if l.name == "cxx-common"}
-            n_alone = sum(1 for l in logs if l.name == "cxx-alone")
-            if total >= 8 and common_nodes == {"cxx-0", "cxx-1"} \
-                    and n_alone >= 3:
-                break
-            time.sleep(1)
-        logs, total = sink.query_logs(page_size=200)
-        assert {l.node for l in logs if l.name == "cxx-common"} == \
-            {"cxx-0", "cxx-1"}, "Common fan-out missed a native agent"
-        assert all(l.success for l in logs)
-        assert all("native-" in l.output
-                   for l in logs if l.name == "cxx-common")
-        # Alone exactly-once ACROSS both agents: every execution echoed
-        # the second it was scheduled for (cron-context env) — each
-        # scheduled second must appear exactly once fleet-wide, and the
-        # echoing node must match the record's node column
-        alone = [l for l in logs if l.name == "cxx-alone"]
-        assert alone, "Alone job never ran"
-        sched_secs = []
-        for l in alone:
-            ts, node = l.output.split()
-            assert ts.isdigit() and node == l.node, l.output
-            sched_secs.append(ts)
-        assert len(sched_secs) == len(set(sched_secs)), \
-            "a scheduled second ran on both native agents"
-
-        # run-now through the REST API reaches a native agent — the job
-        # can NEVER fire by cron (Jan 1 midnight), so a record proves
-        # the once-trigger path, not the background cadence
-        _put_job(op, base, {
-            "name": "cxx-once", "command": "echo native-once", "kind": 0,
-            "rules": [{"timer": "0 0 0 1 1 *", "nids": ["cxx-0"]}]})
-        with op.open(f"{base}/v1/jobs", timeout=10) as r:
-            jobs = json.loads(r.read())
-        jid = next(j["id"] for j in jobs if j["name"] == "cxx-once")
-        req = urllib.request.Request(
-            f"{base}/v1/job/default-{jid}/execute?node=cxx-0", method="PUT")
-        with op.open(req, timeout=10) as r:
-            assert r.status == 200
-        deadline = time.time() + 20
-        once_logs = []
-        while time.time() < deadline and not once_logs:
-            logs, _ = sink.query_logs(job_ids=[jid])
-            once_logs = logs
-            time.sleep(0.3)
-        assert once_logs, "run-now never reached the native agent"
-        assert "native-once" in once_logs[0].output
-
-        # clean shutdown: SIGTERM an agent -> mirror goes dead
-        agents[1].send_signal(signal.SIGTERM)
-        agents[1].wait(timeout=10)
-        deadline = time.time() + 10
-        while time.time() < deadline and sink.get_node("cxx-1")["alived"]:
-            time.sleep(0.3)
-        assert not sink.get_node("cxx-1")["alived"], \
-            "SIGTERMed native agent left an alive mirror"
-        sink.close()
-    finally:
-        _teardown(procs)
-
-
 def test_store_crash_restart_fleet_heals(tmp_path):
     """The deployment resilience story: the native store (with WAL) is
     killed -9 mid-flight and restarted on the same port; every client
@@ -634,40 +494,14 @@ def test_store_crash_restart_fleet_heals(tmp_path):
                        f"127.0.0.1:{port}", "--conf", str(conf),
                        "--port", "0")
         procs = [sched_p, node_p, web_p]
-        # a native agent heals the same crash (its own reconnect+resync
-        # path); it records via a logd since it has no local sqlite
-        import pathlib
-        agentd = pathlib.Path(REPO) / "native" / "cronsun-agentd"
-        nagent_p = logd_p = None
-        nsink = None
-        if agentd.exists():
-            logd_p = _spawn("cronsun_tpu.bin.logd", "--port", "0",
-                            "--db", str(tmp_path / "hz-logd.db"))
-            procs.append(logd_p)
-            logd_addr = _await_ready(logd_p)
-            nagent_p = subprocess.Popen(
-                [str(agentd), "--store", f"127.0.0.1:{port}",
-                 "--logsink", logd_addr, "--node-id", "hz-cxx",
-                 "--ttl", "5"],
-                stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True)
-            procs.append(nagent_p)
         _await_ready(sched_p)
         _await_ready(node_p)
-        if nagent_p is not None:
-            _await_ready(nagent_p)
         web_addr = _await_ready(web_p)
 
         op, base = _login(web_addr)
         job = {"name": "hz", "command": "echo heal", "kind": 0,
                "rules": [{"timer": "* * * * * *", "nids": ["hz-node"]}]}
         _put_job(op, base, job)
-        if nagent_p is not None:
-            _put_job(op, base, {
-                "name": "hz-cxx", "command": "echo heal-cxx", "kind": 0,
-                "rules": [{"timer": "* * * * * *", "nids": ["hz-cxx"]}]})
-            from cronsun_tpu.logsink import RemoteJobLogStore
-            lh, _, lp = logd_addr.rpartition(":")
-            nsink = RemoteJobLogStore(lh, int(lp))
 
         sink = JobLogStore(logdb)
 
@@ -680,20 +514,6 @@ def test_store_crash_restart_fleet_heals(tmp_path):
             time.sleep(0.5)
         before = count()
         assert before >= 3, f"no executions before crash ({before})"
-
-        def ncount():
-            if nsink is None:
-                return 0
-            _, n = nsink.query_logs()
-            return n
-
-        nbefore = ncount()
-        if nsink is not None:
-            deadline = time.time() + 30
-            while time.time() < deadline and ncount() < 2:
-                time.sleep(0.5)
-            nbefore = ncount()
-            assert nbefore >= 2, "native agent executed nothing pre-crash"
 
         # kill -9: wrapper exits via its child monitor
         store_p.send_signal(signal.SIGKILL)
@@ -709,14 +529,6 @@ def test_store_crash_restart_fleet_heals(tmp_path):
         assert after >= before + 3, \
             f"executions did not resume after store restart " \
             f"({before} -> {after})"
-        # the native agent healed too: its executions resume
-        if nsink is not None:
-            deadline = time.time() + 60
-            while time.time() < deadline and ncount() < nbefore + 3:
-                time.sleep(0.5)
-            assert ncount() >= nbefore + 3, \
-                "native agent did not resume after store restart"
-            nsink.close()
         # the job survived in the restarted store
         with op.open(f"{base}/v1/jobs", timeout=10) as r:
             jobs = json.loads(r.read())
@@ -907,342 +719,5 @@ def test_tls_fleet_end_to_end(tmp_path):
         assert total >= 2, "no executions landed through the TLS fleet"
         assert all("over-tls" in l.output for l in logs)
         sink.close()
-    finally:
-        _teardown(procs)
-
-
-def test_native_agent_claim_indeterminate_reply(tmp_path):
-    """agentd's indeterminate-claim recovery (ADVICE r4): a claim that
-    APPLIES in the store but whose reply never reaches the agent (the
-    connection dies mid-RPC) must still execute exactly once.  A
-    reply-dropping TCP proxy sits between agentd and the native store:
-    on the first '"o":"claim"' line it forwards the request, then kills
-    the connection before the reply can cross — agentd's read-back must
-    find its own per-attempt nonce on the fence and proceed."""
-    import pathlib
-    import socket
-    import threading
-    agentd = pathlib.Path(REPO) / "native" / "cronsun-agentd"
-    from cronsun_tpu.store.native import find_binary
-    if find_binary() is None or not agentd.exists():
-        pytest.skip("native binaries unavailable")
-
-    procs = []
-    try:
-        store_p = _spawn("cronsun_tpu.bin.store", "--native", "--port", "0")
-        procs.append(store_p)
-        store_addr = _await_ready(store_p)
-        sh, _, sp = store_addr.rpartition(":")
-        logd_p = _spawn("cronsun_tpu.bin.logd", "--native", "--port", "0",
-                        "--db", str(tmp_path / "logd.wal"))
-        procs.append(logd_p)
-        logd_addr = _await_ready(logd_p)
-
-        armed = threading.Event()
-        armed.set()
-        dropped = threading.Event()
-        lsock = socket.socket()
-        lsock.setsockopt(socket.SOL_SOCKET, socket.SO_REUSEADDR, 1)
-        lsock.bind(("127.0.0.1", 0))
-        lsock.listen(8)
-        proxy_port = lsock.getsockname()[1]
-        stop = threading.Event()
-
-        def pipe(c, s):
-            """client->server, line-scanned for the armed claim kill."""
-            buf = b""
-            try:
-                while not stop.is_set():
-                    data = c.recv(65536)
-                    if not data:
-                        break
-                    buf += data
-                    while b"\n" in buf:
-                        line, buf = buf.split(b"\n", 1)
-                        s.sendall(line + b"\n")
-                        if armed.is_set() and b'"o":"claim"' in line:
-                            # request delivered; reply must never return:
-                            # silence THIS connection's s->c pump FIRST,
-                            # then give the server time to apply
-                            armed.clear()
-                            dropped.set()
-                            time.sleep(0.3)
-                            c.close()
-                            s.close()
-                            return
-            except OSError:
-                pass
-            finally:
-                for x in (c, s):
-                    try:
-                        x.close()
-                    except OSError:
-                        pass
-
-        def pump(s, c, pre_drop):
-            """server->client; a connection alive at drop time goes
-            silent once the kill fires — connections agentd opens
-            AFTERWARDS (the heal + recovery reads) always forward."""
-            try:
-                while not stop.is_set():
-                    data = s.recv(65536)
-                    if not data:
-                        break
-                    if pre_drop and dropped.is_set():
-                        continue   # the lost reply (and any trailing
-                                   # pushes on the killed connection)
-                    c.sendall(data)
-            except OSError:
-                pass
-
-        def accept_loop():
-            while not stop.is_set():
-                try:
-                    c, _ = lsock.accept()
-                except OSError:
-                    return
-                s = socket.create_connection((sh, int(sp)))
-                pre_drop = not dropped.is_set()
-                threading.Thread(target=pipe, args=(c, s),
-                                 daemon=True).start()
-                threading.Thread(target=pump, args=(s, c, pre_drop),
-                                 daemon=True).start()
-
-        threading.Thread(target=accept_loop, daemon=True).start()
-
-        p = subprocess.Popen(
-            [str(agentd), "--store", f"127.0.0.1:{proxy_port}",
-             "--logsink", logd_addr, "--node-id", "cxxI",
-             "--ttl", "5", "--proc-req", "5"],
-            stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True)
-        procs.append(p)
-        _await_ready(p)
-
-        from cronsun_tpu.core import Keyspace
-        from cronsun_tpu.store.remote import RemoteStore
-        ks = Keyspace()
-        direct = RemoteStore(sh, int(sp))   # unproxied control channel
-        job_doc = json.dumps({
-            "name": "indet", "command": "echo indet-ran", "kind": 2,
-            "rules": [{"id": "r", "timer": "* * * * * *",
-                       "nids": ["cxxI"]}]})
-        direct.put(ks.job_key("g", "ij"), job_doc)
-        epoch = int(time.time()) - 2        # past: runs immediately
-        order = ks.dispatch_key("cxxI", epoch, "g", "ij")
-        direct.put(order, '{"rule":"r","kind":2}')
-
-        assert dropped.wait(timeout=30), "proxy never saw the claim RPC"
-        from cronsun_tpu.logsink import RemoteJobLogStore
-        lh, _, lp = logd_addr.rpartition(":")
-        sink = RemoteJobLogStore(lh, int(lp))
-        deadline = time.time() + 30
-        total = 0
-        while time.time() < deadline:
-            logs, total = sink.query_logs(page_size=50)
-            if total >= 1:
-                break
-            time.sleep(0.5)
-        assert total == 1, \
-            "indeterminate claim must not skip the execution (fleet-wide)"
-        assert logs[0].output.strip() == "indet-ran"
-        # the fence survives under this agent's per-attempt nonce, and
-        # the applied claim consumed the order key
-        fences = direct.get_prefix(ks.lock)
-        assert any(kv.value.startswith("cxxI@") for kv in fences), \
-            [kv.value for kv in fences]
-        assert direct.get(order) is None, "order key not consumed"
-        sink.close()
-        direct.close()
-        stop.set()
-        lsock.close()
-    finally:
-        _teardown(procs)
-
-
-def test_native_agent_consumes_coalesced_bundle(tmp_path):
-    """agentd's coalesced-order path against the native store: one
-    (node, second) bundle key fans out to per-job executions, the
-    per-job fences land under this agent's nonces, the reservation key
-    is consumed, and a DUPLICATE bundle delivery re-claims and loses
-    (exactly-once).  A legacy per-job key drains side by side (rollout
-    tolerance)."""
-    import pathlib
-    agentd = pathlib.Path(REPO) / "native" / "cronsun-agentd"
-    from cronsun_tpu.store.native import find_binary
-    if find_binary() is None or not agentd.exists():
-        pytest.skip("native binaries unavailable")
-
-    procs = []
-    try:
-        store_p = _spawn("cronsun_tpu.bin.store", "--native", "--port", "0")
-        procs.append(store_p)
-        store_addr = _await_ready(store_p)
-        sh, _, sp = store_addr.rpartition(":")
-        logd_p = _spawn("cronsun_tpu.bin.logd", "--native", "--port", "0",
-                        "--db", str(tmp_path / "logd.wal"))
-        procs.append(logd_p)
-        logd_addr = _await_ready(logd_p)
-        p = subprocess.Popen(
-            [str(agentd), "--store", store_addr, "--logsink", logd_addr,
-             "--node-id", "cxB", "--ttl", "5", "--proc-req", "5"],
-            stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True)
-        procs.append(p)
-        _await_ready(p)
-
-        from cronsun_tpu.core import Keyspace
-        from cronsun_tpu.store.remote import RemoteStore
-        ks = Keyspace()
-        direct = RemoteStore(sh, int(sp))
-        for i in range(3):
-            direct.put(ks.job_key("g", f"bj{i}"), json.dumps({
-                "name": f"bj{i}", "command": f"echo bundle-ran-{i}",
-                "kind": 2,
-                "rules": [{"id": "r", "timer": "* * * * * *",
-                           "nids": ["cxB"]}]}))
-        epoch = int(time.time()) - 2        # past: runs immediately
-        bundle = ks.dispatch_bundle_key("cxB", epoch)
-        direct.put(bundle, json.dumps(["g/bj0", "g/bj1", "g/bj2"]))
-        legacy = ks.dispatch_key("cxB", epoch, "g", "bj0")
-        # legacy key for a DIFFERENT second: exercises both formats
-        legacy = ks.dispatch_key("cxB", epoch - 1, "g", "bj0")
-        direct.put(legacy, '{"rule":"r","kind":2}')
-
-        from cronsun_tpu.logsink import RemoteJobLogStore
-        lh, _, lp = logd_addr.rpartition(":")
-        sink = RemoteJobLogStore(lh, int(lp))
-        deadline = time.time() + 30
-        total = 0
-        while time.time() < deadline:
-            logs, total = sink.query_logs(page_size=50)
-            if total >= 4:
-                break
-            time.sleep(0.5)
-        assert total == 4, f"expected 3 bundle + 1 legacy runs, got {total}"
-        assert direct.get(bundle) is None, "bundle key not consumed"
-        assert direct.get(legacy) is None, "legacy key not consumed"
-        fences = direct.get_prefix(ks.lock)
-        bundle_fences = [kv for kv in fences
-                         if kv.key.endswith(f"/{epoch}")]
-        assert len(bundle_fences) == 3
-        assert all(kv.value.startswith("cxB@") for kv in bundle_fences), \
-            [kv.value for kv in bundle_fences]
-
-        # duplicate bundle: every fence loses, nothing re-runs
-        direct.put(bundle, json.dumps(["g/bj0", "g/bj1", "g/bj2"]))
-        deadline = time.time() + 10
-        while time.time() < deadline and direct.get(bundle) is not None:
-            time.sleep(0.3)
-        assert direct.get(bundle) is None, "duplicate bundle not consumed"
-        time.sleep(1.0)
-        _, total = sink.query_logs(page_size=50)
-        assert total == 4, "duplicate bundle re-ran a member"
-        sink.close()
-        direct.close()
-    finally:
-        _teardown(procs)
-
-
-def test_native_agentd_record_flusher_batches_and_barriers(tmp_path):
-    """agentd's background record flusher: a burst of instant
-    executions lands in the result store through a handful of bulk
-    create_job_logs RPCs (not one lock-step RPC per exec — the
-    BENCH_r05 ~0.7k/s ceiling), stat counters exactly match the
-    executions (no loss, no double-count under the batch-coalesced
-    logd path), and a SIGTERM right after the orders are consumed
-    still lands every buffered record (the stop() flush barrier)."""
-    import pathlib
-    agentd = pathlib.Path(REPO) / "native" / "cronsun-agentd"
-    from cronsun_tpu.store.native import find_binary
-    if find_binary() is None or not agentd.exists():
-        pytest.skip("native binaries unavailable")
-
-    procs = []
-    try:
-        store_p = _spawn("cronsun_tpu.bin.store", "--native", "--port", "0")
-        procs.append(store_p)
-        store_addr = _await_ready(store_p)
-        sh, _, sp = store_addr.rpartition(":")
-        logd_p = _spawn("cronsun_tpu.bin.logd", "--native", "--port", "0",
-                        "--db", str(tmp_path / "logd.wal"))
-        procs.append(logd_p)
-        logd_addr = _await_ready(logd_p)
-        p = subprocess.Popen(
-            [str(agentd), "--store", store_addr, "--logsink", logd_addr,
-             "--node-id", "cxF", "--ttl", "5", "--proc-req", "5",
-             "--instant-exec"],
-            stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True)
-        procs.append(p)
-        _await_ready(p)
-
-        from cronsun_tpu.core import Keyspace
-        from cronsun_tpu.logsink import RemoteJobLogStore
-        from cronsun_tpu.store.remote import RemoteStore
-        ks = Keyspace()
-        direct = RemoteStore(sh, int(sp))
-        lh, _, lp = logd_addr.rpartition(":")
-        sink = RemoteJobLogStore(lh, int(lp))
-
-        # N crosses the oversized-bundle chunk boundary (2048): the
-        # bundle fans out as concurrent chunk tasks — every member
-        # still runs exactly once and the reservation key is released
-        N = 3000
-        direct.put_many([
-            (ks.job_key("g", f"fj{i}"), json.dumps({
-                "name": f"fj{i}", "command": "true", "kind": 2,
-                "rules": [{"id": "r", "timer": "* * * * * *",
-                           "nids": ["cxF"]}]}))
-            for i in range(N)])
-        epoch = int(time.time()) - 2        # past: runs immediately
-        bundle = ks.dispatch_bundle_key("cxF", epoch)
-        direct.put(bundle, json.dumps([f"g/fj{i}" for i in range(N)]))
-
-        deadline = time.time() + 30
-        while time.time() < deadline:
-            if sink.stat_overall()["total"] >= N:
-                break
-            time.sleep(0.2)
-        assert sink.stat_overall() == {
-            "total": N, "successed": N, "failed": 0}
-        # the chunked reservation release rides the buffered ack flush
-        # (only after EVERY chunk settled) — poll briefly
-        deadline = time.time() + 10
-        while time.time() < deadline and direct.get(bundle) is not None:
-            time.sleep(0.1)
-        assert direct.get(bundle) is None, "reservation key not released"
-        # a DUPLICATE chunked delivery re-claims and loses every fence
-        direct.put(bundle, json.dumps([f"g/fj{i}" for i in range(N)]))
-        deadline = time.time() + 15
-        while time.time() < deadline and direct.get(bundle) is not None:
-            time.sleep(0.2)
-        assert direct.get(bundle) is None, "duplicate bundle not consumed"
-        time.sleep(1.0)
-        assert sink.stat_overall()["total"] == N, \
-            "duplicate chunked bundle re-ran a member"
-        # batched, not lock-step: the whole burst rode far fewer bulk
-        # RPCs than records (the flusher ships interval-capped batches)
-        stats = sink.op_stats()
-        bulk = stats.get("create_job_logs", {}).get("count", 0)
-        singles = stats.get("create_job_log", {}).get("count", 0)
-        nrecs = stats.get("log_records", {}).get("count", 0)
-        assert nrecs == N and singles == 0, stats
-        assert 0 < bulk <= N // 4, \
-            f"record wire not batched: {bulk} RPCs for {N} records"
-
-        # flush barrier on stop: a second burst, SIGTERM the moment the
-        # order key is consumed — records still in the 50 ms buffer
-        # must land before the process exits
-        epoch2 = int(time.time()) - 1
-        bundle2 = ks.dispatch_bundle_key("cxF", epoch2)
-        direct.put(bundle2, json.dumps([f"g/fj{i}" for i in range(50)]))
-        deadline = time.time() + 15
-        while time.time() < deadline and direct.get(bundle2) is not None:
-            time.sleep(0.02)
-        assert direct.get(bundle2) is None, "second bundle not consumed"
-        p.send_signal(signal.SIGTERM)
-        p.wait(timeout=15)
-        assert sink.stat_overall()["total"] == N + 50, \
-            f"stop() barrier lost buffered records: {sink.stat_overall()}"
-        sink.close()
-        direct.close()
     finally:
         _teardown(procs)

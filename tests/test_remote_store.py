@@ -778,3 +778,39 @@ def test_get_prefix_paged_falls_back_on_old_server(monkeypatch):
     finally:
         s.close()
         srv.stop()
+
+
+def test_find_binary_builds_once_under_contention(tmp_path, monkeypatch):
+    """Two callers that both find the binary stale (xdist workers, a
+    `bin.store --native` beside them) must not both run `make`, and
+    neither may get the path back while the other is still linking."""
+    import os
+    import subprocess
+    import threading
+
+    from cronsun_tpu import native_launcher
+
+    (tmp_path / "foo.cc").write_text("int main() {}\n")
+    cand = tmp_path / "cronsun-foo"
+    makes = []
+
+    def fake_make(argv, **kw):
+        makes.append(argv)
+        time.sleep(0.3)            # a link in progress
+        cand.write_text("#!/bin/sh\n")
+        cand.chmod(0o755)
+        return subprocess.CompletedProcess(argv, 0)
+
+    monkeypatch.setattr(native_launcher, "NATIVE_DIR", tmp_path)
+    monkeypatch.setattr(native_launcher.subprocess, "run", fake_make)
+    monkeypatch.delenv("CRONSUN_FOO", raising=False)
+    got = []
+    threads = [threading.Thread(target=lambda: got.append(
+        (native_launcher.find_binary("cronsun-foo", "CRONSUN_FOO"),
+         os.access(cand, os.X_OK)))) for _ in range(2)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    assert got == [(str(cand), True)] * 2
+    assert len(makes) == 1, makes

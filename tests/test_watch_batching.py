@@ -73,13 +73,7 @@ class _RawWatchClient:
 
 
 def _event_count(frames):
-    n = 0
-    for f in frames:
-        if "evs" in f:
-            n += len(f["evs"])
-        elif "ev" in f:
-            n += 1
-    return n
+    return sum(len(f["evs"]) for f in frames if "evs" in f)
 
 
 @pytest.mark.parametrize("backend", BACKENDS)
