@@ -438,7 +438,13 @@ class NodeAgent:
                       "trace_spans_total": 0, "trace_spans_dropped_total": 0,
                       "execs_demoted_total": 0,
                       "avg_time_writebacks_total": 0,
-                      "stage_scan_enqueued_max": 0}
+                      "stage_scan_enqueued_max": 0,
+                      # Alone fires skipped here, at their second,
+                      # behind a live lifetime lock: the one place a
+                      # KindAlone lock is judged (the scheduler orders
+                      # every due fire).  Growing: runs outlast their
+                      # period
+                      "alone_skipped_total": 0}
         # fire-lifecycle tracing: head-sampled (or failed, or per-job
         # trace:true) executions buffer a span here and ride the record
         # flush — zero extra RPCs on the hot path.  The verdict is the
@@ -756,8 +762,10 @@ class NodeAgent:
         """Fleet-wide running lock for KindAlone: held under a lease with
         keepalive for the execution's lifetime, released on completion
         (reference job.go:87-123).  A still-running Alone job blocks the
-        next fire everywhere.  Returns (lease, stop_event) or None if the
-        lock is already live."""
+        next fire everywhere.  Tried here, at the fire's second, and
+        nowhere else (reference job.go:243-271).  Returns (lease,
+        stop_event), or None if the lock is already live: the fire is
+        skipped and counted (alone_skipped_total)."""
         # TTL is a crash-safety net only (keepalive holds the lock while we
         # live); sized from the cost estimate like the reference's lockTtl
         # (job.go:194-233).
@@ -774,9 +782,11 @@ class NodeAgent:
             # lost every other member of the bundle with it
             log.warnf("alone lock for %s: its %.0fs lease expired before "
                       "the put landed; fire skipped", job.id, ttl)
+            self._bump("alone_skipped_total")
             return None
         if not won:
             self.store.revoke(lease)
+            self._bump("alone_skipped_total")
             return None
         stop = threading.Event()
 

@@ -40,7 +40,6 @@ from jax.profiler import TraceAnnotation
 
 from .. import log
 from ..core import Group, Job, Keyspace, TenantQuota
-from ..core.models import KIND_ALONE
 from ..cron.parser import ParseError, parse
 from ..metrics import LatencyRing, MetricsPublisher, PhaseClock, Spans
 from ..ops.deps import NEVER as DEP_NEVER, POLICY_BY_NAME
@@ -246,7 +245,9 @@ class SchedulerService:
         # entry), maintained by the job watch handlers so the per-fire
         # order-build loop is dict-lookup + list-append only — no
         # json.dumps, no Job lookup per fire (the leader's order build is
-        # on the dispatch plane's critical path).
+        # on the dispatch plane's critical path).  No builder reads the
+        # kind (the node judges a KindAlone lock); it stays because the
+        # tuples are checkpointed in this shape.
         self._row_dispatch: Dict[
             int, Tuple[bool, str, str, str, int, str, str]] = {}
         # the same dispatch cache as PARALLEL per-row ARRAYS, so the
@@ -259,7 +260,7 @@ class SchedulerService:
         # fire built from the just-previous revision of a row — is the
         # same one-window staleness the device table already has).
         J = self.planner.J
-        self._rd_flags = np.zeros(J, np.uint8)   # 1 valid|2 excl|4 alone
+        self._rd_flags = np.zeros(J, np.uint8)   # 1 valid|2 excl
         # plain lists, extracted in batch with operator.itemgetter —
         # measurably faster than object-ndarray fancy indexing (which
         # pays a PyObject alloc+incref per element per array)
@@ -415,17 +416,16 @@ class SchedulerService:
         self._agg_excl_avail = float("inf")
 
         # watch-fed mirrors of the execution-state prefixes (proc registry,
-        # outstanding exclusive orders, Alone lifetime locks).  The hot loop
-        # must NOT re-list these every second — at planner fire rates that
-        # serializes the whole keyspace over TCP per step; deltas arrive by
-        # watch and a periodic anti-entropy re-list bounds drift.
+        # outstanding exclusive orders).  The hot loop must NOT re-list
+        # these every second — at planner fire rates that serializes the
+        # whole keyspace over TCP per step; deltas arrive by watch and a
+        # periodic anti-entropy re-list bounds drift.
         # Mirror values are (node, cost, exclusive) FROZEN at entry time,
         # and per-node counters advance incrementally with the mirrors —
         # reconcile_capacity is O(nodes), not O(outstanding) (r4 measured
         # 548 ms/step of re-iteration at the 1M scale).
         self._procs: Dict[str, Tuple[str, float, bool]] = {}
         self._orders: Dict[str, Tuple[str, float, bool]] = {}
-        self._alone_live: Set[str] = set()
         self._excl_cnt: Dict[str, int] = {}    # node -> reserved slots
         self._load_sum: Dict[str, float] = {}  # node -> running cost
         self.mirror_resync_s = 30.0
@@ -651,10 +651,8 @@ class SchedulerService:
                       "compiles_total": 0, "compile_s_total": 0.0,
                       "cache_loads_total": 0, "compiles_leading_total": 0,
                       # fires the order build drops without a trace
-                      # downstream: an Alone fire whose lock the mirror
-                      # showed live at build time; an exclusive fire
-                      # placed on a node that left the fleet since
-                      "alone_left_out_total": 0,
+                      # downstream: an exclusive fire placed on a node
+                      # that left the fleet since
                       "fires_node_gone_total": 0}
         self._compile_mu = threading.Lock()
         _listen_for_compiles(self)
@@ -708,10 +706,6 @@ class SchedulerService:
         if self.checkpoint_dir and self._delta_on:
             self._delta_buf = []
 
-    @property
-    def _alone_pfx(self) -> str:
-        return self.ks.alone_lock
-
     def _open_watches(self, start_rev: int = 0):
         """Open every watch; with ``start_rev`` (checkpoint restore),
         resume each stream from that revision so the deltas since the
@@ -739,7 +733,6 @@ class SchedulerService:
             # consumption/expiry arrives as DELETEs; other-leader writes
             # are covered by anti-entropy.
             self._w_orders = w(self.ks.dispatch, events="delete")
-            self._w_alone = w(self._alone_pfx)
             # workflow DAG completion events (agents write one key per
             # job round; the fold into the success-epoch vectors is the
             # dep-trigger edge signal)
@@ -764,7 +757,7 @@ class SchedulerService:
 
     def _all_watches(self):
         base = (self._w_jobs, self._w_groups, self._w_nodes,
-                self._w_procs, self._w_orders, self._w_alone,
+                self._w_procs, self._w_orders,
                 self._w_deps, self._w_tenants, self._w_ckpt)
         return base + (self._w_acct,) if self._w_acct is not None \
             else base
@@ -1169,8 +1162,7 @@ class SchedulerService:
             self._trace.fnv_partial(group + "/" + job_id + "|"))
         self._rd_tflag[row] = bool(getattr(job, "trace", False))
         self._rd_jitter[row] = int(getattr(job, "jitter", 0) or 0)
-        self._rd_flags[row] = (1 | (2 if job.exclusive else 0)
-                               | (4 if job.kind == KIND_ALONE else 0))
+        self._rd_flags[row] = 1 | (2 if job.exclusive else 0)
 
     # ---- multi-tenant control plane -------------------------------------
 
@@ -1740,8 +1732,7 @@ class SchedulerService:
                        ("jobs", self._w_jobs),
                        ("deps", self._w_deps),
                        ("procs", self._w_procs),
-                       ("orders", self._w_orders),
-                       ("alone", self._w_alone)):
+                       ("orders", self._w_orders)):
             for ev in w.drain():
                 if rec is not None:
                     rec.append((sid, ev.type, ev.kv.key, ev.kv.value))
@@ -1865,8 +1856,9 @@ class SchedulerService:
                         self._dep_epoch_updates[row] = (succ, fail)
         # execution-state mirrors: proc registry (leased keys expire ->
         # DELETE events age dead executions out), outstanding exclusive
-        # orders (delete-only watch: own puts mirrored at submit), Alone
-        # lifetime locks
+        # orders (delete-only watch: own puts mirrored at submit).  An
+        # "alone" event in a delta chain the parent tree wrote (it
+        # mirrored the Alone lifetime locks) matches no branch: ignored
         elif sid == "procs":
             if typ == DELETE:
                 self._acct_del(self._procs, key)
@@ -1882,14 +1874,6 @@ class SchedulerService:
                 t = self._parse_order(key)             # suppress these
                 if t and (self._owns is None or self._owns(t[2])):
                     self._acct_add(self._orders, key, *t)
-        elif sid == "alone":
-            jid = key[len(self._alone_pfx):]
-            if self._owns is not None and not self._owns(jid):
-                return
-            if typ == DELETE:
-                self._alone_live.discard(jid)
-            else:
-                self._alone_live.add(jid)
         elif sid == "ordmirror":
             try:
                 node, jobs = value
@@ -2115,18 +2099,11 @@ class SchedulerService:
             t = self._parse_order(kv.key)
             if t and (self._owns is None or self._owns(t[2])):
                 add(orders, kv.key, *t)
-        alone = {kv.key[len(self._alone_pfx):]
-                 for kv in _list_prefix(store, self._alone_pfx)
-                 if self._owns is None
-                 or self._owns(kv.key[len(self._alone_pfx):])}
-        return procs, orders, alone, excl, load, order_tids
+        return procs, orders, excl, load, order_tids
 
     def _install_mirrors(self, built):
-        order_tids = None
-        if len(built) == 6:
-            *built, order_tids = built
-        self._procs, self._orders, self._alone_live, \
-            self._excl_cnt, self._load_sum = built
+        self._procs, self._orders, self._excl_cnt, self._load_sum, \
+            order_tids = built
         # ground-truth rebuild of the dep in-flight counters from the
         # fresh procs mirror (the incremental counters drift with the
         # same bounded windows the load/excl counters do)
@@ -2493,7 +2470,6 @@ class SchedulerService:
             col_live=np.array(self._col_live),
             mirrors=dict(procs=dict(self._procs),
                          orders=dict(self._orders),
-                         alone=set(self._alone_live),
                          excl=dict(self._excl_cnt),
                          load=dict(self._load_sum)),
         )
@@ -2543,8 +2519,9 @@ class SchedulerService:
                     ("dep", ("latest",)),
                     ("rd", ("flags", "payload", "suffix", "bentry",
                             "job")),
-                    ("mirrors", ("procs", "orders", "alone", "excl",
-                                 "load"))):
+                    # the parent tree's "alone" (its mirror of the
+                    # Alone lifetime locks) is accepted and not read
+                    ("mirrors", ("procs", "orders", "excl", "load"))):
                 if not isinstance(st.get(outer), dict):
                     missing.append(outer)
                 else:
@@ -2766,7 +2743,6 @@ class SchedulerService:
         m = st["mirrors"]
         self._procs = m["procs"]
         self._orders = m["orders"]
-        self._alone_live = m["alone"]
         self._excl_cnt = m["excl"]
         self._load_sum = m["load"]
         # workflow DAG state: the completion mirror + device vectors
@@ -3598,7 +3574,7 @@ class SchedulerService:
         pipeline's build stage, invoked on the WindowBuilder thread
         while the device already computes the next window.
 
-        Reads of the row-dispatch arrays / alone mirror may race a
+        Reads of the row-dispatch arrays may race a
         concurrent watch drain on the step thread; every such race is
         the same one-window staleness the device table itself has
         (plans were dispatched a window ago), and the flags-last write
@@ -3765,12 +3741,11 @@ class SchedulerService:
         hole-rewind rebuild) the target's arrivals are PREPENDED to its
         native fires — oldest source second first — and
         the merged plan runs through the unsmeared vectorized build, so
-        coalescing, the KindAlone live-lock skip, the tenancy
-        max_running clamp, the herd gauges and trace sampling all apply
-        at the EMISSION second.  Fences, (node, second) bundle keys and
-        dedup therefore key on the smeared epoch with no downstream
-        change, and agents derive trace ids from the order-key epoch
-        exactly as before.
+        coalescing, the tenancy max_running clamp, the herd gauges and
+        trace sampling all apply at the EMISSION second.  Fences,
+        (node, second) bundle keys and dedup therefore key on the
+        smeared epoch with no downstream change, and agents derive trace
+        ids from the order-key epoch exactly as before.
 
         The ring is NOT consumed on read: a rebuilt window re-reads the
         same arrivals, keeping the bundle-overwrite-is-a-superset
@@ -3977,11 +3952,6 @@ class SchedulerService:
                         flags = self._rd_flags[row]
                         if not flags & 1:
                             continue   # job dropped since the source
-                        if flags & 4 and self._alone_live and \
-                                self._rd_job[row][1] in self._alone_live:
-                            # KindAlone lifetime lock is live
-                            self.stats["alone_left_out_total"] += 1
-                            continue
                         if flags & 2:
                             if not 0 <= col < len(self._col_node):
                                 continue
@@ -4142,17 +4112,17 @@ class SchedulerService:
         rows fancy-index precomputed per-row arrays, a stable argsort
         groups exclusive fires by node column, and each coalesced
         (node, second) value is ONE join over precomputed JSON entry
-        strings.  Python-level work is O(nodes + alone-fires), not
-        O(fires).
+        strings.  Python-level work is O(nodes), not O(fires).
 
         Semantics are byte-identical to :meth:`_build_plan_orders_ref`
         (the retired loop, kept as the differential-test reference):
         routing branches on the ROW's exclusive flag, not the plan's
         bucket split (mesh planners don't populate n_excl, and a flag
         mismatch must never turn a placed exclusive fire into a
-        broadcast); KindAlone fires whose lifetime lock is live
-        anywhere are skipped (reference job.go:87-123) via the
-        watch-fed mirror; exclusive fires COALESCE into one key per
+        broadcast); a KindAlone fire is ordered like any exclusive fire
+        — its node tries the lifetime lock at the fire's second
+        (reference job.go:243-271), nothing here judges it a window
+        ahead; exclusive fires COALESCE into one key per
         (node, second) — nodes in first-fire order, entries in plan
         order — whose re-publish (overflow replan, hole rewind)
         OVERWRITES the bundle; Common fires stay one broadcast key per
@@ -4185,18 +4155,6 @@ class SchedulerService:
         if rows.size:
             flags = self._rd_flags[rows]
             live = (flags & 1) != 0
-            # only the (typically few) KindAlone fires pay a Python
-            # set lookup against the lifetime-lock mirror
-            if self._alone_live:
-                al = np.flatnonzero(live & ((flags & 4) != 0))
-                if al.size:
-                    alone_live = self._alone_live
-                    rd_job = self._rd_job
-                    drop = [int(i) for i in al
-                            if rd_job[rows[i]][1] in alone_live]
-                    if drop:
-                        live[drop] = False
-                        self.stats["alone_left_out_total"] += len(drop)
             is_excl = (flags & 2) != 0
             ep = str(plan.epoch_s)
             # Common fan-out, in plan order: ONE broadcast order per
@@ -4322,7 +4280,6 @@ class SchedulerService:
                         - self._tenant_excl.get(tid, 0)
                         - (pending_excl or {}).get(tid, 0))
         mr_taken: Dict[int, int] = {}
-        alone_live = self._alone_live
         row_disp = self._row_dispatch
         col_node = self._col_node
         disp_pfx = self.ks.dispatch
@@ -4341,11 +4298,7 @@ class SchedulerService:
             ent = row_disp.get(row)
             if ent is None:
                 continue
-            exclusive, payload, group, job_id, kind, suffix, bentry = ent
-            if kind == KIND_ALONE and job_id in alone_live:
-                # previous run still holds the fleet lock
-                self.stats["alone_left_out_total"] += 1
-                continue
+            exclusive, payload, group, job_id, _kind, suffix, bentry = ent
             if exclusive:
                 if 0 <= node_col < n_cols:
                     node = col_node[node_col]
@@ -4571,7 +4524,12 @@ class SchedulerService:
                    else self.stats[k])
                for k in ("compiles_total", "compile_s_total",
                          "cache_loads_total", "compiles_leading_total",
-                         "alone_left_out_total", "fires_node_gone_total")},
+                         "fires_node_gone_total")},
+            # constant: no site is left that leaves an Alone fire out
+            # (the node judges the lock at the fire's second, and counts
+            # alone_skipped_total); benchmarks/metrics/alone_left_out.py
+            # reads the key
+            "alone_left_out_total": 0,
             # the cold load by phase, seconds from the OS's process
             # start to READY (total); a checkpoint restore leaves
             # lists/jobs at 0 and checkpoint_restore_ms holds the time

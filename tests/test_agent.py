@@ -761,43 +761,69 @@ def test_bundle_tolerates_legacy_keys_side_by_side():
     store.close()
 
 
+@pytest.mark.parametrize("order", ["bundle", "bundle of it alone",
+                                   "single order"])
 @pytest.mark.parametrize("why", ["previous run live",
                                  "lock lease expired before its put"])
-def test_bundle_alone_skip_does_not_consume_fence(why):
-    """A KindAlone member whose previous run still holds the lifetime
-    lock is skipped WITHOUT consuming its (job, second) fence — the
-    lock-first ordering survives coalescing — while the rest of the
-    bundle runs and the reservation is still released.  Likewise a
-    member whose fresh lock lease (5 s for a fast job) ran out before
-    its put landed, behind a store round trip longer than that: the
-    store refuses the put, and the bundle's other members still run."""
+def test_bundle_alone_skip_does_not_consume_fence(why, order):
+    """A KindAlone fire whose previous run still holds the lifetime
+    lock is skipped here, on the node, at its second — the one place
+    the lock is judged — WITHOUT consuming its (job, second) fence (the
+    lock-first ordering survives coalescing), and counted
+    (``alone_skipped_total``); the rest of its bundle runs, and the
+    order key — the scheduler's capacity reservation — is released
+    whichever way the order came: in a bundle with others (the claim
+    takes the key), in a bundle with nothing else claimable, or as a
+    late single order (both acked).  Likewise a fire whose fresh lock
+    lease (5 s for a fast job) ran out before its put landed, behind a
+    store round trip longer than that: the store refuses the put.  Once
+    the lock is let go, the job's next order runs, once."""
     store, sink = MemStore(), JobLogStore()
     agent = NodeAgent(store, sink, node_id="n0")
     agent.register()
-    jobs = _seed_excl(store, 1, prefix="az")
+    jobs = _seed_excl(store, 1, prefix="az") if order == "bundle" else []
     alone = Job(id="alz", name="alz", group="g", command="echo a", kind=1,
                 rules=[JobRule(id="r", timer="* * * * * *", nids=["n0"])])
     store.put(KS.job_key("g", "alz"), alone.to_json())
+    real = store.put_if_absent
     if why == "previous run live":
         store.put(KS.alone_lock_key("alz"), "other")
     else:
-        real = store.put_if_absent
-
         def late(key, value, lease=0):
             if key == KS.alone_lock_key("alz"):
                 store.revoke(lease)             # its ttl ran out in flight
             return real(key, value, lease=lease)
         store.put_if_absent = late
-    epoch = int(time.time()) - 1
-    key = KS.dispatch_bundle_key("n0", epoch)
-    store.put(key, _bundle(jobs + [("g", "alz")], epoch))
-    agent.poll()
-    agent.join_running()
+
+    def deliver(epoch):
+        if order == "single order":
+            key = KS.dispatch_key("n0", epoch, "g", "alz")
+            store.put(key, '{"rule":"r","kind":1}')
+        else:
+            key = KS.dispatch_bundle_key("n0", epoch)
+            store.put(key, _bundle(jobs + [("g", "alz")], epoch))
+        agent.poll()
+        agent.join_running()
+        return key
+
+    epoch = int(time.time()) - 2
+    key = deliver(epoch)
     recs, total = sink.query_logs()
-    assert total == 1 and recs[0].job_id == "az0"
+    assert [r.job_id for r in recs] == [j for _g, j in jobs]
     assert store.get(KS.lock_key("alz", epoch)) is None, \
         "Alone skip consumed the fence"
     assert store.get(key) is None, "reservation not released"
+    assert agent.metrics_snapshot()["alone_skipped_total"] == 1
+    # the previous run ends: the next fire is ordered like any other
+    store.delete(KS.alone_lock_key("alz"))
+    store.put_if_absent = real
+    key = deliver(epoch + 1)
+    recs, total = sink.query_logs(job_ids=["alz"])
+    assert total == 1, "the fire after the lock was let go ran once"
+    assert store.get(KS.lock_key("alz", epoch + 1)) is not None
+    assert store.get(key) is None
+    assert store.get(KS.alone_lock_key("alz")) is None, "lock let go"
+    assert agent.metrics_snapshot()["alone_skipped_total"] == 1
     agent.stop()
     store.close()
 

@@ -551,7 +551,8 @@ def test_sched_checkpoint_roundtrip_identical_dispatch(sched_world):
     of the current store it fires the exact same (epoch, job) set
     (placement of group-placed jobs may permute with row order).  The
     delta replayed between checkpoint and takeover covers a job added,
-    a job deleted, a node added, and proc + alone-lock mirror entries."""
+    a job deleted, a node added, a proc mirror entry and an Alone lock
+    (which no mirror holds: the node judges it)."""
     store, ks, d, svcs = sched_world
     a = _make_sched(store, ks, "A")
     svcs.append(a)
@@ -584,7 +585,6 @@ def test_sched_checkpoint_roundtrip_identical_dispatch(sched_world):
     assert b.universe.index == a.universe.index
     assert b.rows.by_cmd == a.rows.by_cmd
     assert b._procs == a._procs
-    assert b._alone_live == a._alone_live
     assert b._excl_cnt == a._excl_cnt
 
     ep = (int(time.time()) // 60 + 2) * 60
@@ -727,6 +727,46 @@ def test_sched_checkpoint_missing_field_falls_back_cold(sched_world):
     assert len(b.jobs) == 64
 
 
+def test_sched_checkpoint_of_the_lock_mirroring_parent_restores(sched_world):
+    """A scheduler that mirrored the KindAlone locks wrote them into its
+    checkpoints: ``mirrors["alone"]`` in the base, ``alone`` events in
+    the delta chain.  Both still restore warm here — the key and the
+    events are ignored — and the restored scheduler plans the same
+    window as the live one."""
+    import pickle
+    store, ks, d, svcs = sched_world
+    a = _make_sched(store, ks, "A", checkpoint_dir=d)
+    svcs.append(a)
+    assert a.checkpoint_save()["kind"] == "full"
+    _mutate_store(store, ks)
+    a.drain_watches()
+    assert a.checkpoint_save()["kind"] == "delta"
+    base, delta = (os.path.join(d, f) for f in ("sched.ckpt",
+                                                "sched.ckpt.d1"))
+    st = pickle.load(open(base, "rb"))
+    assert sorted(st["mirrors"]) == ["excl", "load", "orders", "procs"]
+    st["mirrors"]["alone"] = {"j2"}
+    st["rd"]["flags"][st["rows"]["by_cmd"][("g", "j2", "r")]] |= 4  # its
+    # row flag for an Alone job
+    rec = pickle.load(open(delta, "rb"))
+    assert not any(ev[0] == "alone" for ev in rec["events"])
+    rec["events"] += [("alone", "PUT", ks.alone_lock_key("j2"), "n0"),
+                      ("alone", "DELETE", ks.alone_lock_key("j2"), "")]
+    for path, obj in ((base, st), (delta, rec)):
+        with open(path, "wb") as f:
+            pickle.dump(obj, f)
+    b = _make_sched(store, ks, "B", checkpoint_dir=d)
+    svcs.append(b)
+    assert b.checkpoint_restored
+    b.drain_watches()
+    b._flush_device()
+    a._flush_device()
+    assert b.rows.by_cmd == a.rows.by_cmd and b._procs == a._procs
+    ep = (int(time.time()) // 60 + 2) * 60
+    assert _window_orders(b, ep) == _window_orders(a, ep)
+    assert _window_orders(b, ep)[0] > 0
+
+
 def test_sched_checkpoint_request_key_triggers_save(sched_world):
     """The operator trigger: a PUT on the ckpt request key (what the
     web /v1/checkpoint endpoint writes) makes the scheduler save and
@@ -768,8 +808,8 @@ def test_sched_periodic_checkpoint(sched_world):
 # ---------------------------------------------------------------------------
 
 def _mutate_store(store, ks, tag="extra"):
-    """A small representative delta: job add, job delete, node add,
-    proc + alone mirror entries."""
+    """A small representative delta: job add, job delete, node add, a
+    proc mirror entry, and an Alone lock (mirrored nowhere)."""
     store.put(f"{ks.cmd}g/{tag}", json.dumps(
         {"name": tag, "command": "true", "kind": 2,
          "rules": [{"id": "r", "timer": "@every 10s", "nids": ["n1"]}]}))
@@ -807,7 +847,6 @@ def test_delta_checkpoint_roundtrip_identical(sched_world):
     assert ("g", "extra") in b.jobs and ("g", "j5") not in b.jobs
     assert b.rows.by_cmd == a.rows.by_cmd
     assert b._procs == a._procs
-    assert b._alone_live == a._alone_live
     assert b._excl_cnt == a._excl_cnt
     ep = (int(time.time()) // 60 + 2) * 60
     assert _window_orders(b, ep) == _window_orders(a, ep)

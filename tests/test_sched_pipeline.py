@@ -62,19 +62,24 @@ def test_vectorized_build_byte_identical_on_randomized_plans():
         store.put(KS.job_key("g", job.id), job.to_json())
     sched = SchedulerService(store, job_capacity=64, node_capacity=8,
                              window_s=1, node_id="vec-sched")
-    # one Alone job's lifetime lock is LIVE: its fires must be skipped
+    # one Alone job's lifetime lock is LIVE: its fires are ordered all
+    # the same (the node judges the lock at the fire's second)
     store.put(KS.alone_lock_key("vj01"), "held")
     # one node dies: its column must route to nothing
     store.delete(KS.node_key("dn3"))
     sched.drain_watches()
-    assert "vj01" in sched._alone_live
     J, N = sched.planner.J, sched.planner.N
     rng = np.random.default_rng(7)
     rows_pool = np.arange(J)     # includes rows with no dispatch entry
+    vj01 = (sched.rows.by_cmd[("g", "vj01", "r")],
+            sched.universe.index["dn1"])
     for trial in range(25):
         f = int(rng.integers(0, 70))
         fired = rng.choice(rows_pool, size=f, replace=True)
         assigned = rng.integers(-2, N + 3, size=f)
+        if trial == 0:      # the locked job fires, placed on its node
+            fired, assigned = np.append(fired, vj01[0]), \
+                np.append(assigned, vj01[1])
         plan = TickPlan(epoch_s=1_753_940_000 + trial,
                         fired=np.asarray(fired, np.int32),
                         assigned=np.asarray(assigned, np.int32),
@@ -86,6 +91,11 @@ def test_vectorized_build_byte_identical_on_randomized_plans():
         assert sec_v == sec_r, f"trial {trial}: orders diverged"
         assert acct_v == acct_r, f"trial {trial}: accounting diverged"
         assert n_v == n_r, f"trial {trial}: fire count diverged"
+        if trial == 0:
+            for sec in (sec_v, sec_r):
+                assert any("/dn1/" in k and '"g/vj01"' in v
+                           for _s, orders in sec for k, v in orders), \
+                    "the Alone fire behind a live lock was left out"
     sched.stop()
     store.close()
 

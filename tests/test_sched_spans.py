@@ -415,7 +415,8 @@ def test_one_compile_listener_a_process():
 
 
 # ---------------------------------------------------------------------------
-# what the order build drops silently, counted one per fire
+# what the order build drops silently, counted one per fire — and what it
+# does not drop: an Alone fire, whatever its lifetime lock says
 # ---------------------------------------------------------------------------
 
 def build_fixture():
@@ -460,25 +461,28 @@ def build(svc, plan, site):
 
 
 @pytest.mark.parametrize("site", ["native", "ref", "late"])
-def test_alone_left_out_counts_each_fire_the_live_lock_drops(site):
+def test_alone_fire_is_ordered_whatever_its_lock_says(site):
+    """The scheduler judges no KindAlone lock: with sj02's lifetime lock
+    live in the store when the window is built, every due fire of it is
+    ordered to its node like an Interval fire (the node tries the lock
+    at the fire's second), and the two builders agree byte for byte."""
     store, svc, rows = build_fixture()
-    # sj02 and sj05 are Alone; sj02's lifetime lock is live in the mirror
+    # sj02 and sj05 are Alone; sj02's previous run is live
     store.put(KS.alone_lock_key("sj02"), "held")
     svc.drain_watches()
-    assert svc._alone_live == {"sj02"}
     plan = plan_of(svc, [(rows["sj02"], "sn2"), (rows["sj05"], "sn2"),
                          (rows["sj01"], "sn1"), (rows["sj02"], "sn2")])
     orders = build(svc, plan, site)
-    assert svc.stats["alone_left_out_total"] == 2, "one per dropped fire"
+    assert sum(o.count("sj02") for o in orders) == 2, "both fires"
+    assert all("/sn2/" in o for o in orders if "sj02" in o or "sj05" in o)
+    assert sum(o.count("sj05") for o in orders) == 1
+    assert build(svc, plan, "native") == build(svc, plan, "ref")
     assert svc.stats["fires_node_gone_total"] == 0
-    assert not any("sj02" in o for o in orders)
-    assert any("sj05" in o for o in orders)
-    assert svc.metrics_snapshot()["alone_left_out_total"] == 2
-    # the lock is let go: nothing is left out any more
+    assert svc.metrics_snapshot()["alone_left_out_total"] == 0
+    # the lock is let go: the same orders
     store.delete(KS.alone_lock_key("sj02"))
     svc.drain_watches()
-    build(svc, plan, site)
-    assert svc.stats["alone_left_out_total"] == 2
+    assert build(svc, plan, site) == orders
     svc.stop()
     store.close()
 
@@ -498,7 +502,6 @@ def test_fires_node_gone_counts_each_fire_placed_on_a_node_that_left(site):
     svc.drain_watches()
     orders = build(svc, plan, site)
     assert svc.stats["fires_node_gone_total"] == 2, "one per dropped fire"
-    assert svc.stats["alone_left_out_total"] == 0
     assert not any("/sn1/" in o for o in orders)
     assert any("/sn2/" in o and "sj05" in o for o in orders)
     assert svc.metrics_snapshot()["fires_node_gone_total"] == 2
