@@ -320,7 +320,15 @@ class Job:
 
     @classmethod
     def from_json(cls, s: str) -> "Job":
-        d = json.loads(s)
+        return cls.from_dict(json.loads(s))
+
+    @classmethod
+    def from_dict(cls, d) -> "Job":
+        """A job from its parsed document; TypeError when the document
+        is not a JSON object."""
+        if not isinstance(d, dict):
+            raise TypeError(f"job document is a {type(d).__name__}, "
+                            "not an object")
         rules = [JobRule.from_dict(r) for r in d.get("rules") or []]
         deps = d.get("deps")
         if isinstance(deps, dict) and deps.get("on"):

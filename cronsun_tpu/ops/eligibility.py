@@ -68,6 +68,14 @@ def pack_bitmask(cols: Sequence[int], n_words: int) -> np.ndarray:
     return row
 
 
+def _cells(rows: List[int], cols: List[int]):
+    """(row, word) index pairs and the one-bit uint32 values of matrix
+    cells (rows[i], cols[i])."""
+    c = np.asarray(cols, np.int64)
+    return ((np.asarray(rows, np.int64), c >> 5),
+            np.uint32(1) << (c & 31).astype(np.uint32))
+
+
 def pack_eligibility(include_cols: Sequence[int], group_rows: Sequence[np.ndarray],
                      exclude_cols: Sequence[int], n_words: int) -> np.ndarray:
     """Eligibility row for one job: (includes ∪ groups) − excludes.
@@ -135,6 +143,59 @@ class EligibilityBuilder:
         for g in gids:
             self.group_jobs.setdefault(g, set()).add(row)
         self._rebuild(row)
+
+    def set_jobs(self, rows: Sequence[int],
+                 include_nids: Sequence[Sequence[str]],
+                 gids: Sequence[Sequence[str]],
+                 exclude_nids: Sequence[Sequence[str]]):
+        """:meth:`set_job` for a batch of DISTINCT rows, the matrix
+        written in one pass: every row's include bits in one
+        ``bitwise_or.at``, one OR of the cached group masks per distinct
+        gid list, every exclude bit cleared in one ``bitwise_and.at``.
+        The same job_rules, group_jobs, dirty set and bits as a loop of
+        set_job, and the same ownership transfer of the lists."""
+        if len(set(rows)) != len(rows):
+            raise ValueError("set_jobs needs distinct rows")
+        job_rules, group_jobs, idx = self.job_rules, self.group_jobs, \
+            self.u.index
+        inc_r, inc_c, ex_r, ex_c = [], [], [], []
+        by_gids: Dict[tuple, list] = {}
+        for row, nl, gl, xl in zip(rows, include_nids, gids, exclude_nids):
+            old = job_rules.get(row)
+            if old:
+                for g in old["gids"]:
+                    group_jobs.get(g, set()).discard(row)
+            job_rules[row] = dict(nids=nl, gids=gl, ex=xl)
+            for n in nl:
+                c = idx.get(n)
+                if c is not None:
+                    inc_r.append(row)
+                    inc_c.append(c)
+            if gl:
+                for g in gl:
+                    group_jobs.setdefault(g, set()).add(row)
+                by_gids.setdefault(tuple(gl), []).append(row)
+            for n in xl:
+                c = idx.get(n)
+                if c is not None:
+                    ex_r.append(row)
+                    ex_c.append(c)
+        m = self.matrix
+        m[np.asarray(rows, np.int64)] = 0
+        if inc_r:
+            where, bits = _cells(inc_r, inc_c)
+            np.bitwise_or.at(m, where, bits)
+        for gl, grows in by_gids.items():
+            acc = np.zeros(self.u.n_words, np.uint32)
+            for g in gl:
+                mask = self.group_mask.get(g)
+                if mask is not None:
+                    acc |= mask
+            m[grows] |= acc
+        if ex_r:        # last: (includes | groups) & ~excludes
+            where, bits = _cells(ex_r, ex_c)
+            np.bitwise_and.at(m, where, ~bits)
+        self._dirty.update(rows)
 
     def del_job(self, row: int):
         old = self.job_rules.pop(row, None)

@@ -25,7 +25,7 @@ updates without recompile").
 from __future__ import annotations
 
 import dataclasses
-from typing import List, Optional, Union
+from typing import List, Optional, Sequence, Union
 
 import jax
 import jax.numpy as jnp
@@ -111,21 +111,32 @@ def make_row(spec: Union[CronSpec, EverySpec, str], phase_epoch_s: int = 0,
              paused: bool = False, tenant: int = 0,
              jitter: int = 0) -> dict:
     """Host-side row dict for one spec (strings are parsed)."""
+    return make_rows(spec, (phase_epoch_s,), paused, tenant, jitter)[0]
+
+
+def make_rows(spec: Union[CronSpec, EverySpec, str],
+              phase_epochs_s: Sequence[int], paused: bool = False,
+              tenant: int = 0, jitter: int = 0) -> List[dict]:
+    """Row dicts for rules that share one spec, pause, tenant and
+    jitter: one per phase anchor.  A cron row does not read its phase,
+    so cron rows share ONE dict — row dicts are read-only once made."""
     if isinstance(spec, str):
         spec = parse(spec)
     if isinstance(spec, EverySpec):
         period = max(1, spec.period_s)
-        return dict(
+        base = dict(
             sec_lo=0, sec_hi=0, min_lo=0, min_hi=0, hour=0, dom=0, month=0,
             dow=0, dom_star=False, dow_star=False, is_every=True,
-            period=period,
-            phase_mod=int((phase_epoch_s - FRAMEWORK_EPOCH) % period),
+            period=period, phase_mod=0,
             active=True, paused=paused,
             has_dep=False, dep_policy=0, dep_cols=_NO_DEPS, tenant=tenant,
             jitter=int(jitter))
+        return [{**base,
+                 "phase_mod": int((p - FRAMEWORK_EPOCH) % period)}
+                for p in phase_epochs_s]
     sec_lo, sec_hi = _split64(spec.second)
     min_lo, min_hi = _split64(spec.minute)
-    return dict(
+    base = dict(
         sec_lo=sec_lo, sec_hi=sec_hi, min_lo=min_lo, min_hi=min_hi,
         hour=spec.hour & _MASK32, dom=spec.dom & _MASK32,
         month=spec.month & _MASK32, dow=spec.dow & _MASK32,
@@ -133,6 +144,7 @@ def make_row(spec: Union[CronSpec, EverySpec, str], phase_epoch_s: int = 0,
         is_every=False, period=1, phase_mod=0, active=True, paused=paused,
         has_dep=False, dep_policy=0, dep_cols=_NO_DEPS, tenant=tenant,
         jitter=int(jitter))
+    return [base] * len(phase_epochs_s)
 
 
 def make_dep_row(upstream_rows, policy: int, paused: bool = False,

@@ -24,6 +24,8 @@ death the lease expires and a standby takes over within ``lease_ttl``.
 
 from __future__ import annotations
 
+import gc
+import itertools
 import json
 import os
 import re
@@ -46,12 +48,16 @@ from ..ops.deps import NEVER as DEP_NEVER, POLICY_BY_NAME
 from ..ops.eligibility import EligibilityBuilder, NodeUniverse
 from ..ops.planner import TickPlanner
 from ..ops.schedule_table import DEP_BROKEN, FRAMEWORK_EPOCH, \
-    make_dep_row, make_row, _INACTIVE_ROW
+    make_dep_row, make_row, make_rows, _INACTIVE_ROW
 from ..store.memstore import CompactedError, DELETE, MemStore, PUT, \
     WatchLost
 
 # ids that serialize into a JSON string verbatim (no escapes needed)
 _WIRE_SAFE = re.compile(r"^[A-Za-z0-9_.:-]*$").match
+# json.loads without its per-call wrappers: (value, end) of the JSON
+# value that starts at an index; the same value json.loads gives where
+# it starts at 0 and ends at the string's end
+_scan_json = json.JSONDecoder().scan_once
 
 # JAX's own duration events: the first wraps every executable the
 # process obtains (compiled, or loaded from the persistent cache), the
@@ -653,7 +659,11 @@ class SchedulerService:
                       # fires the order build drops without a trace
                       # downstream: an exclusive fire placed on a node
                       # that left the fleet since
-                      "fires_node_gone_total": 0}
+                      "fires_node_gone_total": 0,
+                      # cold-load jobs by path: written a column at a
+                      # time, or handed to _apply_job (_load_jobs)
+                      "cold_jobs_columnar_total": 0,
+                      "cold_jobs_per_job_total": 0}
         self._compile_mu = threading.Lock()
         _listen_for_compiles(self)
         # herd gauges, tracked where orders are built: the most
@@ -914,15 +924,22 @@ class SchedulerService:
         self._phase_puts = []
         if cold is not None:
             cold.mark("lists")      # watches + every listing but cmd
-        try:
-            for kv in (jobs if jobs is not None
-                       else _list_prefix(self.store, self.ks.cmd)):
-                self._apply_job(kv.key, kv.value)
-        finally:
-            for i in range(0, len(self._phase_puts), 50_000):
-                self.store.put_many(self._phase_puts[i:i + 50_000])
-            self._phase_prefetch = None
-            self._phase_puts = None
+        # the registry is a dozen long-lived containers a job: with the
+        # collector on, its full passes walked the growing heap again
+        # and again (≈ 1.7 of 4.3 s at 100,000 jobs on a CPU)
+        from ..checkpoint.sched_ckpt import gc_paused
+        with gc_paused():
+            try:
+                self._load_jobs(jobs if jobs is not None
+                                else _list_prefix(self.store, self.ks.cmd))
+            finally:
+                for i in range(0, len(self._phase_puts), 50_000):
+                    self.store.put_many(self._phase_puts[i:i + 50_000])
+                self._phase_prefetch = None
+                self._phase_puts = None
+        # ONE full pass files the registry with the old objects; left to
+        # the collector, its passes would land in the device flush
+        gc.collect()
         if cold is not None:
             cold.mark("jobs")
         self._mirror_antientropy()
@@ -935,6 +952,132 @@ class SchedulerService:
             if sync is not None:
                 sync()
             cold.mark("device")
+            s = cold.seconds
+            log.infof("cold load: %d jobs (%d columnar, %d one at a time); "
+                      "lists %.3f s, jobs %.3f s, device %.3f s",
+                      len(self.jobs), self.stats["cold_jobs_columnar_total"],
+                      self.stats["cold_jobs_per_job_total"],
+                      s.get("lists", 0.0), s.get("jobs", 0.0),
+                      s.get("device", 0.0))
+
+    # the cold load's jobs listing is taken this many documents at a time
+    _JOB_PAGE = 65536
+
+    def _load_jobs(self, kvs):
+        """Apply a cmd/ listing a page at a time.  A document the page
+        shows to be a plain job (:meth:`_plain_job`) that is not
+        registered yet is written with the rest of its run of such jobs,
+        a column at a time (:meth:`_apply_fresh_jobs`); every other
+        document goes through :meth:`_apply_job`.  Both keep listing
+        order, so every job gets the row it gets one at a time."""
+        it = iter(kvs)
+        while True:
+            page = list(itertools.islice(it, self._JOB_PAGE))
+            if not page:
+                return
+            plen = len(self.ks.cmd)
+            owns, jobs, run = self._owns, self.jobs, []
+            for kv in page:
+                rest = kv.key[plen:]
+                if "/" not in rest:
+                    continue
+                group, job_id = rest.split("/", 1)
+                if owns is not None and not owns(job_id):
+                    continue        # another partition's token slice
+                plain = None if (group, job_id) in jobs \
+                    else self._plain_job(kv.value)
+                if plain is None:
+                    self._apply_fresh_jobs(run)
+                    run = []
+                    self.stats["cold_jobs_per_job_total"] += 1
+                    self._apply_job(kv.key, kv.value)
+                else:
+                    run.append((group, job_id) + plain)
+            self._apply_fresh_jobs(run)
+
+    def _plain_job(self, value):
+        """(job, its rules' specs) where ``value`` is one JSON object
+        whose job has no deps and no tenant, a bool pause, an int jitter
+        and rules with distinct string ids whose timers all parse; None
+        for any other document, which :meth:`_apply_job` then takes —
+        and skips where it skips."""
+        try:
+            d, end = _scan_json(value, 0)
+            if end != len(value):
+                return None
+            job = Job.from_dict(d)
+        except (StopIteration, ValueError, TypeError, AttributeError,
+                RecursionError):
+            return None         # not JSON, or not a job: _apply_job decides
+        if job.deps is not None or job.tenant or \
+                type(job.pause) is not bool or type(job.jitter) is not int:
+            return None
+        specs, ids = [], set()
+        for rule in job.rules:
+            if type(rule.id) is not str or type(rule.timer) is not str \
+                    or rule.id in ids:
+                return None
+            ids.add(rule.id)
+            spec = self._spec(rule.timer)
+            if spec is None:
+                return None
+            specs.append(spec)
+        return job, specs
+
+    def _apply_fresh_jobs(self, run: list):
+        """What :meth:`_apply_job` does for each (group, job_id, job,
+        specs) of ``run``, in listing order, written a column at a time:
+        rows and phase anchors one rule after another, then the table
+        rows (one dict per spec, pause, jitter and anchor), tenant
+        column, eligibility, job meta and dispatch cache a batch each.
+        Only for plain jobs not registered yet: no old rules to drop."""
+        if not run:
+            return
+        self.stats["cold_jobs_columnar_total"] += len(run)
+        acquire, row_phase = self.rows.acquire, self._row_phase
+        rows, items, by_spec = [], [], {}
+        for group, job_id, job, specs in run:
+            job.group, job.id = group, job_id
+            self.jobs[(group, job_id)] = job
+            if job.jitter > 0:
+                self._jitter_jobs += 1
+                self._max_jitter_seen = max(self._max_jitter_seen,
+                                            job.jitter)
+            for rule, spec in zip(job.rules, specs):
+                row = acquire(group, job_id, rule.id)
+                prev = row_phase.get(row)
+                if prev is not None and prev[0] == rule.timer:
+                    phase = prev[1]
+                else:
+                    phase = self._phase_anchor(group, job_id, rule.id,
+                                               rule.timer)
+                    row_phase[row] = (rule.timer, phase)
+                _s, srows, phases = by_spec.setdefault(
+                    (rule.timer, job.pause, job.jitter), (spec, [], []))
+                srows.append(row)
+                phases.append(phase)
+                rows.append(row)
+                items.append((job, rule, group, job_id))
+        for (_t, pause, jitter), (spec, srows, phases) in by_spec.items():
+            self._table_updates.update(zip(srows, make_rows(
+                spec, phases, paused=pause, jitter=jitter)))
+        if self._dep_rows:
+            self._dep_rows.difference_update(rows)
+        ra = np.asarray(rows, np.intp)
+        for row in ra[self._row_tenant[ra] != 0].tolist():
+            self._row_tenant[row] = 0
+            self._tenant_row_updates[row] = 0
+        rules = [it[1] for it in items]
+        self.builder.set_jobs(rows, [r.nids for r in rules],
+                              [r.gids for r in rules],
+                              [r.exclude_nids for r in rules])
+        self._set_rows_dispatch(rows, items)
+        if self._dep_rdeps:
+            # an upstream of jobs loaded before it: as in _apply_job
+            for group, job_id, _job, _specs in run:
+                if self._dep_rdeps.get((group, job_id)):
+                    self._dep_refresh_dependents(group, job_id)
+                    self._dep_seed_job_rows(group, job_id)
 
     # ---- leadership ------------------------------------------------------
 
@@ -1086,20 +1229,11 @@ class SchedulerService:
                     self._tenant_row_updates[row] = tid
                 self.builder.set_job(row, rule.nids, rule.gids,
                                      rule.exclude_nids)
-                self._meta_updates[row] = (
-                    job.exclusive,
-                    job.avg_time if job.avg_time > 0 else 1.0)
                 self._set_row_dispatch(row, job, rule, group, job_id)
                 continue
-            spec = self._spec_cache.get(rule.timer)
+            spec = self._spec(rule.timer)
             if spec is None:
-                try:
-                    spec = parse(rule.timer)
-                except ParseError:
-                    continue
-                if len(self._spec_cache) > 65536:
-                    self._spec_cache.clear()
-                self._spec_cache[rule.timer] = spec
+                continue
             new_rules.add(rule.id)
             row = self.rows.acquire(group, job_id, rule.id)
             self._dep_rows.discard(row)   # dep -> cron transition
@@ -1117,8 +1251,6 @@ class SchedulerService:
                 self._row_tenant[row] = tid
                 self._tenant_row_updates[row] = tid
             self.builder.set_job(row, rule.nids, rule.gids, rule.exclude_nids)
-            self._meta_updates[row] = (job.exclusive,
-                                       job.avg_time if job.avg_time > 0 else 1.0)
             self._set_row_dispatch(row, job, rule, group, job_id)
         for rule_id in old_rules - new_rules:
             self._drop_rule(group, job_id, rule_id)
@@ -1130,11 +1262,26 @@ class SchedulerService:
             self._dep_refresh_dependents(group, job_id)
             self._dep_seed_job_rows(group, job_id)
 
-    def _set_row_dispatch(self, row: int, job: Job, rule, group: str,
-                          job_id: str):
-        """Per-row dispatch cache install (tuple + parallel arrays);
-        flags LAST so a concurrently building worker never sees a
-        half-set row."""
+    def _spec(self, timer: str):
+        """A timer's compiled spec through ``_spec_cache``, None where
+        it does not parse."""
+        spec = self._spec_cache.get(timer)
+        if spec is None:
+            try:
+                spec = parse(timer)
+            except ParseError:
+                return None
+            if len(self._spec_cache) > 65536:
+                self._spec_cache.clear()
+            self._spec_cache[timer] = spec
+        return spec
+
+    @staticmethod
+    def _row_fields(job: Job, rule, group: str, job_id: str):
+        """Everything a row's job meta and dispatch cache hold but the
+        two FNV bases: ((exclusive, cost), the _row_dispatch tuple,
+        trace flag, jitter, flags) — the one formatter of the watch
+        path and of the cold load's columnar path."""
         if _WIRE_SAFE(rule.id):
             # default ids are next_id() hex: skip the json encoder
             # (measured at 1M-job load scale)
@@ -1142,27 +1289,72 @@ class SchedulerService:
         else:
             payload = json.dumps({"rule": rule.id, "kind": job.kind},
                                  separators=(",", ":"))
-        suffix = f"/{group}/{job_id}"
-        bentry = json.dumps(f"{group}/{job_id}")
-        self._row_dispatch[row] = (
-            job.exclusive, payload,
-            group, job_id, job.kind,
-            suffix,                 # precomputed key tail: the
-                                    # order-build loop is concat-only
-            # pre-escaped bundle entry: coalesced (node, second)
-            # values are "[" + ",".join(entries) + "]" at build time
-            bentry)
-        self._rd_payload[row] = payload
-        self._rd_suffix[row] = suffix
-        self._rd_bentry[row] = bentry
+        excl = job.exclusive
+        return ((excl, job.avg_time if job.avg_time > 0 else 1.0),
+                (excl, payload, group, job_id, job.kind,
+                 # precomputed key tail: the order-build loop is
+                 # concat-only
+                 f"/{group}/{job_id}",
+                 # pre-escaped bundle entry: coalesced (node, second)
+                 # values are "[" + ",".join(entries) + "]" at build
+                 # time
+                 f'"{group}/{job_id}"'
+                 if _WIRE_SAFE(group) and _WIRE_SAFE(job_id)
+                 else json.dumps(f"{group}/{job_id}")),
+                bool(getattr(job, "trace", False)),
+                int(getattr(job, "jitter", 0) or 0),
+                1 | (2 if excl else 0))
+
+    @staticmethod
+    def _fnv_keys(group: str, job_id: str) -> Tuple[str, str]:
+        """The strings the trace and smear FNV bases hash."""
+        return job_id + "|", group + "/" + job_id + "|"
+
+    def _set_row_dispatch(self, row: int, job: Job, rule, group: str,
+                          job_id: str):
+        """Per-row job meta and dispatch cache install (tuple + parallel
+        arrays); flags LAST so a concurrently building worker never sees
+        a half-set row.  :meth:`_set_rows_dispatch` is the batch form."""
+        meta, ent, tflag, jitter, flags = self._row_fields(
+            job, rule, group, job_id)
+        self._meta_updates[row] = meta
+        self._row_dispatch[row] = ent
+        self._rd_payload[row] = ent[1]
+        self._rd_suffix[row] = ent[5]
+        self._rd_bentry[row] = ent[6]
         self._rd_job[row] = (group, job_id)
-        self._rd_tbase[row] = np.uint64(
-            self._trace.fnv_partial(job_id + "|"))
-        self._rd_sbase[row] = np.uint64(
-            self._trace.fnv_partial(group + "/" + job_id + "|"))
-        self._rd_tflag[row] = bool(getattr(job, "trace", False))
-        self._rd_jitter[row] = int(getattr(job, "jitter", 0) or 0)
-        self._rd_flags[row] = 1 | (2 if job.exclusive else 0)
+        tkey, skey = self._fnv_keys(group, job_id)
+        self._rd_tbase[row] = np.uint64(self._trace.fnv_partial(tkey))
+        self._rd_sbase[row] = np.uint64(self._trace.fnv_partial(skey))
+        self._rd_tflag[row] = tflag
+        self._rd_jitter[row] = jitter
+        self._rd_flags[row] = flags
+
+    def _set_rows_dispatch(self, rows: List[int], items: list):
+        """:meth:`_set_row_dispatch` for DISTINCT rows, ``items`` their
+        (job, rule, group, job_id): the same fields, the numpy columns
+        written one page at a time (both FNV bases in one vectorized
+        pass each) and every row's flags last."""
+        fields = [self._row_fields(*it) for it in items]
+        meta, disp = self._meta_updates, self._row_dispatch
+        pay, suf, bent, rj = (self._rd_payload, self._rd_suffix,
+                              self._rd_bentry, self._rd_job)
+        for row, (m, ent, _t, _j, _f) in zip(rows, fields):
+            meta[row] = m
+            disp[row] = ent
+            pay[row] = ent[1]
+            suf[row] = ent[5]
+            bent[row] = ent[6]
+            rj[row] = (ent[2], ent[3])
+        ra = np.asarray(rows, np.intp)
+        keys = [self._fnv_keys(ent[2], ent[3]) for _m, ent, *_ in fields]
+        self._rd_tbase[ra] = self._trace.fnv_partial_vec(
+            [k[0] for k in keys])
+        self._rd_sbase[ra] = self._trace.fnv_partial_vec(
+            [k[1] for k in keys])
+        self._rd_tflag[ra] = [f[2] for f in fields]
+        self._rd_jitter[ra] = [f[3] for f in fields]
+        self._rd_flags[ra] = [f[4] for f in fields]
 
     # ---- multi-tenant control plane -------------------------------------
 
@@ -4539,6 +4731,8 @@ class SchedulerService:
             # lists/jobs at 0 and checkpoint_restore_ms holds the time
             **{f"cold_{k}_s": round(cold.get(k, 0.0), 3)
                for k in ("startup", "planner", "lists", "jobs", "device")},
+            **{k: self.stats[k] for k in ("cold_jobs_columnar_total",
+                                          "cold_jobs_per_job_total")},
             "cold_total_s": round(self.cold.total(), 3),
             "warm_s": round(self._warm_s, 3),
             "first_publish_s": round(self._first_publish_s, 3),

@@ -75,6 +75,24 @@ def fnv_continue_vec(states, s: str):
     return h
 
 
+def fnv_partial_vec(strings):
+    """:func:`fnv_partial` of every string at once, as a np.uint64
+    array: the UTF-8 bytes laid out in a [n, longest] matrix and folded
+    column by column, each row only as far as its own length."""
+    import numpy as np
+    enc = [s.encode() for s in strings]
+    lens = np.fromiter(map(len, enc), np.int64, len(enc))
+    width = int(lens.max()) if len(enc) else 0
+    mat = np.zeros((len(enc), width), np.uint8)
+    mat[np.arange(width) < lens[:, None]] = np.frombuffer(b"".join(enc),
+                                                          np.uint8)
+    h = np.full(len(enc), _FNV_OFFSET, np.uint64)
+    prime = np.uint64(_FNV_PRIME)
+    for j in range(width):
+        h = np.where(lens > j, (h ^ mat[:, j]) * prime, h)
+    return h
+
+
 def trace_id(job_id: str, epoch_s: int) -> int:
     return fnv1a64(f"{job_id}|{int(epoch_s)}")
 

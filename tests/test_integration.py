@@ -454,6 +454,36 @@ def test_scheduler_resync_after_watch_loss(world):
         "new job missed by resync"
 
 
+def test_resync_reload_takes_registered_jobs_one_at_a_time():
+    """The cold load writes jobs it has not seen a column at a time; a
+    reload over a registry that already holds them (what resync runs)
+    hands each to _apply_job, and leaves the scheduler as it was."""
+    store = MemStore()
+    store.put(KS.node_key("node-0"), "x")
+    for i in range(6):
+        put_job(store, Job(
+            name=f"j{i}", command="true",
+            kind=KIND_COMMON if i % 2 else KIND_ALONE,
+            rules=[JobRule(timer="@every 30s" if i % 3 else "*/5 * * * * *",
+                           nids=["node-0"])]))
+    sched = SchedulerService(store, job_capacity=64, node_capacity=16,
+                             window_s=2)
+    assert sched.stats["cold_jobs_columnar_total"] == 6
+    assert sched.stats["cold_jobs_per_job_total"] == 0
+
+    def state():
+        return (dict(sched.jobs), dict(sched.rows.by_cmd),
+                dict(sched._row_phase), dict(sched._row_dispatch),
+                sched.builder.matrix.tobytes(), sched._rd_flags.tobytes(),
+                sched._rd_tbase.tobytes(), sched._rd_sbase.tobytes(),
+                [(kv.key, kv.value) for kv in store.get_prefix(KS.phase)])
+    before = state()
+    sched._load_initial()
+    assert sched.stats["cold_jobs_per_job_total"] == 6
+    assert sched.stats["cold_jobs_columnar_total"] == 6
+    assert state() == before
+
+
 def test_agent_resync_after_watch_loss():
     """An agent whose dispatch watch overflows re-lists still-live orders
     and runs them exactly once (store fence); Common broadcasts dedupe
