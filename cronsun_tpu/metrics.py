@@ -11,6 +11,7 @@ failure-must-not-stall-the-caller rule.
 
 from __future__ import annotations
 
+import gc
 import json
 import os
 import threading
@@ -107,25 +108,47 @@ class LatencyRing:
         return float(sum(self._v))
 
 
+# the leaf each thread has open, ``<layer>.<name>``: the pause accounts
+# put a compile or a collector pass under the span it landed in
+_open = threading.local()
+
+
+def open_leaf() -> str:
+    """The ``<layer>.<name>`` of the :class:`Spans` block open on the
+    calling thread (a ring-only holder's too), or ``none``."""
+    return getattr(_open, "leaf", None) or "none"
+
+
+def leaf_key(leaf: str) -> str:
+    """A leaf as snapshot keys name it: its ``<name>``, as the
+    ``step_span_<name>_*`` gauges name its ring."""
+    return leaf.rpartition(".")[2]
+
+
 class _Span:
     """One timed block of a :class:`Spans` holder; ``ms`` is set when
     the block ends."""
 
-    __slots__ = ("_holder", "_key", "_into", "_note", "_t0", "ms")
+    __slots__ = ("_holder", "_key", "_into", "_note", "_t0", "_leaf",
+                 "_prev", "ms")
 
-    def __init__(self, holder, key, into, note, since):
+    def __init__(self, holder, key, into, note, since, leaf):
         self._holder, self._key, self._into = holder, key, into
         self._note, self._t0, self.ms = note, since, 0.0
+        self._leaf = leaf
 
     def __enter__(self):
         if self._note is not None:
             self._note.__enter__()
+        self._prev = getattr(_open, "leaf", None)
+        _open.leaf = self._leaf
         if self._t0 is None:
             self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         self.ms = (time.perf_counter() - self._t0) * 1e3
+        _open.leaf = self._prev
         if self._note is not None:
             self._note.__exit__(*exc)
         if self._into is not None:
@@ -156,6 +179,7 @@ class Spans:
     agent's own lock (``node/agent.py`` STAGES, ``_task_done``) — a
     ring's append is safe from one producer, and there the producers
     are 64 pool threads.  Nothing is timed per order or per key.
+    While a block is open, :func:`open_leaf` names it on its thread.
 
     ``ring`` names the ring where it differs from the span's name;
     ``into`` collects the duration in a dict instead (summed per name:
@@ -180,11 +204,114 @@ class Spans:
              **ids) -> _Span:
         note = (self._annotate(f"cronsun.{self.layer}.{name}", **ids)
                 if self._annotate is not None else None)
-        return _Span(self, ring or name, into, note, since)
+        return _Span(self, ring or name, into, note, since,
+                     f"{self.layer}.{name}")
 
     def commit(self, spans: Dict[str, float]) -> None:
         for name, ms in spans.items():
             self.ring(name).add(ms)
+
+
+class CollectorPauses:
+    """The interpreter's cyclic collector, pass by pass: ONE account a
+    process, fed by a ``gc.callbacks`` hook (:meth:`install`).  Each
+    pass is timed between its ``start`` and ``stop`` callbacks on the
+    thread that ran it, which holds the interpreter throughout, so
+    every other thread of the process waited as long.  Kept: passes
+    and ms per generation, and the ms of full (generation-2) passes by
+    the leaf open on that thread (:func:`open_leaf`).
+
+    Installed with an annotation factory (the scheduler's), a full
+    pass is also ``cronsun.gc.full`` on the profiler's timeline, with
+    the objects of the oldest generation as its id ``objects`` (counted
+    only while the factory's ``is_enabled()`` says a trace is being
+    recorded: the count walks the heap).  It is the one annotation that
+    nests inside an open leaf, so an idle gap of the device under it
+    reads ``cronsun.gc.full + cronsun.step.<leaf>``.  Young passes come
+    thousands of times a second and get totals only."""
+
+    def __init__(self):
+        self.passes = [0, 0, 0]
+        self.ms = [0.0, 0.0, 0.0]
+        self.full_ms: Dict[str, float] = {}     # leaf -> ms of full passes
+        self.annotate: Optional[Callable] = None
+        self._t0 = 0.0
+        self._note = None
+        self._installed = False
+
+    def install(self, annotate: Optional[Callable] = None) -> None:
+        """Hook the collector (once a process); ``annotate`` replaces the
+        factory where given."""
+        if annotate is not None:
+            self.annotate = annotate
+        if not self._installed:
+            self._installed = True
+            gc.callbacks.append(self._on_pass)
+
+    def _on_pass(self, phase, info, _clock=time.perf_counter):
+        if phase == "start":
+            if info["generation"] == 2 and self.annotate is not None:
+                self._open_note()
+            self._t0 = _clock()
+            return
+        ms = (_clock() - self._t0) * 1e3
+        gen = info["generation"]
+        self.passes[gen] += 1
+        self.ms[gen] += ms
+        if gen == 2:
+            leaf = open_leaf()
+            self.full_ms[leaf] = self.full_ms.get(leaf, 0.0) + ms
+            note, self._note = self._note, None
+            if note is not None:
+                note.__exit__(None, None, None)
+
+    def _open_note(self):
+        annotate = self.annotate
+        enabled = getattr(annotate, "is_enabled", None)
+        ids = ({"objects": len(gc.get_objects(2))}
+               if enabled is None or enabled() else {})
+        self._note = annotate("cronsun.gc.full", **ids)
+        self._note.__enter__()
+
+    def pause_ms(self) -> float:
+        """ms of every pass so far, all generations."""
+        return sum(self.ms)
+
+    def totals(self) -> Dict[str, float]:
+        """The account so far, flat: ``pause_ms`` (every generation),
+        ``full_passes``, ``full_ms`` and ``full_ms_<leaf key>``."""
+        out = {"pause_ms": sum(self.ms), "full_ms": self.ms[2],
+               "full_passes": self.passes[2]}
+        # a copy in one C call: a pass from another thread may add a leaf
+        for leaf, ms in dict(self.full_ms).items():
+            key = "full_ms_" + leaf_key(leaf)
+            out[key] = out.get(key, 0.0) + ms
+        return out
+
+
+# the process's one collector account
+gc_pauses = CollectorPauses()
+
+
+class Gains:
+    """What a growing counter dict (``read()``) gained over the
+    stretches a condition held: ``poll(held)`` adds the gain since the
+    previous poll when ``held`` says the condition held over it."""
+
+    def __init__(self, read: Callable[[], Dict[str, float]]):
+        self._read = read
+        self._last = read()
+        self.total: Dict[str, float] = {}
+
+    def poll(self, held: bool) -> None:
+        now = self._read()
+        if held:
+            last, total = self._last, self.total
+            for key, v in now.items():
+                gain = v - last.get(key, 0)
+                if gain:
+                    total[key] = total.get(key, 0) + gain
+        self._last = now
 
 
 def process_age_s() -> Optional[float]:

@@ -35,7 +35,8 @@ from ..core.backoff import REC_FLUSH
 from ..core.errors import DuplicateNode
 from ..core.models import KIND_ALONE
 from ..logsink import JobLogStore, LogRecord
-from ..metrics import LatencyRing, MetricsPublisher, Spans, percentile
+from ..metrics import LatencyRing, MetricsPublisher, Spans, gc_pauses, \
+    percentile
 from ..store.memstore import DELETE, MemStore, WatchLost
 from .executor import ExecResult, Executor
 
@@ -212,7 +213,8 @@ class _Second:
         # a burst (armed): the pool it ran on and, from the moment it
         # was armed, (the GIL probe's samples, how many there were,
         # perf_counter, the probe thread's id and run-queue wait, the
-        # host's CPU stall) — see NodeAgent._arm_gil_probe
+        # host's CPU stall, the collector's ms) — see
+        # NodeAgent._arm_gil_probe
         self.pool: Optional[_ExecPool] = None
         self.probe: Optional[tuple] = None
 
@@ -233,7 +235,7 @@ class _Second:
         the log line."""
         first, last = self.first, self.last
         drain = max(last[0] - first[0], 1e-9)
-        samples, i0, t_armed, tid, runq0, stall0 = self.probe
+        samples, i0, t_armed, tid, runq0, stall0, gc0 = self.probe
         armed = samples[i0:]
         over = sorted(o for t, o in armed if first[0] <= t <= last[0])
         rec = {"sec": epoch_s, "n": self.n,
@@ -252,7 +254,10 @@ class _Second:
                "gil_probe_max_ms": round(percentile(over, 1.0), 3),
                # armed -> closed: all the probe overshot by, and how much
                # of that it waited for a core, not for the interpreter
-               "gil_probe_over_ms": round(sum(o for _t, o in armed), 3)}
+               "gil_probe_over_ms": round(sum(o for _t, o in armed), 3),
+               # armed -> closed: the collector's passes, every thread
+               # of the agent stopped for each
+               "gc_ms": round(gc_pauses.pause_ms() - gc0, 3)}
         runq1, stall1 = _runq_wait_ms(tid), _host_cpu_stall_ms()
         if runq0 is not None and runq1 is not None:
             rec["gil_probe_runq_ms"] = round(runq1 - runq0, 3)
@@ -474,6 +479,8 @@ class NodeAgent:
         # execution makes is under _stats_mu (_task_done)
         self._spans = Spans("agent", rings={name: LatencyRing(512)
                                             for name in SPAN_RINGS})
+        # the collector's passes, timed (the process's one account)
+        gc_pauses.install()
         # scheduled second -> its account while tasks of it are out; the
         # bursts closed lately, as (closed at, record); and the GIL
         # probe, alive only while a burst is armed (_gil_probe_loop)
@@ -634,6 +641,8 @@ class NodeAgent:
                 self._spawn_ring.percentile(0.99), 3)
         snap["running"] = len(self.running)
         snap["procs_registered"] = len(self._procs)
+        snap["gc_pause_ms_total"] = round(gc_pauses.pause_ms(), 3)
+        snap["gc_full_ms_total"] = round(gc_pauses.ms[2], 3)
         snap["rec_flush_max_batch"] = self._rec_flush_max_batch
         with self._rec_mu:
             snap["rec_buf"] = len(self._rec_buf)
@@ -2228,7 +2237,8 @@ class NodeAgent:
             self._probe_thread.start()
         tid = self._probe_thread.native_id
         acct.probe = (self._probe_samples, len(self._probe_samples), now,
-                      tid, _runq_wait_ms(tid), _host_cpu_stall_ms())
+                      tid, _runq_wait_ms(tid), _host_cpu_stall_ms(),
+                      gc_pauses.pause_ms())
 
     def _gil_probe_loop(self, samples: list):
         """While a burst is open: nap GIL_PROBE_NAP_S and note how much

@@ -204,11 +204,6 @@ class OrderPublisher:
         publish."""
         self._mark_failed(epoch)
 
-    @property
-    def inflight(self) -> int:
-        """Windows submitted but not yet fully published/abandoned."""
-        return self._inflight
-
     def take_failed_epoch(self):
         """The lowest epoch whose orders were dropped after retries, or
         None.  NOT cleared by reading: the mark stands until a window

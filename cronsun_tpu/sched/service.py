@@ -38,12 +38,14 @@ from typing import Callable, Dict, List, NamedTuple, Optional, Set, Tuple
 import numpy as np
 
 from jax import monitoring as _jax_monitoring
+from jax import profiler as _jax_profiler
 from jax.profiler import TraceAnnotation
 
 from .. import log
 from ..core import Group, Job, Keyspace, TenantQuota
 from ..cron.parser import ParseError, parse
-from ..metrics import LatencyRing, MetricsPublisher, PhaseClock, Spans
+from ..metrics import Gains, LatencyRing, MetricsPublisher, PhaseClock, \
+    Spans, gc_pauses, leaf_key, open_leaf
 from ..ops.deps import NEVER as DEP_NEVER, POLICY_BY_NAME
 from ..ops.eligibility import EligibilityBuilder, NodeUniverse
 from ..ops.planner import TickPlanner
@@ -577,6 +579,21 @@ class SchedulerService:
                                   rings=self._span_hist)
         self._timers = Spans("plan", rings=self._span_hist)
         self._warm_spans = Spans("warm", TraceAnnotation)
+        # the flush by part (FLUSH_PARTS): ms into ring flush_<part> and
+        # rows written, a leader's only; not leaves — they sit inside
+        # span "flush" and stay out of the step's spans
+        for part in self.FLUSH_PARTS:
+            self._span_hist["flush_" + part] = LatencyRing()
+        self._flush_rows = dict.fromkeys(self.FLUSH_PARTS, 0)
+        # (part, padded rows) of the scatter the flush is dispatching,
+        # and the pairs a served-path compile already logged
+        self._flush_at: Optional[Tuple[str, int]] = None
+        self._flush_compiled: Set[Tuple[str, int]] = set()
+        # the collector's passes while this scheduler led (polled at
+        # each step's try_lead); the profiler's own annotation, so a
+        # full pass is cronsun.gc.full on the timeline
+        gc_pauses.install(_jax_profiler.TraceAnnotation)
+        self._gc_leading = Gains(gc_pauses.totals)
         from .publisher import OrderPublisher, WindowBuilder
         self.publisher = OrderPublisher(
             lanes, self._advance_hwm, shard_of=shard_of,
@@ -656,6 +673,7 @@ class SchedulerService:
                       # thread — a stall of the served path
                       "compiles_total": 0, "compile_s_total": 0.0,
                       "cache_loads_total": 0, "compiles_leading_total": 0,
+                      "compile_leading_s_total": 0.0,
                       # fires the order build drops without a trace
                       # downstream: an exclusive fire placed on a node
                       # that left the fleet since
@@ -665,6 +683,8 @@ class SchedulerService:
                       "cold_jobs_columnar_total": 0,
                       "cold_jobs_per_job_total": 0}
         self._compile_mu = threading.Lock()
+        # compiles_leading_total by the leaf open on the compiling thread
+        self._compiles_by_leaf: Dict[str, int] = {"flush": 0}
         _listen_for_compiles(self)
         # herd gauges, tracked where orders are built: the most
         # EXCLUSIVE (per-node) keys any one second published — bounded
@@ -3203,9 +3223,18 @@ class SchedulerService:
                     [a, np.repeat(a[-1:], pad, axis=0)]))
         return tuple(out)
 
-    def _flush_device(self):
+    # the flush's parts, in the order _flush_device writes them
+    FLUSH_PARTS = ("tenant", "table", "elig", "meta", "deps")
+
+    def _flush_device(self, parts: Optional[dict] = None):
+        """Write the queued row updates to the device: the host-only
+        tenant snapshot, then one eager scatter family per table.
+        ``parts`` collects ``{part: (ms, rows written)}`` for each part
+        that had work, its numpy prep and padding included."""
+        clock = time.perf_counter
         if self._tenant_row_updates:
             if self._tenant_supported:
+                t0 = clock()
                 rows = np.fromiter(self._tenant_row_updates, np.int32,
                                    len(self._tenant_row_updates))
                 tids = np.array([self._tenant_row_updates[int(r)]
@@ -3213,36 +3242,53 @@ class SchedulerService:
                 # host-only snapshot update (the device tenant column
                 # rides the normal table scatters below); marks the
                 # admission permutation dirty for the next dispatch
+                self._flush_at = ("tenant", len(rows))
                 self.planner.set_row_tenants(rows, tids)
+                self._flush_part(parts, "tenant", t0, len(rows))
             self._tenant_row_updates.clear()
         if self._table_updates:
+            t0 = clock()
             rows = np.array(sorted(self._table_updates), dtype=np.int32)
             vals = [self._table_updates[int(r)] for r in rows]
+            n = len(rows)
             rows, vals = self._pad_pow2(rows, vals)
+            self._flush_at = ("table", len(rows))
             self.planner.update_table_rows(rows, vals)
             self._table_updates.clear()
+            self._flush_part(parts, "table", t0, n)
+        t0 = clock()
         dirty, mat = self.builder.dirty_rows()
         if len(dirty):
+            n = len(dirty)
             dirty, mat = self._pad_pow2(dirty, mat)
+            self._flush_at = ("elig", len(dirty))
             self.planner.set_eligibility_rows(dirty, mat)
+            self._flush_part(parts, "elig", t0, n)
         if self._meta_updates:
+            t0 = clock()
             rows = np.array(sorted(self._meta_updates), dtype=np.int32)
             excl = np.array([self._meta_updates[int(r)][0] for r in rows])
             cost = np.array([self._meta_updates[int(r)][1] for r in rows],
                             dtype=np.float32)
+            n = len(rows)
             rows, excl, cost = self._pad_pow2(rows, excl, cost)
+            self._flush_at = ("meta", len(rows))
             self.planner.set_job_meta(rows, excl, cost)
             self._meta_updates.clear()
+            self._flush_part(parts, "meta", t0, n)
         # workflow DAG scatters, strictly ordered: row RESETS first (a
         # released row's clean slate must not be re-poisoned by a stale
         # queued fold), then the monotone epoch folds, then the
         # max_in_flight gate
+        t0, n = clock(), 0
         self._dep_refresh_blocks()
         if self._dep_resets:
             rows = np.array(sorted(self._dep_resets), dtype=np.int32)
             anchors = np.array([self._dep_resets[int(r)] for r in rows],
                                dtype=np.int32)
+            n += len(rows)
             rows, anchors = self._pad_pow2(rows, anchors)
+            self._flush_at = ("deps", len(rows))
             self.planner.reset_dep_rows(rows, anchors)
             self._dep_resets.clear()
         if self._dep_epoch_updates:
@@ -3252,7 +3298,9 @@ class SchedulerService:
                              for r in rows], dtype=np.int32)
             fail = np.array([self._dep_epoch_updates[int(r)][1]
                              for r in rows], dtype=np.int32)
+            n += len(rows)
             rows, succ, fail = self._pad_pow2(rows, succ, fail)
+            self._flush_at = ("deps", len(rows))
             self.planner.set_dep_epochs(rows, succ, fail)
             self._dep_epoch_updates.clear()
         if self._dep_block_updates:
@@ -3260,9 +3308,26 @@ class SchedulerService:
                             dtype=np.int32)
             vals = np.array([self._dep_block_updates[int(r)]
                              for r in rows])
+            n += len(rows)
             rows, vals = self._pad_pow2(rows, vals)
+            self._flush_at = ("deps", len(rows))
             self.planner.set_dep_block(rows, vals)
             self._dep_block_updates.clear()
+        if n:
+            self._flush_part(parts, "deps", t0, n)
+        self._flush_at = None
+
+    @staticmethod
+    def _flush_part(parts: Optional[dict], part: str, t0: float,
+                    rows: int):
+        if parts is not None:
+            parts[part] = ((time.perf_counter() - t0) * 1e3, rows)
+
+    def _commit_flush_parts(self, parts: dict):
+        """A leading step's flush parts into their rings and counts."""
+        for part, (ms, rows) in parts.items():
+            self._span_hist["flush_" + part].add(ms)
+            self._flush_rows[part] += rows
 
     def _start_warm(self):
         """Background compile of the plan executables this process will
@@ -3299,17 +3364,29 @@ class SchedulerService:
         self._warm_thread.start()
 
     def _count_compile(self, event: str, secs: float):
-        """One of JAX's compile events (any thread; see
-        _on_compile_event)."""
+        """One of JAX's compile events (on the compiling thread; see
+        _on_compile_event).  A served-path one is counted under the leaf
+        open on that thread; one inside the flush is logged the first
+        time its (part, padded rows) is seen."""
         with self._compile_mu:
             if event == _CACHE_LOAD_EVENT:
                 self.stats["cache_loads_total"] += 1
                 return
             self.stats["compiles_total"] += 1
             self.stats["compile_s_total"] += secs
-            if self.is_leader and \
-                    threading.current_thread() is not self._warm_thread:
-                self.stats["compiles_leading_total"] += 1
+            if not self.is_leader or \
+                    threading.current_thread() is self._warm_thread:
+                return
+            self.stats["compiles_leading_total"] += 1
+            self.stats["compile_leading_s_total"] += secs
+            leaf = leaf_key(open_leaf())
+            self._compiles_by_leaf[leaf] = \
+                self._compiles_by_leaf.get(leaf, 0) + 1
+            at = self._flush_at
+            if leaf != "flush" or at is None or at in self._flush_compiled:
+                return
+            self._flush_compiled.add(at)
+        log.infof("flush compiled %s at %d rows (%.3f s)", at[0], at[1], secs)
 
     # ---- capacity reconciliation ----------------------------------------
 
@@ -3420,6 +3497,7 @@ class SchedulerService:
             led_before = self.is_leader
             leading = self.try_lead()
             t_lead = time.monotonic()
+            self._gc_leading.poll(led_before)
             if not leading:
                 self._next_epoch = None
                 self._pending_plan = None
@@ -3444,8 +3522,9 @@ class SchedulerService:
                 # partition's next reconcile subtracts it (O(active
                 # nodes) JSON once per exchange period, not per step)
                 self._publish_acct()
+        flushed: Dict[str, tuple] = {}
         with sp.span("flush", into=spans, n=n):
-            self._flush_device()
+            self._flush_device(flushed)
         with sp.span("cursor", into=spans, n=n):
             start = self._plan_cursor(now)
         window = max(1, self.window_s)
@@ -3468,6 +3547,7 @@ class SchedulerService:
         self._step_ms.add(spans["total"])
         self._pl_step_ms += spans["total"]
         sp.commit(spans)
+        self._commit_flush_parts(flushed)
         self.stats["steps_total"] += 1
         self._drain_tenant_q()
         self._publish_snapshots(None, n)
@@ -4659,6 +4739,9 @@ class SchedulerService:
         wait_ms = self._spans.ring("wait").sum()
         cold = self.cold.seconds
         pub_ring = self.publisher.spans.ring("window")
+        with self._compile_mu:
+            by_leaf = dict(self._compiles_by_leaf)
+        gcl = dict(self._gc_leading.total)
         part = ({"partition": self.partition,
                  "partitions": self.partitions,
                  "acct_exchanges_total":
@@ -4701,7 +4784,6 @@ class SchedulerService:
             "pipeline_offstep_ms_total": round(offstep_ms, 3),
             "pipeline_overlap_ratio":
                 round(hidden_ms / denom_ms, 4) if denom_ms else 0.0,
-            "publish_inflight": self.publisher.inflight,
             "overflow_drops_total": self.stats["overflow_drops"],
             "overflow_late_fires_total": self.stats["overflow_late_fires"],
             "skipped_seconds_total": self.stats["skipped_seconds"],
@@ -4712,11 +4794,24 @@ class SchedulerService:
             # the partition= label rides every series above)
             "lease_resigns_total": self.stats["lease_resigns_total"],
             # what the program does silently (see self.stats)
-            **{k: (round(self.stats[k], 3) if k == "compile_s_total"
-                   else self.stats[k])
-               for k in ("compiles_total", "compile_s_total",
-                         "cache_loads_total", "compiles_leading_total",
-                         "fires_node_gone_total")},
+            **{k: (round(v, 3) if isinstance(v, float) else v)
+               for k, v in ((k, self.stats[k]) for k in (
+                   "compiles_total", "compile_s_total", "cache_loads_total",
+                   "compiles_leading_total", "compile_leading_s_total",
+                   "fires_node_gone_total"))},
+            # the served path's compiles by the leaf they landed in (they
+            # sum to compiles_leading_total), and its collector passes
+            **{f"compiles_leading_{leaf}_total": k
+               for leaf, k in sorted(by_leaf.items())},
+            "gc_pause_ms_leading_total": round(gcl.get("pause_ms", 0.0), 3),
+            "gc_full_ms_leading_total": round(gcl.get("full_ms", 0.0), 3),
+            "gc_full_passes_leading_total": int(gcl.get("full_passes", 0)),
+            **{f"gc_full_ms_leading_{k[8:]}_total": round(v, 3)
+               for k, v in sorted(gcl.items()) if k.startswith("full_ms_")},
+            # rows each part of a leader's flushes wrote (unpadded); its
+            # ms are the step_span_flush_<part>_* rings above
+            **{f"flush_rows_{part}_total": rows
+               for part, rows in self._flush_rows.items()},
             # the served windows by assign variant, and the exclusive
             # bucket's rows bid against the fires they held (the
             # single-chip planner's; the mesh planners keep none)
@@ -4736,10 +4831,6 @@ class SchedulerService:
             "cold_total_s": round(self.cold.total(), 3),
             "warm_s": round(self._warm_s, 3),
             "first_publish_s": round(self._first_publish_s, 3),
-            # per-shard publish decoupling: 1 when the publisher runs
-            # one shard-routed lane per store shard
-            "publish_shard_lanes":
-                1 if self.publisher.shard_lanes else 0,
             # outstanding exclusive-slot reservations: slot counts over
             # the ORDERS mirror only (coalesced keys reserve len(jobs)
             # each, so key count would understate it; _excl_cnt would
@@ -4767,24 +4858,19 @@ class SchedulerService:
             "publish_max_second_excl_fires": self.max_second_excl_fires,
             # herd-smearing plane: jobs arming jitter, fires deferred
             # past their matched second / re-emitted at their smeared
-            # one, the widest observed delta and the largest arrival
-            # burst any single smeared second absorbed (the smeared
-            # twins of the herd gauges above), plus spill-ring health
+            # one and the widest observed delta (smear_snapshot has the
+            # arrivals a second), plus spill-ring health
             # (late = overflow-replan spill emitted on legacy keys;
             # drops = ring cap exceeded, LOUD — fires were lost)
             "smear_jobs": self._jitter_jobs,
             "smear_deferred_total": self._smear_stats["deferred_total"],
             "smear_emitted_total": self._smear_stats["emitted_total"],
-            "smear_merged_dups_total":
-                self._smear_stats["merged_dups_total"],
             "smear_late_emits_total":
                 self._smear_stats["late_emits_total"],
             "smear_ring_depth": self._smear_ring_n,
             "smear_ring_drops_total":
                 self._smear_stats["ring_drops_total"],
             "smear_max_spread_s": self._smear_stats["max_spread_s"],
-            "smear_max_second_arrivals":
-                self._smear_stats["max_second_arrivals"],
             # checkpoint plane: save cadence health + whether this
             # instance booted warm (restored=1) and how fast
             "checkpoint_saves_total": self._ckpt_stats["saves_total"],
@@ -4811,7 +4897,6 @@ class SchedulerService:
             # workflow DAG plane health
             "dep_jobs": len(self._dep_jobs),
             "dep_blocked_jobs": len(self._dep_blocked),
-            "dep_events_mirrored": len(self._dep_latest),
             # multi-tenant admission health (per-tenant breakdown rides
             # the "tenant" component snapshot -> cronsun_tenant_*)
             "tenants": len(self._tenants),
@@ -4821,8 +4906,6 @@ class SchedulerService:
             "tenant_throttled_fires_total": sum(
                 c["throttled_fires"]
                 for c in self._tenant_counters.values()),
-            "tenant_shed_fires_total": sum(
-                c["shed_fires"] for c in self._tenant_counters.values()),
         }
 
     def smear_snapshot(self) -> dict:
